@@ -149,7 +149,7 @@ def bench_cached():
     from repro.configs.base import EmbeddingTableConfig
     from repro.embeddings.cache import HotIdCache
     from repro.embeddings.engine import EmbeddingCollection
-    from repro.launch.mesh import make_mesh, mesh_scope
+    from repro.launch.mesh import make_mesh
     from repro.parallel.context import ParallelContext
 
     mesh = make_mesh((2, 4), ("data", "model"))
@@ -179,7 +179,7 @@ def bench_cached():
             cache.observe(g.name,
                           np.asarray(feats[s.spec.name]) + s.offset)
 
-    with mesh_scope(mesh):
+    with jax.set_mesh(mesh):
         un = jax.jit(lambda p, f: coll.lookup(p, f, ctx, method="a2a"))
         ca = jax.jit(lambda p, f, c: coll.lookup(p, f, ctx, method="a2a",
                                                  cache=c))

@@ -67,7 +67,7 @@ from repro.configs.base import EmbeddingTableConfig
 from repro.embeddings.cache import HotIdCache
 from repro.embeddings.dedup import dedup_ids
 from repro.embeddings.sharding import Placement, plan_placement
-from repro.parallel.context import LOCAL, ParallelContext, shard_map
+from repro.parallel.context import LOCAL, ParallelContext
 from repro.parallel.overlap import software_pipeline
 
 P = jax.sharding.PartitionSpec
@@ -574,7 +574,10 @@ def _segment_combine(rows, ids, cols):
         sel[a:b, i] = 1.0
     sel = jnp.asarray(sel)
     valid = (ids >= 0).astype(rows.dtype)
-    out = jnp.einsum("bvd,vk->bkd", rows * valid[..., None], sel)
+    # a TPU's default f32 matmul rounds its operands to bf16; the 0/1
+    # selector sum must stay exact, as the per-table combine is
+    out = jnp.einsum("bvd,vk->bkd", rows * valid[..., None], sel,
+                     precision=jax.lax.Precision.HIGHEST)
     counts = jnp.einsum("bv,vk->bk", valid, sel)
     means = jnp.asarray([c == "mean" for *_, c in cols])
     denom = jnp.where(means[None, :], jnp.maximum(counts, 1.0), 1.0)
@@ -594,7 +597,7 @@ def _rowsharded_psum(table, ids, ctx: ParallelContext, *, cols):
         combined = _psum_partial(table_loc, ids_loc, axis, rps, cols, ctx)
         return jax.lax.psum(combined, axis)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=ctx.mesh,
         in_specs=(P(axis, None), P(bspec, None)),
         out_specs=P(bspec, None, None), check_vma=False)
@@ -635,7 +638,7 @@ def _rowsharded_psum_multi(tables, ids_list, ctx: ParallelContext, *,
 
         return tuple(software_pipeline(stage_a, stage_b, range(n)))
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=ctx.mesh,
         in_specs=(tuple(P(axis, None) for _ in range(n)),
                   tuple(P(bspec, None) for _ in range(n))),
@@ -771,7 +774,7 @@ def _rowsharded_a2a_pipelined(tables, ids_list, ctx: ParallelContext, *,
 
         cache_specs = (tuple((P(None), P(None, None)) for _ in cache_args)
                        if with_cache else ())
-        fn = shard_map(
+        fn = jax.shard_map(
             local, mesh=ctx.mesh,
             in_specs=(tuple(P(axis, None) for _ in range(n)),
                       tuple(P(batch_both, None) for _ in range(n)),

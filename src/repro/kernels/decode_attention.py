@@ -4,18 +4,21 @@ The serving decode hot spot: every slot holds ONE fresh query token and a KV
 cache whose *valid* length differs per slot (continuous batching admits and
 retires requests independently).  A dense decode attention scans all
 ``max_len`` cache rows for every slot; this kernel gathers only each slot's
-valid prefix — a per-slot ``seq_lens`` vector rides in scalar-prefetch SMEM
-and KV blocks entirely past a slot's length are skipped with ``pl.when``, so
-a freshly admitted slot costs ``ceil(len/bk)`` block reads no matter how long
-the compile-time cache envelope is.
+valid prefix — a per-slot ``seq_lens`` vector rides in scalar-prefetch SMEM,
+KV blocks entirely past a slot's length are skipped with ``pl.when``, and
+the index maps clamp to the slot's last valid block, so a freshly admitted
+slot costs ``ceil(len/bk)`` block reads no matter how long the
+compile-time cache envelope is.
 
 Semantics are shared with ``flash_attention``: flash-style online softmax
-over KV blocks, GQA by per-head index mapping (no KV duplication), sliding
+over KV blocks, GQA by head grouping (no KV duplication), sliding
 windows, and gemma2-style logit soft-capping.  ``ref.paged_decode_attention_
 ref`` is the dense XLA oracle and serving fallback for non-TPU backends.
 
-Tiling: grid (B, H, nk); the single query row (1, d) stays resident; k/v
-blocks (bk, d) stream through VMEM; m/l/acc live in VMEM scratch.
+Tiling: grid (B, nk); the slot's query rows (H, d) stay resident; k/v
+blocks (bk, KH, d) — every KV head of bk cache rows, so the block's last
+two dims are the cache's own — stream through VMEM, and the body walks the
+KV heads with strided (bk, d) loads; m/l/acc (H, ·) live in VMEM scratch.
 """
 from __future__ import annotations
 
@@ -35,13 +38,16 @@ NEG_INF = -1e30
 def _decode_body(sl_ref, q_ref, load_kv, o_ref,
                  m_ref, l_ref, acc_ref, *,
                  scale: float, window: Optional[int],
-                 softcap: Optional[float], bk: int, nk: int):
-    """Shared online-softmax body; ``load_kv()`` yields this grid step's
-    (bk, d) k and v tiles — raw VMEM loads on the full-width path, an
-    int8-row dequant (1-byte rows + a per-row scale broadcast) on the
-    quantized path."""
+                 softcap: Optional[float], bk: int, nk: int, kh: int):
+    """Shared online-softmax body over one (bk, KH, d) KV block of one
+    slot.  ``load_kv(h)`` yields KV head h's (bk, d) k and v tiles — a
+    strided VMEM load on the full-width path, plus an int8-row dequant
+    (1-byte rows times a per-row scale) on the quantized path.  The
+    G = H // KH query heads of KV head h are rows [h*G, (h+1)*G) of the
+    (H, d) query block."""
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
+    g = q_ref.shape[1] // kh
 
     @pl.when(j == 0)
     def _():
@@ -60,40 +66,42 @@ def _decode_body(sl_ref, q_ref, load_kv, o_ref,
 
     @pl.when(reachable)
     def _():
-        k, v = load_kv()                             # (bk, d) each
-        q = q_ref[0].astype(jnp.float32) * scale     # (1, d)
-        s = jax.lax.dot_general(
-            q, k.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)      # (1, bk)
-        if softcap is not None:
-            s = softcap * jnp.tanh(s / softcap)
         kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
         allow = kpos < sl
         if window is not None:
             allow = jnp.logical_and(allow, (sl - 1) - kpos < window)
-        s = jnp.where(allow, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None]) * allow
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1)
-        acc_ref[...] = (acc_ref[...] * alpha[:, None]
-                        + jax.lax.dot_general(
-                            p.astype(v.dtype), v,
-                            (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32))
-        m_ref[...] = m_new
+        for h in range(kh):
+            rows = slice(h * g, (h + 1) * g)
+            k, v = load_kv(h)                        # (bk, d) each
+            q = q_ref[0, rows].astype(jnp.float32) * scale    # (G, d)
+            s = jax.lax.dot_general(
+                q, k.astype(jnp.float32), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)  # (G, bk)
+            if softcap is not None:
+                s = softcap * jnp.tanh(s / softcap)
+            s = jnp.where(allow, s, NEG_INF)
+            m_prev = m_ref[rows]                     # (G, 1)
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            p = jnp.where(allow, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[rows] = l_ref[rows] * alpha + p.sum(axis=-1, keepdims=True)
+            acc_ref[rows] = (acc_ref[rows] * alpha
+                             + jax.lax.dot_general(
+                                 p.astype(v.dtype), v,
+                                 (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32))
+            m_ref[rows] = m_new
 
     @pl.when(j == nk - 1)
     def _():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def _decode_kernel(sl_ref, q_ref, k_ref, v_ref, o_ref,
                    m_ref, l_ref, acc_ref, **kw):
     _decode_body(sl_ref, q_ref,
-                 lambda: (k_ref[0, :, 0], v_ref[0, :, 0]),
+                 lambda h: (k_ref[0, :, h, :], v_ref[0, :, h, :]),
                  o_ref, m_ref, l_ref, acc_ref, **kw)
 
 
@@ -102,11 +110,26 @@ def _decode_kernel_q(sl_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
     """int8-KV variant: k/v tiles arrive as int8 rows + per-row fp32 scales
     (models/quant.quantize_kv layout) and dequantise in VMEM right after the
     DMA — the HBM stream is 1 byte/element."""
-    def load_kv():
-        k = k_ref[0, :, 0].astype(jnp.float32) * ks_ref[0, :, 0][:, None]
-        v = v_ref[0, :, 0].astype(jnp.float32) * vs_ref[0, :, 0][:, None]
+    def load_kv(h):
+        k = (k_ref[0, :, h, :].astype(jnp.float32)
+             * ks_ref[0, :, h][:, None])
+        v = (v_ref[0, :, h, :].astype(jnp.float32)
+             * vs_ref[0, :, h][:, None])
         return k, v
     _decode_body(sl_ref, q_ref, load_kv, o_ref, m_ref, l_ref, acc_ref, **kw)
+
+
+def _scratch(H: int, d: int):
+    return [pltpu.VMEM((H, 1), jnp.float32),         # running max
+            pltpu.VMEM((H, 1), jnp.float32),         # running denominator
+            pltpu.VMEM((H, d), jnp.float32)]         # output accumulator
+
+
+def _last_block(sl, b, bk: int):
+    """Index of slot b's last valid KV block (0 for an empty slot).  Index
+    maps clamp to it, so steps past a slot's length re-name the block
+    already in VMEM and the pipeline issues no DMA for them."""
+    return jnp.maximum(sl[b] - 1, 0) // bk
 
 
 def paged_decode_attention_kernel_call(
@@ -122,9 +145,9 @@ def paged_decode_attention_kernel_call(
 
     ``seq_lens[b]`` counts the valid cache rows of slot b INCLUDING the
     just-written current token (the query attends to kv_pos < seq_lens[b]).
-    GQA handled by per-head index mapping (H % KH == 0).  The cache length S
-    is padded to a multiple of ``bk``; padded rows sit past every seq_len and
-    are never touched.
+    GQA: query heads [h*G, (h+1)*G) read KV head h (H % KH == 0).  The
+    cache length S is padded to a multiple of ``bk``; padded rows sit past
+    every seq_len and are never touched.
 
     int8 KV: pass ``k``/``v`` as int8 with per-row fp32 ``k_scale``/
     ``v_scale`` (B, S, KH) — ``models/quant.quantize_kv`` layout.  Rows
@@ -132,7 +155,6 @@ def paged_decode_attention_kernel_call(
     """
     B, H, d = q.shape
     S, KH = k.shape[1], k.shape[2]
-    G = H // KH
     quantized = k_scale is not None
     if scale is None:
         scale = d ** -0.5
@@ -148,36 +170,33 @@ def paged_decode_attention_kernel_call(
     nk = S // bk
     seq_lens = seq_lens.astype(jnp.int32)
 
-    kv_spec = pl.BlockSpec((1, bk, 1, d), lambda b, h, j, sl: (b, j, h // G, 0))
-    sc_spec = pl.BlockSpec((1, bk, 1), lambda b, h, j, sl: (b, j, h // G))
+    # one slot's whole (bk, KH, d) block per grid step: the block's last two
+    # dims are the array's own (KH, d), as the TPU tiling requires
+    def kv_map(b, j, sl):
+        return (b, jnp.minimum(j, _last_block(sl, b, bk)), 0, 0)
+
+    def sc_map(b, j, sl):
+        return kv_map(b, j, sl)[:3]
+
+    q_spec = pl.BlockSpec((1, H, d), lambda b, j, sl: (b, 0, 0))
+    kv_spec = pl.BlockSpec((1, bk, KH, d), kv_map)
+    sc_spec = pl.BlockSpec((1, bk, KH), sc_map)
+    kw = dict(scale=scale, window=window, softcap=softcap, bk=bk, nk=nk,
+              kh=KH)
     if quantized:
-        kern = functools.partial(
-            _decode_kernel_q, scale=scale, window=window, softcap=softcap,
-            bk=bk, nk=nk)
-        in_specs = [
-            pl.BlockSpec((1, 1, d), lambda b, h, j, sl: (b, h, 0)),
-            kv_spec, sc_spec, kv_spec, sc_spec,
-        ]
+        kern = functools.partial(_decode_kernel_q, **kw)
+        in_specs = [q_spec, kv_spec, sc_spec, kv_spec, sc_spec]
         operands = (q, k, k_scale, v, v_scale)
     else:
-        kern = functools.partial(
-            _decode_kernel, scale=scale, window=window, softcap=softcap,
-            bk=bk, nk=nk)
-        in_specs = [
-            pl.BlockSpec((1, 1, d), lambda b, h, j, sl: (b, h, 0)),
-            kv_spec, kv_spec,
-        ]
+        kern = functools.partial(_decode_kernel, **kw)
+        in_specs = [q_spec, kv_spec, kv_spec]
         operands = (q, k, v)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B, H, nk),
+        grid=(B, nk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, d), lambda b, h, j, sl: (b, h, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
-        ],
+        out_specs=q_spec,
+        scratch_shapes=_scratch(H, d),
     )
     fn = pl.pallas_call(
         kern,
@@ -239,48 +258,40 @@ def paged_decode_attention_bt_kernel_call(
     B, H, d = q.shape
     NB, bs, KH = k.shape[0], k.shape[1], k.shape[2]
     nk = tables.shape[1]
-    G = H // KH
     quantized = k_scale is not None
     if scale is None:
         scale = d ** -0.5
     seq_lens = seq_lens.astype(jnp.int32)
     # OOB sentinel entries (unadmitted slots) clamp to a real block: the
-    # pipeline still fetches whatever the index map names, and seq_lens=0
-    # masks the compute — mirrors the reference's clamped gather
+    # pipeline fetches whatever the index map names, and seq_lens=0 masks
+    # the compute — mirrors the reference's clamped gather
     tables = jnp.clip(tables.astype(jnp.int32), 0, NB - 1)
 
-    kv_spec = pl.BlockSpec((1, bs, 1, d),
-                           lambda b, h, j, sl, bt: (bt[b, j], 0, h // G, 0))
-    sc_spec = pl.BlockSpec((1, bs, 1),
-                           lambda b, h, j, sl, bt: (bt[b, j], 0, h // G))
+    def kv_map(b, j, sl, bt):
+        return (bt[b, jnp.minimum(j, _last_block(sl, b, bs))], 0, 0, 0)
+
+    def sc_map(b, j, sl, bt):
+        return kv_map(b, j, sl, bt)[:3]
+
+    q_spec = pl.BlockSpec((1, H, d), lambda b, j, sl, bt: (b, 0, 0))
+    kv_spec = pl.BlockSpec((1, bs, KH, d), kv_map)
+    sc_spec = pl.BlockSpec((1, bs, KH), sc_map)
+    kw = dict(scale=scale, window=window, softcap=softcap, bk=bs, nk=nk,
+              kh=KH)
     if quantized:
-        kern = functools.partial(
-            _decode_kernel_bt_q, scale=scale, window=window,
-            softcap=softcap, bk=bs, nk=nk)
-        in_specs = [
-            pl.BlockSpec((1, 1, d), lambda b, h, j, sl, bt: (b, h, 0)),
-            kv_spec, sc_spec, kv_spec, sc_spec,
-        ]
+        kern = functools.partial(_decode_kernel_bt_q, **kw)
+        in_specs = [q_spec, kv_spec, sc_spec, kv_spec, sc_spec]
         operands = (q, k, k_scale, v, v_scale)
     else:
-        kern = functools.partial(
-            _decode_kernel_bt, scale=scale, window=window, softcap=softcap,
-            bk=bs, nk=nk)
-        in_specs = [
-            pl.BlockSpec((1, 1, d), lambda b, h, j, sl, bt: (b, h, 0)),
-            kv_spec, kv_spec,
-        ]
+        kern = functools.partial(_decode_kernel_bt, **kw)
+        in_specs = [q_spec, kv_spec, kv_spec]
         operands = (q, k, v)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, H, nk),
+        grid=(B, nk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, d), lambda b, h, j, sl, bt: (b, h, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
-        ],
+        out_specs=q_spec,
+        scratch_shapes=_scratch(H, d),
     )
     fn = pl.pallas_call(
         kern,

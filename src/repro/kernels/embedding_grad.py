@@ -17,10 +17,14 @@ would be serialised per HBM channel by the Flush unit).
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 
 def _scatter_kernel(ids_ref, grads_ref, zeros_ref, out_ref):
@@ -33,7 +37,7 @@ def _scatter_kernel(ids_ref, grads_ref, zeros_ref, out_ref):
 
 
 def scatter_kernel_call(grads: jax.Array, ids: jax.Array, vocab: int, *,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: Optional[bool] = None) -> jax.Array:
     """grads (N, D), unique sorted ids (N,) i32 (-1 tail) -> (V, D) grad table."""
     N, D = grads.shape
     dtable0 = jnp.zeros((vocab, D), grads.dtype)
@@ -51,7 +55,7 @@ def scatter_kernel_call(grads: jax.Array, ids: jax.Array, vocab: int, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((vocab, D), grads.dtype),
         input_output_aliases={2: 0},   # alias the zero table (arg idx incl. ids)
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )
     return fn(ids, grads, dtable0)
 
@@ -73,7 +77,7 @@ def _fused_scatter_kernel(rows_ref, slots_ref, gout_ref, zeros_ref, out_ref):
 
 def fused_scatter_kernel_call(gout: jax.Array, rows: jax.Array,
                               slots: jax.Array, vocab: int, *,
-                              interpret: bool = True) -> jax.Array:
+                              interpret: Optional[bool] = None) -> jax.Array:
     """gout (B, K, Dm) slot grads (pre-scaled for mean combiners); rows (B, S)
     absolute fused row ids (-1 invalid); slots (S,) i32 slot per descriptor
     column -> (R, Dm) accumulated gradient over the fused row space."""
@@ -98,7 +102,9 @@ def fused_scatter_kernel_call(gout: jax.Array, rows: jax.Array,
         _fused_scatter_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((vocab, Dm), gout.dtype),
-        input_output_aliases={3: 0},   # alias the zero table (arg idx incl.
-        interpret=interpret,           # the two prefetched descriptor args)
+        # alias the zero table (arg index counts the two prefetched
+        # descriptor args)
+        input_output_aliases={3: 0},
+        interpret=resolve_interpret(interpret),
     )
     return fn(rows, slots, gout, dtable0)

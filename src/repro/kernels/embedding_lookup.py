@@ -29,11 +29,14 @@ constraint.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 
 # ---------------------------------------------------------------------------
@@ -55,7 +58,7 @@ def _gather_kernel(ids_ref, table_ref, out_ref):
 
 
 def gather_kernel_call(table: jax.Array, ids: jax.Array, *,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: Optional[bool] = None) -> jax.Array:
     """table (V, D) f32, ids (B, Vl) i32 -> (B, Vl, D) f32."""
     V, D = table.shape
     B, Vl = ids.shape
@@ -71,7 +74,7 @@ def gather_kernel_call(table: jax.Array, ids: jax.Array, *,
         _gather_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Vl, D), table.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )
     return fn(ids, table)
 
@@ -108,7 +111,7 @@ def _lookup_kernel(ids_ref, table_ref, out_ref, acc_ref, *, n_val: int,
 
 def lookup_kernel_call(table: jax.Array, ids: jax.Array, *,
                        combiner: str = "sum",
-                       interpret: bool = True) -> jax.Array:
+                       interpret: Optional[bool] = None) -> jax.Array:
     """table (V, D), ids (B, Vl) -> (B, D) combined (sum/mean over valency)."""
     V, D = table.shape
     B, Vl = ids.shape
@@ -125,7 +128,7 @@ def lookup_kernel_call(table: jax.Array, ids: jax.Array, *,
         functools.partial(_lookup_kernel, n_val=Vl, mean=(combiner == "mean")),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, D), table.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )
     return fn(ids, table)
 
@@ -207,7 +210,7 @@ def _fused_lookup_kernel_q(rows_ref, slots_ref, means_ref, table_ref,
 def fused_lookup_kernel_call(table: jax.Array, rows: jax.Array,
                              slots: jax.Array, means: jax.Array, *,
                              scales: jax.Array = None,
-                             interpret: bool = True) -> jax.Array:
+                             interpret: Optional[bool] = None) -> jax.Array:
     """One launch over every table of a fused row space.
 
     table (R, Dm); rows (B, S) absolute fused row ids (-1 invalid);
@@ -256,6 +259,6 @@ def fused_lookup_kernel_call(table: jax.Array, rows: jax.Array,
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, Dm), out_dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )
     return fn(rows, slots, means, *operands)

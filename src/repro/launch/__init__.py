@@ -6,11 +6,10 @@ programmatic surface.
 """
 from repro.launch.hlo_cost import HloCost
 from repro.launch.mesh import (make_local_mesh, make_mesh,
-                               make_production_mesh, mesh_scope,
-                               mesh_to_slice)
+                               make_production_mesh, mesh_to_slice)
 from repro.launch.roofline import Roofline, collective_bytes_from_hlo
 
 __all__ = [
     "HloCost", "Roofline", "collective_bytes_from_hlo", "make_local_mesh",
-    "make_mesh", "make_production_mesh", "mesh_scope", "mesh_to_slice",
+    "make_mesh", "make_production_mesh", "mesh_to_slice",
 ]

@@ -31,7 +31,7 @@ from repro.configs import (OptimizerConfig, ParallelConfig, get_config,
                            registry)
 from repro.launch import roofline as RL
 from repro.launch import steps as STEPS
-from repro.launch.mesh import make_production_mesh, mesh_scope
+from repro.launch.mesh import make_production_mesh
 from repro.parallel import sharding as SH
 
 RESULTS = pathlib.Path(__file__).resolve().parents[3] / "results"
@@ -54,7 +54,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, *,
     ctx = SH.make_context(mesh, pcfg)
 
     t0 = time.time()
-    with mesh_scope(mesh):
+    with jax.set_mesh(mesh):
         args, in_sh, out_sh, step = STEPS.shapes_and_shardings(
             cfg, shape, pcfg, ocfg, ctx)
         in_shardings = jax.tree.map(

@@ -12,7 +12,7 @@ the §2.7 guidance made concrete by ``mesh_to_slice``.
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 
@@ -20,26 +20,11 @@ from repro.core.topology import SliceTopology
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """Version-portable ``jax.make_mesh``.
-
-    Newer jax wants explicit ``axis_types`` (Auto); 0.4.x has no AxisType and
-    no ``axis_types`` kwarg.  Everything downstream only needs a plain mesh
-    with named axes, so fall back silently.
-    """
-    try:
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
-
-
-def mesh_scope(mesh):
-    """Context manager activating `mesh`: ``jax.set_mesh`` where it exists,
-    the legacy ``with mesh:`` trace context otherwise."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """``jax.make_mesh`` with every axis ``Auto``: the model code places
+    arrays with sharding constraints and PartitionSpecs, never with
+    explicit-sharding types."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

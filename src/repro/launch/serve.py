@@ -2,6 +2,9 @@
 
     PYTHONPATH=src python -m repro.launch.serve --arch olmo-1b --requests 8
 
+serves the published config of ``--arch``; ``--reduced`` serves its small
+test-size preset instead (what a CPU host can run).
+
 ``--chunk`` sets the multi-step decode width (tokens advanced per device
 dispatch); ``--chunk 1`` is the per-token path with identical greedy output.
 """
@@ -13,6 +16,7 @@ import numpy as np
 
 from repro.cluster import SliceSpec, Supercomputer
 from repro.configs import registry
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
 
 
@@ -30,11 +34,15 @@ def main():
     ap.add_argument("--sample", action="store_true",
                     help="temperature sampling instead of greedy decode")
     ap.add_argument("--slice", dest="slice_chips", type=int, default=256)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the small test-size preset of --arch "
+                         "instead of its published config")
     args = ap.parse_args()
 
-    cfg = registry.get_reduced(args.arch)
-    params = api.init_params(cfg, jax.random.PRNGKey(0))
+    enable_compile_cache()
+    cfg = (registry.get_reduced(args.arch) if args.reduced
+           else registry.get_config(args.arch))
+    params = jax.jit(lambda k: api.init_params(cfg, k))(jax.random.PRNGKey(0))
     sc = Supercomputer()
     with sc.allocate(args.slice_chips) as sl:
         session = sl.serve(cfg, params,

@@ -193,7 +193,6 @@ def make_train_step(cfg: ModelConfig, shape: ShapeConfig,
                    and shape.global_batch % (ndp * accum) == 0)
 
     def dp_step(params, batch):
-        from repro.parallel.context import shard_map
         axes = tuple(ctx.batch_axes)
 
         def body(p, b):
@@ -202,9 +201,10 @@ def make_train_step(cfg: ModelConfig, shape: ShapeConfig,
             metrics = {k: _pmean(v, axes) for k, v in metrics.items()}
             return g, metrics
 
-        return shard_map(body, mesh=ctx.mesh,
-                         in_specs=(P(), P(axes)),
-                         out_specs=(P(), P()))(params, batch)
+        return jax.shard_map(body, mesh=ctx.mesh,
+                             in_specs=(P(), P(axes)),
+                             out_specs=(P(), P()),
+                             check_vma=False)(params, batch)
 
     def train_step(params, opt_state, batch):
         if dp_exchange:

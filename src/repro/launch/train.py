@@ -4,14 +4,14 @@
         --reduced --batch 8 --seq 64
 
 Full-scale configs (--arch without --reduced) target the production mesh and
-are what the dry-run lowers; on this CPU container use --reduced.
+are what the dry-run lowers; on a CPU host use --reduced.
 """
 import argparse
 import json
 
-
 from repro.configs import (OptimizerConfig, ParallelConfig, RunConfig,
                            ShapeConfig, registry)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train.trainer import Trainer
 
 
@@ -34,6 +34,7 @@ def main():
                     choices=["adam", "sgd", "adafactor"])
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = (registry.get_reduced(args.arch) if args.reduced
            else registry.get_config(args.arch))
     from repro.launch.mesh import make_local_mesh

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig, MoEConfig
-from repro.parallel.context import LOCAL, ParallelContext, shard_map
+from repro.parallel.context import LOCAL, ParallelContext
 
 P = jax.sharding.PartitionSpec
 
@@ -303,7 +303,7 @@ def moe_ep(cfg: ModelConfig, p, x, ctx: ParallelContext, *,
                 {"wg": w_specs["shared"]["wg"], "wu": w_specs["shared"]["wu"],
                  "wo": w_specs["shared"]["wo"]})
     out_specs = (P(batch_spec, seq_spec, None), P(), P())
-    fn = shard_map(local_fn, mesh=ctx.mesh, in_specs=in_specs,
+    fn = jax.shard_map(local_fn, mesh=ctx.mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
     return fn(x, args["router"], args["wg"], args["wu"], args["wi"],
               args["wo"], args["shared"])
@@ -395,7 +395,7 @@ def moe_decode(cfg: ModelConfig, p, x, ctx: ParallelContext, *,
                 P(axis, None, fs),
                 {"wg": P(fs, None), "wu": P(fs, None), "wo": P(None, fs)})
     out_specs = (P(batch_spec, None, None), P(), P())
-    fn = shard_map(local_fn, mesh=ctx.mesh, in_specs=in_specs,
+    fn = jax.shard_map(local_fn, mesh=ctx.mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
     shared = p.get("shared", {"wg": None, "wu": None, "wo": None})
     return fn(x, p["router"], p.get("wg"), p.get("wu"), p.get("wi"),

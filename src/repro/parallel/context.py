@@ -83,27 +83,6 @@ LOCAL = ParallelContext(mesh=None, pod_axis=None, data_axis=None,
                         model_axis=None, fsdp=False)
 
 
-def lax_axis_size(axis) -> int:
-    """Static size of a mapped axis inside shard_map: ``jax.lax.axis_size``
-    where it exists; on 0.4.x recover it from an all_gather's trace-time
-    shape (the gathered value is unused, so XLA dead-code-eliminates it)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis)
-    import jax.numpy as jnp
-    return jax.lax.all_gather(jnp.zeros((1,), jnp.float32), axis).shape[0]
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """Version-portable shard_map: ``jax.shard_map`` (new API, check_vma) or
-    ``jax.experimental.shard_map`` (0.4.x, check_rep)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
-
-
 # ---------------------------------------------------------------------------
 # Trace-time activation sharding hints
 # ---------------------------------------------------------------------------

@@ -24,8 +24,6 @@ from typing import Callable, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
-from repro.parallel.context import lax_axis_size
-
 P = jax.sharding.PartitionSpec
 
 
@@ -55,7 +53,7 @@ def overlapped_matmul_ag(x_shard, w, axis: str):
     """x_shard: (m_local, k); w: (k, n) local weight shard of a matmul whose
     LHS is row-sharded over `axis`.  Computes all_gather(x) @ w with the
     gather decomposed into size-1 ring hops (runs inside shard_map)."""
-    s = lax_axis_size(axis)
+    s = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     m_l = x_shard.shape[0]
     perm_fwd = [(i, (i + 1) % s) for i in range(s)]
@@ -81,7 +79,7 @@ def overlapped_matmul_rs(x, w_shard, axis: str):
     activations, w_shard (k_local, n): each step computes one output block
     and passes the partial around the ring (ring reduce-scatter fused with
     the matmul)."""
-    s = lax_axis_size(axis)
+    s = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     m = x.shape[0]
     assert m % s == 0

@@ -16,8 +16,6 @@ from typing import Any, Callable, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.parallel.context import shard_map
-
 P = jax.sharding.PartitionSpec
 
 
@@ -72,7 +70,7 @@ def pipeline_apply(layer_fn: Callable, params_stacked, x, *, mesh,
             jnp.where(stage == S - 1, out, jnp.zeros_like(out)), stage_axis)
         return out.reshape(x_local.shape)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(stage_axis), P()),
         out_specs=P(), check_vma=False)

@@ -31,7 +31,6 @@ from repro.configs.base import (ModelConfig, OptimizerConfig, ParallelConfig,
 from repro.core.scheduler import SliceScheduler
 from repro.data.synthetic import Dataset
 from repro.launch import steps as STEPS
-from repro.launch.mesh import mesh_scope
 from repro.models import api
 from repro.obs import Telemetry
 from repro.optim import adam as OPT
@@ -85,7 +84,7 @@ class Trainer:
         self._series = self.obs.metrics.series("train.metrics",
                                                **self._obs_labels)
 
-        with mesh_scope(mesh):
+        with jax.set_mesh(mesh):
             # ONE step builder for every entry point (shapes_and_shardings
             # -> make_train_step), so ParallelConfig knobs — notably
             # grad_compression — can't silently apply on one path only
@@ -120,7 +119,7 @@ class Trainer:
     def init_state(self) -> TrainerState:
         """Fresh params + optimizer state at step 0 (seeded by the run)."""
         key = jax.random.PRNGKey(self.run.seed)
-        with mesh_scope(self.mesh):
+        with jax.set_mesh(self.mesh):
             params = jax.jit(
                 lambda: api.init_params(self.run.model, key, self.ctx),
                 out_shardings=self._in_sh[0])()
@@ -200,9 +199,10 @@ class Trainer:
             cooperative-eviction path without a cluster driver).
           scheduler/job_id: OCS scheduler wiring for the fault drill.
           log_every: metric logging period.
-          on_step: called after every executed step with
-            ``(step, step_wall_s)`` — the hook the straggler detector
-            rides (`TrainSession.run` feeds per-block step times from it).
+          on_step: called after every executed step, once its results are
+            ready, with ``(step, step_wall_s)`` — the hook the straggler
+            detector rides (`TrainSession.run` feeds per-block step times
+            from it).
 
         Returns the final `TrainerState`.  If a preemption request arrived
         (externally or via ``preempt_at``), the loop checkpointed, set
@@ -245,12 +245,15 @@ class Trainer:
             with self.obs.span("train.step", cat="train", track="train",
                                step=step):
                 batch = self._put_batch(step)
-                with mesh_scope(self.mesh):
+                with jax.set_mesh(self.mesh):
                     params, opt, metrics = self.train_step(
                         state.params, state.opt_state, batch)
             state = TrainerState(params, opt, step + 1)
             step += 1
             if on_step is not None:
+                # dispatch is asynchronous: wait for the step to finish so
+                # the hook sees its run time, not the dispatch latency
+                jax.block_until_ready((params, metrics))
                 on_step(step, time.perf_counter() - t_step)
             if step % log_every == 0 or step == num_steps:
                 m = {k: float(v) for k, v in metrics.items()}

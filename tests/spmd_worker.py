@@ -27,11 +27,11 @@ from repro.models import api
 from repro.models import moe as MOE
 from repro.optim import adam as OPT
 from repro.parallel import sharding as SH
-from repro.parallel.context import ParallelContext, shard_map
+from repro.parallel.context import ParallelContext
 from repro.parallel.overlap import overlapped_matmul_ag, overlapped_matmul_rs
 from repro.parallel.pipeline import pipeline_apply
 
-from repro.launch.mesh import make_mesh, mesh_scope
+from repro.launch.mesh import make_mesh
 
 P = jax.sharding.PartitionSpec
 
@@ -60,14 +60,14 @@ feats = {"big": jax.random.randint(jax.random.PRNGKey(1), (16, 4), -1, 4096,
                                     jnp.int32)}
 want = lookup_reference(materialize_tables(coll, params), specs, feats)
 for method in ("psum", "a2a"):
-    with mesh_scope(mesh):
+    with jax.set_mesh(mesh):
         out = jax.jit(lambda p, f: coll.lookup(p, f, ctx, method=method))(
             params, feats)
     ok = all(np.allclose(np.asarray(out[k]), np.asarray(want[k]),
                          rtol=1e-5, atol=1e-6) for k in out)
     check(f"embedding_{method}_matches_oracle", ok)
 
-with mesh_scope(mesh):
+with jax.set_mesh(mesh):
     g = jax.jit(jax.grad(lambda p: sum(
         jnp.sum(v ** 2) for v in coll.lookup(p, feats, ctx,
                                              method="a2a").values())))(params)
@@ -80,7 +80,7 @@ check("embedding_a2a_grads_match_local", ok)
 # ---- 1b. pipeline v2 parity: pipelined / per-group / psum / cached ---------
 from repro.embeddings.cache import HotIdCache
 
-with mesh_scope(mesh):
+with jax.set_mesh(mesh):
     out_pipe = jax.jit(lambda p, f: coll.lookup(p, f, ctx, method="a2a",
                                                 fused=True))(params, feats)
     out_legacy = jax.jit(lambda p, f: coll.lookup(p, f, ctx, method="a2a",
@@ -90,7 +90,7 @@ ok = all(np.array_equal(np.asarray(out_pipe[k]), np.asarray(out_legacy[k]))
          for k in out_pipe)
 check("embedding_pipelined_bitwise_matches_pergroup", ok)
 
-with mesh_scope(mesh):
+with jax.set_mesh(mesh):
     out_psum = jax.jit(lambda p, f: coll.lookup(p, f, ctx,
                                                 method="psum"))(params,
                                                                 feats)
@@ -108,7 +108,7 @@ for _dim, _g in sorted(coll.groups.items()):
         _ids = np.asarray(feats[_s.spec.name])
         cache.observe(_g.name, np.where(_ids >= 0, _ids + _s.offset, -1))
 cache.refresh_all(coll, params)
-with mesh_scope(mesh):
+with jax.set_mesh(mesh):
     out_cached = jax.jit(
         lambda p, f, c: coll.lookup(p, f, ctx, method="a2a", cache=c))(
         params, feats, cache.arrays())
@@ -129,7 +129,7 @@ cfg = registry.get_reduced("qwen3-moe-30b-a3b")
 pm = MOE.moe_init(cfg, jax.random.PRNGKey(3))
 x = jax.random.normal(jax.random.PRNGKey(4), (8, 16, cfg.d_model),
                       jnp.float32) * 0.3
-with mesh_scope(mesh):
+with jax.set_mesh(mesh):
     out_ep, aux_ep, _ = jax.jit(
         lambda p, x: MOE.moe_ep(cfg, p, x.astype(jnp.bfloat16), ctx,
                                 batch_spec=("data",), seq_spec="model",
@@ -159,7 +159,7 @@ for arch in ("olmo-1b", "hymba-1.5b"):
                                    accum_steps=2)
     _, _, m_l = jax.jit(step_l)(params, opt, batch)
     # sharded
-    with mesh_scope(mesh):
+    with jax.set_mesh(mesh):
         args, in_sh, out_sh, step_s = STEPS.shapes_and_shardings(
             rcfg, shape, pcfg, ocfg, sctx)
         step_s = STEPS.make_train_step(rcfg, shape, pcfg, ocfg, sctx,
@@ -185,7 +185,7 @@ pre = {"tokens": jax.random.randint(key, (8, 16), 0, rcfg.vocab_size,
 logits_l, cache_l = api.prefill(rcfg, params, pre, max_len=24)
 tok = jnp.zeros((8,), jnp.int32)
 dl, _ = api.decode_step(rcfg, params, cache_l, tok)
-with mesh_scope(mesh):
+with jax.set_mesh(mesh):
     from repro.parallel.context import activate
     def dstep(p, c, t):
         with activate(sctx):
@@ -198,17 +198,18 @@ check("decode_sharded_matches_local", ok)
 # ---- 5. overlap decomposition ------------------------------------------------
 w = jax.random.normal(jax.random.PRNGKey(11), (16, 8))
 xs = jax.random.normal(jax.random.PRNGKey(12), (8, 16))
-with mesh_scope(mesh):
-    yag = shard_map(lambda xs_, w_: overlapped_matmul_ag(xs_, w_, "model"),
-                    mesh=mesh, in_specs=(P("model", None), P()),
-                    out_specs=P(), check_vma=False)(xs, w)
+with jax.set_mesh(mesh):
+    yag = jax.shard_map(
+        lambda xs_, w_: overlapped_matmul_ag(xs_, w_, "model"),
+        mesh=mesh, in_specs=(P("model", None), P()),
+        out_specs=P(), check_vma=False)(xs, w)
 check("overlap_allgather_matmul", np.allclose(np.asarray(yag),
                                               np.asarray(xs @ w), rtol=2e-5,
                                               atol=2e-5))
 wrs = jax.random.normal(jax.random.PRNGKey(13), (16, 8))
 xrs = jax.random.normal(jax.random.PRNGKey(14), (8, 16))
-with mesh_scope(mesh):
-    yrs = shard_map(
+with jax.set_mesh(mesh):
+    yrs = jax.shard_map(
         lambda x_, w_: overlapped_matmul_rs(x_, w_, "model"),
         mesh=mesh, in_specs=(P(None, "model"), P("model", None)),
         out_specs=P("model", None), check_vma=False)(xrs, wrs)
@@ -220,7 +221,7 @@ mesh_p = make_mesh((4, 2), ("stage", "x"))
 S = 4
 Ws = jax.random.normal(jax.random.PRNGKey(15), (S, 16, 16)) * 0.1
 xp = jax.random.normal(jax.random.PRNGKey(16), (8, 16))
-with mesh_scope(mesh_p):
+with jax.set_mesh(mesh_p):
     y = pipeline_apply(lambda w, x: jnp.tanh(x @ w), Ws, xp, mesh=mesh_p,
                        stage_axis="stage", microbatches=4)
 refp = xp
@@ -237,8 +238,8 @@ xs8 = np.asarray(jax.random.normal(jax.random.PRNGKey(21), (8, 256),
                                    jnp.float32))
 ref_mean = xs8.mean(axis=0, keepdims=True)
 
-with mesh_scope(mesh_d):
-    out8 = shard_map(
+with jax.set_mesh(mesh_d):
+    out8 = jax.shard_map(
         lambda g: COMP.compressed_allreduce(g, "int8", ("data",)),
         mesh=mesh_d, in_specs=P("data", None), out_specs=P(),
         check_vma=False)(jnp.asarray(xs8))
@@ -251,8 +252,8 @@ sp = np.zeros_like(xs8)
 for d in range(8):
     idx = np.argsort(-np.abs(xs8[d]), kind="stable")[:k]
     sp[d, idx] = xs8[d, idx]
-with mesh_scope(mesh_d):
-    outk = shard_map(
+with jax.set_mesh(mesh_d):
+    outk = jax.shard_map(
         lambda g: COMP.compressed_allreduce(g, "topk", ("data",)),
         mesh=mesh_d, in_specs=P("data", None), out_specs=P(),
         check_vma=False)(jnp.asarray(xs8))
@@ -275,7 +276,7 @@ from repro.parallel.context import LOCAL as _LOCAL
 step_l = STEPS.make_train_step(rcfg, shape_c, ParallelConfig(remat="none"),
                                OptimizerConfig(), _LOCAL, accum_steps=1)
 _, _, m_l = jax.jit(step_l)(params, opt, batch)
-with mesh_scope(mesh_d):
+with jax.set_mesh(mesh_d):
     step_c = STEPS.make_train_step(rcfg, shape_c, pcfg_c, OptimizerConfig(),
                                    sctx_d, accum_steps=1)
     _, _, m_c = jax.jit(step_c)(params, opt, batch)
