@@ -1,0 +1,6 @@
+"""Share of the traced window in which no operation ran on the device,
+while serving (averaged over the chips)."""
+
+
+def read(summary, job, out):
+    return 100.0 * summary.idle_share
