@@ -11,9 +11,14 @@ One `Telemetry` object is the handle every subsystem takes:
 
 Cost model (the tentpole's contract):
 
-  * **tracing** is opt-in (`tracing=False` default → the shared
-    `NOOP_TRACER`; `obs.span(...)` returns one reusable null context,
-    `complete`/`begin`/`end` are no-ops) — zero-cost when disabled;
+  * **profiler spans**: every `obs.span(...)` is also a
+    `jax.profiler.TraceAnnotation` over the same extent, with the span's
+    args as typed stats.  The profiler keeps it only while a session
+    records (`jax.profiler.start_trace`), on the device trace's clock;
+    otherwise it costs under a microsecond;
+  * **tracing** (the in-memory tracer) is opt-in (`tracing=False` default
+    → the shared `NOOP_TRACER`; `complete`/`begin`/`end` are no-ops and
+    spans record nothing in memory);
   * **metrics** and the **flight recorder** are always on — an `inc` is
     one int add, a flight record one deque append — cheap enough that
     drop accounting and postmortems never depend on a debug flag.
@@ -27,8 +32,11 @@ for code paths constructed without an explicit handle.
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import Any, Dict, Optional
+
+from jax.profiler import TraceAnnotation
 
 from .flight import DEFAULT_CAPACITY, FlightRecorder
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry, Series)
@@ -78,7 +86,12 @@ class Telemetry:
 
     def span(self, name: str, cat: str = "", track: Optional[str] = None,
              **args):
-        return self.tracer.span(name, cat, track, **args)
+        """Scoped work: a profiler annotation named ``name`` with ``args``
+        as its stats, and with tracing on also a span of the tracer's."""
+        ann = TraceAnnotation(name, **args)
+        if not self.tracer.enabled:
+            return ann
+        return _both(ann, self.tracer.span(name, cat, track, **args))
 
     def complete(self, name: str, t0: float, t1: float, cat: str = "",
                  track: Optional[str] = None, **args):
@@ -116,6 +129,12 @@ class Telemetry:
 
     def dump_metrics(self) -> Dict[str, Any]:
         return self.metrics.dump()
+
+
+@contextlib.contextmanager
+def _both(ann, ctx):
+    with ann, ctx as span:
+        yield span
 
 
 NULL_OBS = Telemetry(tracing=False)

@@ -150,7 +150,6 @@ class MetricsRegistry:
         reg = MetricsRegistry()
         reg.counter("fleet.drops", reason="wait_queue_full").inc()
         reg.gauge("machine.block_slowdown", block=3).set(2.0)
-        reg.histogram("serve.chunk_s").observe(0.011)
         reg.dump()   # {"fleet.drops{reason=wait_queue_full}": 1, ...}
     """
 

@@ -291,7 +291,6 @@ class ServeEngine:
             "serve.kv_migrated_shared_blocks", **labels)
         self._c_mig_suffix = reg.counter(
             "serve.kv_migrated_suffix_blocks", **labels)
-        self._h_chunk = reg.histogram("serve.chunk_s", **labels)
 
         if self._pooled:
             assert cfg.family == "dense", \
@@ -322,7 +321,8 @@ class ServeEngine:
         happens on a later `step`/`step_chunk`).  The prompt is truncated
         to the last `spec.prompt_len` tokens at prefill."""
         r = Request(rid=self._next_rid, prompt=np.asarray(prompt, np.int32),
-                    max_new_tokens=max_new_tokens, t_submit=time.time())
+                    max_new_tokens=max_new_tokens,
+                    t_submit=time.perf_counter())
         self._next_rid += 1
         self.queue.append(r)
         self.pending.append(r)
@@ -354,36 +354,49 @@ class ServeEngine:
         n = min(len(self.pending), len(free))
         if n == 0:
             return False
-        if self.cache is None:
-            self.cache = api.init_cache(self.cfg, self.slots, self.max_len)
-        admitted = self.pending[:n]
-        del self.pending[:n]
-        slots = np.full((self.slots,), self.slots, np.int32)  # OOB sentinel
-        slots[:n] = free[:n]
-        prompts = np.zeros((self.slots, self.prompt_len), np.int32)
-        for row, (slot, r) in enumerate(zip(slots[:n], admitted)):
-            self.active[slot] = r
-            seq = r.prompt[-self.prompt_len:]
-            prompts[row, -len(seq):] = seq
-        rids = np.zeros((self.slots,), np.int32)
-        rids[:n] = [r.rid for r in admitted]
-        self._c_prefill.inc(self.prompt_len * self.slots)
-        batch = {"tokens": jnp.asarray(prompts),
-                 **self._extra_inputs(self.slots)}
-        nxt, self.cache, self.seq_lens, self.last_tokens, self.sample_salt = \
-            self._admit_fn(self.params, self.cache, batch,
-                           jnp.asarray(slots), jnp.asarray(rids),
-                           self.seq_lens, self.last_tokens,
-                           self.sample_salt)
-        nxt = np.asarray(nxt)
-        now = time.time()
+        with self.obs.span("serve.admit", requests=n):
+            self._admit_dense(free[:n])
+        return True
+
+    def _admit_dense(self, free: List[int]) -> None:
+        n = len(free)
+        with self.obs.span("serve.admit.plan"):
+            if self.cache is None:
+                self.cache = api.init_cache(self.cfg, self.slots,
+                                            self.max_len)
+            admitted = self.pending[:n]
+            del self.pending[:n]
+            # padding rows keep the out-of-bounds sentinel slot
+            slots = np.full((self.slots,), self.slots, np.int32)
+            slots[:n] = free
+            prompts = np.zeros((self.slots, self.prompt_len), np.int32)
+            tokens = 0
+            for row, (slot, r) in enumerate(zip(slots[:n], admitted)):
+                self.active[slot] = r
+                seq = r.prompt[-self.prompt_len:]
+                prompts[row, -len(seq):] = seq
+                tokens += len(seq)
+            rids = np.zeros((self.slots,), np.int32)
+            rids[:n] = [r.rid for r in admitted]
+            self._c_prefill.inc(self.prompt_len * self.slots)
+            batch = {"tokens": jnp.asarray(prompts),
+                     **self._extra_inputs(self.slots)}
+            slots, rids = jnp.asarray(slots), jnp.asarray(rids)
+        with self.obs.span("serve.admit.prefill", tokens=tokens,
+                           width=self.slots * self.prompt_len):
+            nxt, self.cache, self.seq_lens, self.last_tokens, \
+                self.sample_salt = self._admit_fn(
+                    self.params, self.cache, batch, slots, rids,
+                    self.seq_lens, self.last_tokens, self.sample_salt)
+        with self.obs.span("serve.admit.sync"):
+            nxt = np.asarray(nxt)
+        now = time.perf_counter()
         for row, r in enumerate(admitted):
             r.out_tokens.append(int(nxt[row]))
             r.t_first = now
             if len(r.out_tokens) >= r.max_new_tokens:
                 r.done = True
                 r.t_done = now
-        return True
 
     def _admit_pooled(self) -> bool:
         """Pooled admission: map each admitted prompt's shared prefix onto
@@ -400,14 +413,24 @@ class ServeEngine:
         n = min(len(self.pending), len(free))
         if n == 0:
             return False
+        with self.obs.span("serve.admit", requests=n):
+            with self.obs.span("serve.admit.plan"):
+                rows = self._plan_pooled(free[:n])
+            self._prefill_pooled(rows)
+        return True
+
+    def _plan_pooled(self, free: List[int]) -> list:
+        """Seat the first pending requests in the ``free`` slots, map their
+        prompts onto pool blocks and upload the tables; returns
+        ``(slot, request, start, seq)`` per admitted request."""
         if self.cache is None:
             self.cache = api.init_kv_pool(
                 self.cfg, self.kvpool.num_blocks, self.spec.kv_block)
-        admitted = self.pending[:n]
-        del self.pending[:n]
+        admitted = self.pending[:len(free)]
+        del self.pending[:len(free)]
         bs = self.spec.kv_block
-        rows = []                              # (slot, request, start, seq)
-        for slot, r in zip(free[:n], admitted):
+        rows = []
+        for slot, r in zip(free, admitted):
             self.active[slot] = r
             seq = np.asarray(r.prompt, np.int32)[-self.prompt_len:]
             table, matched = self.kvpool.admit(
@@ -417,6 +440,11 @@ class ServeEngine:
             self._c_kv_shared.inc(matched * bs)
             rows.append((slot, r, matched * bs, seq))
         self.tables = jnp.asarray(self._tables_np)
+        return rows
+
+    def _prefill_pooled(self, rows: list) -> None:
+        """Prefill the admitted suffixes in ``suffix_len``-token dispatches
+        and hand each request its first token."""
         Tc = self._suffix_len
         nchunk = max(1, -(-max(len(seq) - start
                                for (_, _, start, seq) in rows) // Tc))
@@ -439,17 +467,20 @@ class ServeEngine:
                     tok[slot, :v] = seq[s0:s0 + v]
                     commit[slot] = s0 + v == len(seq)
             self._c_prefill.inc(Tc * self.slots)
-            nxt, self.cache, self.seq_lens, self.last_tokens, \
-                self.sample_salt = self._admit_fn(
-                    self.params, self.cache, jnp.asarray(tok),
-                    jnp.asarray(st), jnp.asarray(vd), self.tables,
-                    jnp.asarray(rids), jnp.asarray(plens),
-                    jnp.asarray(commit), self.seq_lens, self.last_tokens,
-                    self.sample_salt)
+            with self.obs.span("serve.admit.prefill", tokens=int(vd.sum()),
+                               width=self.slots * Tc):
+                nxt, self.cache, self.seq_lens, self.last_tokens, \
+                    self.sample_salt = self._admit_fn(
+                        self.params, self.cache, jnp.asarray(tok),
+                        jnp.asarray(st), jnp.asarray(vd), self.tables,
+                        jnp.asarray(rids), jnp.asarray(plens),
+                        jnp.asarray(commit), self.seq_lens,
+                        self.last_tokens, self.sample_salt)
             if commit.any():
-                nxt_np = np.asarray(nxt)
+                with self.obs.span("serve.admit.sync"):
+                    nxt_np = np.asarray(nxt)
                 nxt_keep[commit] = nxt_np[commit]
-        now = time.time()
+        now = time.perf_counter()
         for slot, r, start, seq in rows:
             r.out_tokens.append(int(nxt_keep[slot]))
             r.t_first = now
@@ -458,7 +489,6 @@ class ServeEngine:
                 r.t_done = now
             if self.spec.kv_share:
                 self.kvpool.publish(slot)
-        return True
 
     def _budgets(self) -> np.ndarray:
         """Decode tokens still owed per slot.  Requests longer than the
@@ -475,31 +505,31 @@ class ServeEngine:
         """One device dispatch advancing every live slot up to ``num_steps``
         tokens; host-side bookkeeping runs once on the returned chunk."""
         budgets = self._budgets()
-        t0 = time.perf_counter()
-        if self._pooled:
-            toks, self.cache, self.seq_lens, self.last_tokens = \
-                self._decode_fn(
-                    self.params, self.cache, self.last_tokens,
-                    self.seq_lens, jnp.asarray(budgets), self._sample_key,
-                    self.sample_salt, self.tables, num_steps)
-        else:
-            toks, self.cache, self.seq_lens, self.last_tokens = \
-                self._decode_fn(
-                    self.params, self.cache, self.last_tokens,
-                    self.seq_lens, jnp.asarray(budgets), self._sample_key,
-                    self.sample_salt, num_steps)
-        toks = np.asarray(toks)                      # (num_steps, B) — syncs
-        self._record_latency(time.perf_counter() - t0)
-        self._steps += num_steps
-        now = time.time()
-        for i, r in enumerate(self.active):
-            got = int(min(budgets[i], num_steps))
-            if r is None or r.done or got == 0:
-                continue
-            r.out_tokens.extend(int(t) for t in toks[:got, i])
-            if budgets[i] <= got:                    # budget met this chunk
-                r.done = True
-                r.t_done = now
+        live = int(np.count_nonzero(budgets))
+        with self.obs.span("serve.decode", live=live, steps=num_steps):
+            t0 = time.perf_counter()
+            with self.obs.span("serve.decode.dispatch"):
+                extra = (self.tables,) if self._pooled else ()
+                toks, self.cache, self.seq_lens, self.last_tokens = \
+                    self._decode_fn(
+                        self.params, self.cache, self.last_tokens,
+                        self.seq_lens, jnp.asarray(budgets),
+                        self._sample_key, self.sample_salt, *extra,
+                        num_steps)
+            with self.obs.span("serve.decode.sync"):
+                toks = np.asarray(toks)              # (num_steps, B)
+            self._record_latency(time.perf_counter() - t0)
+            self._steps += num_steps
+            with self.obs.span("serve.decode.bookkeeping"):
+                now = time.perf_counter()
+                for i, r in enumerate(self.active):
+                    got = int(min(budgets[i], num_steps))
+                    if r is None or r.done or got == 0:
+                        continue
+                    r.out_tokens.extend(int(t) for t in toks[:got, i])
+                    if budgets[i] <= got:            # budget met this chunk
+                        r.done = True
+                        r.t_done = now
 
     def _n_active(self) -> int:
         return sum(1 for r in self.active
@@ -568,7 +598,6 @@ class ServeEngine:
 
     def _record_latency(self, lat: float) -> None:
         self.chunk_lat_s.append(lat)
-        self._h_chunk.observe(lat)
         # `run` resets the list per batch, but a fleet replica steps chunk
         # by chunk for the service's lifetime — bound the history so a
         # long-lived engine doesn't leak (EMA carries the tail)
@@ -599,11 +628,13 @@ class ServeEngine:
         number of still-active requests.  The single-dispatch quantum fleet
         replicas advance by — same dataflow as `run`, externally paced."""
         if self._fast:
-            self._admit()
-            if self._n_active() == 0:
-                return 0
-            self._decode_chunk(self.spec.chunk)
-            return self._n_active()
+            with self.obs.span("serve.step_chunk", live=self._n_active(),
+                               pending=len(self.pending)):
+                self._admit()
+                if self._n_active() == 0:
+                    return 0
+                self._decode_chunk(self.spec.chunk)
+                return self._n_active()
         self._admit()
         n = 0
         for _ in range(self.spec.chunk):
@@ -712,12 +743,16 @@ class ServeEngine:
         """Serve until the queue drains; returns latency/throughput stats."""
         self.chunk_lat_s = []
         self._steps = 0
-        t0 = time.time()
+        t0 = time.perf_counter()
         if self._fast:
             while self._steps < max_steps:
                 self._admit()
                 if self._n_active() == 0:
-                    break
+                    # an admission whose requests all finished at once
+                    # leaves slots free for the ones still pending
+                    if not self.pending:
+                        break
+                    continue
                 # always dispatch the full chunk: num_steps is static, so a
                 # data-dependent remainder would recompile the decode
                 # program mid-serve (budgets absorb any overshoot)
@@ -729,7 +764,7 @@ class ServeEngine:
                         break
                     if not self._admit():
                         break
-        wall = time.time() - t0
+        wall = time.perf_counter() - t0
         done = [r for r in self.queue if r.done]
         produced = sum(len(r.out_tokens) for r in done)
         # latency stats cover only THIS run's completions — a prior warmup
@@ -777,7 +812,7 @@ class ServeEngine:
                 (self.slots, self.prompt_len, self.cfg.d_model), jnp.float32)
         logits, self.cache = self._prefill(self.params, batch)
         nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-        now = time.time()
+        now = time.perf_counter()
         for i, r in enumerate(self.active):
             if r is not None and not r.done:
                 r.out_tokens.append(int(nxt[i]))
@@ -797,7 +832,7 @@ class ServeEngine:
         self._record_latency(time.perf_counter() - t0)
         self._steps += 1
         n_active = 0
-        now = time.time()
+        now = time.perf_counter()
         for i, r in enumerate(self.active):
             if r is None or r.done:
                 continue

@@ -135,11 +135,13 @@ class Trainer:
         stream."""
         if not self.ckpt_dir:
             return
-        CKPT.save(self.ckpt_dir, state.step,
-                  {"params": state.params, "opt": state.opt_state},
-                  extra={"step": state.step, "data_seed": self.run.seed,
-                         "slice_dims": (list(self.slice_dims)
-                                        if self.slice_dims else None)})
+        with self.obs.span("train.save", cat="train", track="train",
+                           step=state.step):
+            CKPT.save(self.ckpt_dir, state.step,
+                      {"params": state.params, "opt": state.opt_state},
+                      extra={"step": state.step, "data_seed": self.run.seed,
+                             "slice_dims": (list(self.slice_dims)
+                                            if self.slice_dims else None)})
 
     def request_preempt(self) -> None:
         """Cooperative preemption: ask the running loop to checkpoint and
@@ -179,6 +181,20 @@ class Trainer:
         batch = self.dataset.batch(step)
         return jax.device_put(batch, self._in_sh[2])
 
+    def _log(self, metrics, step: int, wall_s: float) -> None:
+        """Read a step's metrics back (the loop's one wait for the device)
+        into the series."""
+        with self.obs.span("train.log", cat="train", track="train"):
+            m = {k: float(v) for k, v in metrics.items()}
+        m.update(step=step, wall_s=round(wall_s, 2))
+        self._series.append(m)
+        # wire accounting rides the registry too: last-observed
+        # per-step payload bytes from the compressed collectives
+        for k in ("wire_bytes", "wire_bytes_full", "wire_overhead_bytes"):
+            if k in m:
+                self.obs.metrics.gauge(
+                    f"train.{k}", **self._obs_labels).set(m[k])
+
     def train(self, num_steps: int, *, state: Optional[TrainerState] = None,
               fail_at: Optional[int] = None,
               preempt_at: Optional[int] = None,
@@ -209,7 +225,7 @@ class Trainer:
         `preempted`, and returned early — the caller frees the slice and
         resumes later from the checkpoint, on any slice shape."""
         state = state or self.restore() or self.init_state()
-        t0 = time.time()
+        t0 = time.perf_counter()
         step = state.step
         self.preempted = False
         while step < num_steps:
@@ -244,28 +260,23 @@ class Trainer:
             t_step = time.perf_counter()
             with self.obs.span("train.step", cat="train", track="train",
                                step=step):
-                batch = self._put_batch(step)
-                with jax.set_mesh(self.mesh):
+                with self.obs.span("train.batch", cat="train",
+                                   track="train"):
+                    batch = self._put_batch(step)
+                with self.obs.span("train.dispatch", cat="train",
+                                   track="train"), jax.set_mesh(self.mesh):
                     params, opt, metrics = self.train_step(
                         state.params, state.opt_state, batch)
-            state = TrainerState(params, opt, step + 1)
-            step += 1
-            if on_step is not None:
-                # dispatch is asynchronous: wait for the step to finish so
-                # the hook sees its run time, not the dispatch latency
-                jax.block_until_ready((params, metrics))
-                on_step(step, time.perf_counter() - t_step)
-            if step % log_every == 0 or step == num_steps:
-                m = {k: float(v) for k, v in metrics.items()}
-                m.update(step=step, wall_s=round(time.time() - t0, 2))
-                self._series.append(m)
-                # wire accounting rides the registry too: last-observed
-                # per-step payload bytes from the compressed collectives
-                for k in ("wire_bytes", "wire_bytes_full",
-                          "wire_overhead_bytes"):
-                    if k in m:
-                        self.obs.metrics.gauge(
-                            f"train.{k}", **self._obs_labels).set(m[k])
+                state = TrainerState(params, opt, step + 1)
+                step += 1
+                if on_step is not None:
+                    # dispatch is asynchronous: wait for the step to finish
+                    # so the hook sees its run time, not the dispatch
+                    # latency
+                    jax.block_until_ready((params, metrics))
+                    on_step(step, time.perf_counter() - t_step)
+                if step % log_every == 0 or step == num_steps:
+                    self._log(metrics, step, time.perf_counter() - t0)
             if self.ckpt_dir and step % self.ckpt_every == 0:
                 self.save(state)
         if self.preempt_requested:

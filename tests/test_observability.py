@@ -167,11 +167,42 @@ class TestTelemetry:
         obs = Telemetry()
         assert obs.tracer is NOOP_TRACER
         assert not obs.tracing
-        ctx = obs.span("anything")
-        assert ctx is NOOP_TRACER.span("x")     # one shared null context
+        # the no-op tracer hands out one shared null context; the handle's
+        # span is the profiler annotation alone, which records nothing here
+        assert NOOP_TRACER.span("x") is NOOP_TRACER.span("y")
+        ctx = obs.span("anything", n=1)
+        assert isinstance(ctx, jax.profiler.TraceAnnotation)
         with ctx:
             pass
         assert NoopTracer.spans == [] and NoopTracer.events == []
+        assert len(obs.recorder.ring) == 0
+
+    def test_span_reaches_the_profilers_trace(self, tmp_path):
+        """A span lands in the profiler's trace by name, nested in its
+        parent, with its args as typed stats; with tracing on it is also
+        recorded in memory."""
+        obs = Telemetry(tracing=True)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with obs.span("serve.decode", track="t", live=3, steps=8):
+                with obs.span("serve.decode.sync", track="t"):
+                    jax.numpy.ones(4).block_until_ready()
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = tmp_path.glob("**/*.xplane.pb")
+        got = {e.name: e for p in
+               jax.profiler.ProfileData.from_file(str(path)).planes
+               if p.name.startswith("/host") for line in p.lines
+               for e in line.events if e.name.startswith("serve.")}
+        outer, inner = got["serve.decode"], got["serve.decode.sync"]
+        assert dict(outer.stats) == {"live": 3, "steps": 8}
+        assert dict(inner.stats) == {}
+        assert outer.start_ns <= inner.start_ns
+        assert (inner.start_ns + inner.duration_ns
+                <= outer.start_ns + outer.duration_ns)
+        (rec,) = obs.tracer.find("serve.decode")
+        assert rec.args == {"live": 3, "steps": 8}
+        assert obs.tracer.find("serve.decode.sync")[0].parent == rec.sid
 
 
 # -- metrics registry ---------------------------------------------------------
@@ -219,7 +250,7 @@ class TestMetricsRegistry:
 
 class TestNonInterference:
     def test_serve_tokens_bitwise_equal_with_and_without_obs(
-            self, small_model):
+            self, small_model, tmp_path):
         from repro.serve.engine import ServeEngine
         cfg, params = small_model
         spec = SliceSpec(slots=2, max_len=32, prompt_len=8, chunk=4)
@@ -237,6 +268,13 @@ class TestNonInterference:
         traced = run(Telemetry(tracing=True, clock=VirtualClock()))
         assert base == traced
         assert all(len(t) == 8 for t in base)
+        # and with a profiler session recording every span
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            profiled = run(Telemetry(tracing=True))
+        finally:
+            jax.profiler.stop_trace()
+        assert profiled == base
 
     def test_engine_counter_views_match_registry(self, small_model):
         from repro.serve.engine import ServeEngine
