@@ -673,8 +673,7 @@ def _decode_attn_impl(ctx: ParallelContext) -> str:
 
 
 def decode_step_paged(cfg: ModelConfig, p, cache: Cache, tokens, seq_lens,
-                      active, ctx: ParallelContext = LOCAL, *, moe_cf=None,
-                      tables=None):
+                      active, ctx: ParallelContext = LOCAL, *, moe_cf=None):
     """One decode step with PER-SLOT cache lengths (continuous batching).
 
     tokens (B,) int32 — previous token per slot;
@@ -685,19 +684,12 @@ def decode_step_paged(cfg: ModelConfig, p, cache: Cache, tokens, seq_lens,
 
     Returns (logits (B, V), cache, seq_lens + active).  Attention runs
     through ``ops.paged_decode_attention`` — the Pallas paged kernel on TPU,
-    the dense XLA reference elsewhere (ctx.decode_attn overrides).
-
-    ``tables`` (B, nb) int32 switches to the POOLED cache layout (k/v from
-    ``init_kv_pool``, shape (Ls, NB, bs, KH, hd)): each slot's logical
-    block j lives at pool block ``tables[b, j]``, the fresh token's KV
-    scatters to its logical position's pool row, and attention runs through
-    the block-table-indexed kernel.  Writes land strictly past the prompt,
-    so shared prefix blocks are never touched (see serve/kvpool.py).
+    the dense XLA reference elsewhere (ctx.decode_attn overrides).  The
+    pooled layout has its own step, `decode_step_pooled`.
     """
     from repro.kernels import ops as OPS
 
     a = cfg.attention
-    B = tokens.shape[0]
     seq_lens = seq_lens.astype(jnp.int32)
     act_i = active.astype(jnp.int32)
     x = embed_tokens(cfg, p, tokens[:, None])            # (B, 1, D)
@@ -706,7 +698,7 @@ def decode_step_paged(cfg: ModelConfig, p, cache: Cache, tokens, seq_lens,
     impl = _decode_attn_impl(ctx)
     kv_block = getattr(ctx, "decode_kv_block", 128)
 
-    def attn_dense_paged(lp, h, kc, vc, win):
+    def attn_paged(lp, h, kc, vc, win):
         q, k, v = L.attention_qkv(lp["attn"], h, a, q_pos)
         S = kc.shape[1]
         # per-slot KV write at each slot's own next row.  Frozen slots write
@@ -726,34 +718,6 @@ def decode_step_paged(cfg: ModelConfig, p, cache: Cache, tokens, seq_lens,
             softcap=a.logit_softcap, scale=a.attn_scale, bk=kv_block,
             impl=impl)
         return L.attention_out(lp["attn"], o[:, None]), kc, vc
-
-    def attn_pooled(lp, h, kc, vc, win):
-        # kc, vc: (NB, bs, KH, hd) physical block pool
-        q, k, v = L.attention_qkv(lp["attn"], h, a, q_pos)
-        NB, bs = kc.shape[0], kc.shape[1]
-        W = tables.shape[1] * bs
-        pos = jnp.minimum(seq_lens, W - 1)   # overflow clamps into the
-        blk = pos // bs                      # slot's (private) last block
-        phys = jnp.take_along_axis(tables, blk[:, None], axis=1)[:, 0]
-        # OOB table entries (unadmitted slots) give an OOB flat row, which
-        # the scatter drops — no trash block needed
-        dest = phys * bs + pos % bs
-        kf = kc.reshape(NB * bs, *kc.shape[2:])
-        vf = vc.reshape(NB * bs, *vc.shape[2:])
-        kf = kf.at[dest].set(k[:, 0].astype(kc.dtype))
-        vf = vf.at[dest].set(v[:, 0].astype(vc.dtype))
-        kc, vc = kf.reshape(kc.shape), vf.reshape(vc.shape)
-        lens_now = jnp.minimum(seq_lens + 1, W)
-        o = OPS.paged_decode_attention_bt(
-            q[:, 0], kc, vc, lens_now, tables, window=win,
-            softcap=a.logit_softcap, scale=a.attn_scale, impl=impl)
-        return L.attention_out(lp["attn"], o[:, None]), kc, vc
-
-    if tables is not None:
-        tables = tables.astype(jnp.int32)
-        attn_paged = attn_pooled
-    else:
-        attn_paged = attn_dense_paged
 
     new_prefix_k, new_prefix_v = [], []
     for i, blk in enumerate(p.get("dense_prefix", [])):
@@ -830,6 +794,90 @@ def decode_step_paged(cfg: ModelConfig, p, cache: Cache, tokens, seq_lens,
     return logits[:, 0], new_cache, seq_lens + act_i
 
 
+def decode_step_pooled(cfg: ModelConfig, p, kv, tokens, seq_lens, active,
+                       tables, ctx: ParallelContext = LOCAL):
+    """One decode step over a pooled KV cache, read and written in place.
+
+    kv — (k, v), each ``init_kv_pool``'s (Ls, NB, bs, KH, hd) pool with
+    the layer and block axes merged: (Ls * NB, bs, KH, hd), so layer l's
+    pool block t is block ``l * NB + t``;
+    tables (B, nb) int32 — slot block tables (out-of-range = unadmitted);
+    tokens, seq_lens, active — as in `decode_step_paged`.
+
+    The pool stays in the layer loop's carry (never scan ``xs``/``ys``), so
+    each layer writes one row per slot and the block-table kernel reads
+    the slot's blocks where they lie: nothing but the fresh rows moves.
+    Rows land strictly past the prompt, in the slot's private blocks;
+    unadmitted and done slots write nothing.  Returns (logits (B, V), kv,
+    seq_lens + active).
+    """
+    from repro.kernels import ops as OPS
+
+    a = cfg.attention
+    kp, vp = kv
+    Ls = cfg.num_layers
+    NB, bs = kp.shape[0] // Ls, kp.shape[1]
+    W = tables.shape[1] * bs
+    seq_lens = seq_lens.astype(jnp.int32)
+    x = embed_tokens(cfg, p, tokens[:, None])            # (B, 1, D)
+    q_pos = hint(seq_lens[:, None], "batch", None)
+    impl = _decode_attn_impl(ctx)
+
+    # the fresh row: overflow clamps into the slot's (private) last block.
+    # Unadmitted slots (table entry >= NB) and done slots aim past every
+    # layer's blocks, where the scatter drops them — an entry of NB plus a
+    # layer offset would land in the next layer's blocks
+    pos = jnp.minimum(seq_lens, W - 1)
+    phys = jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1)[:, 0]
+    writes = active & (phys < NB)
+    row = pos % bs
+    lens_now = jnp.minimum(seq_lens + 1, W)
+    tclip = jnp.clip(tables, 0, NB - 1)
+
+    def layer(carry, lp, l, win):
+        x, kp, vp = carry
+
+        def attn(lp, h, kp, vp, win):
+            q, k, v = L.attention_qkv(lp["attn"], h, a, q_pos)
+            blk = jnp.where(writes, l * NB + phys, Ls * NB)
+            kp = kp.at[blk, row].set(k[:, 0].astype(kp.dtype), mode="drop")
+            vp = vp.at[blk, row].set(v[:, 0].astype(vp.dtype), mode="drop")
+            o = OPS.paged_decode_attention_bt(
+                q[:, 0], kp, vp, lens_now, tclip + l * NB, window=win,
+                softcap=a.logit_softcap, scale=a.attn_scale, impl=impl)
+            return L.attention_out(lp["attn"], o[:, None]), kp, vp
+
+        x, (kp, vp, _, _) = _decode_layer(
+            cfg, lp, win, x, kp, vp, None, None, ctx, attn_fn=attn,
+            bspec=None, active=active)
+        return x, kp, vp
+
+    # layer groups with a STATIC window each where the stack tiles (the
+    # kernel needs one; a traced window falls back to XLA), as in
+    # `decode_step_paged`
+    static = can_qchunk(cfg)
+    g = attn_group_size(cfg)
+
+    def group(t):
+        return t.reshape((t.shape[0] // g, g) + t.shape[1:])
+
+    def gbody(carry, xs):
+        lp_g, i, win_g = xs
+        for j in range(g):
+            win = static_window_for(cfg, j, g) if static else win_g[j]
+            carry = layer(carry, jax.tree.map(lambda t: t[j], lp_g),
+                          i * g + j, win)
+        return carry, None
+
+    (x, kp, vp), _ = jax.lax.scan(
+        gbody, (x, kp, vp),
+        (jax.tree.map(group, p["layers"]), jnp.arange(Ls // g),
+         group(jnp.asarray(window_schedule(cfg)))))
+    x = L.apply_norm(cfg, p["final_norm"], x)
+    logits = unembed(cfg, p, x)
+    return logits[:, 0], (kp, vp), seq_lens + active.astype(jnp.int32)
+
+
 def decode_n(cfg: ModelConfig, p, cache: Cache, tokens, seq_lens, budget,
              ctx: ParallelContext = LOCAL, *, num_steps: int,
              greedy: bool = True, key=None, temperature: float = 1.0,
@@ -855,16 +903,14 @@ def decode_n(cfg: ModelConfig, p, cache: Cache, tokens, seq_lens, budget,
 
     Returns (toks (num_steps, B) int32, cache, seq_lens, last_tokens).
 
-    With ``tables`` (pooled cache from `init_kv_pool`), the chunk runs
-    gather-once: each slot's logical KV view is gathered from the block
-    pool ONE time, the ``num_steps`` scan advances on that contiguous view
-    exactly like the per-slot dense path, and only the freshly decoded
-    rows scatter back to the pool at chunk end.  Decode writes land
-    strictly past the prompt — always in the slot's private (refcount-1)
-    blocks — so the writeback can never touch a block another table
-    shares, and per-step attention over the view is lane-for-lane the
-    dense program: pooled decode stays bitwise-identical while paying the
-    pool gather once per chunk instead of once per token.
+    With ``tables`` (pooled cache from `init_kv_pool`), the scan runs
+    `decode_step_pooled` and carries the pool itself: each step writes
+    only the fresh row per layer and slot, and the block-table kernel
+    reads the slots' blocks in the pool, so no per-slot view is ever
+    materialised and the pool (donated by the serve engine) is updated
+    in place.  The kernel's body is the per-slot path's, so where its
+    block size (``ctx.decode_kv_block``) is the pool's, pooled and
+    per-slot decode give the same tokens.
     """
     budget = jnp.asarray(budget, jnp.int32)
     if not greedy and key is None:
@@ -872,27 +918,21 @@ def decode_n(cfg: ModelConfig, p, cache: Cache, tokens, seq_lens, budget,
     salt = (jnp.asarray(salt, jnp.int32) if salt is not None
             else jnp.arange(budget.shape[0], dtype=jnp.int32))
 
-    pool_cache = None
-    if tables is not None:
+    if tables is None:
+        kv = cache
+
+        def advance(kv, toks, lens, active):
+            return decode_step_paged(cfg, p, kv, toks, lens, active, ctx,
+                                     moe_cf=moe_cf)
+    else:
         tables = jnp.asarray(tables, jnp.int32)
-        B = tables.shape[0]
-        Ls, NB, bs = cache.k.shape[0], cache.k.shape[1], cache.k.shape[2]
-        nb = tables.shape[1]
-        W = nb * bs
-        # OOB sentinel entries (unadmitted slots) clip for the GATHER only
-        # — their view is garbage, their lanes are masked by seq_lens, and
-        # their budget is 0 so nothing is written back
-        gidx = ((jnp.clip(tables, 0, NB - 1) * bs)[:, :, None]
-                + jnp.arange(bs)).reshape(-1)
-        kf = cache.k.reshape((Ls, NB * bs) + cache.k.shape[3:])
-        vf = cache.v.reshape((Ls, NB * bs) + cache.v.shape[3:])
-        view = Cache(
-            k=jnp.take(kf, gidx, axis=1).reshape(
-                (Ls, B, W) + cache.k.shape[3:]),
-            v=jnp.take(vf, gidx, axis=1).reshape(
-                (Ls, B, W) + cache.v.shape[3:]),
-            pos=cache.pos)
-        pool_cache, cache = cache, view
+        shape = cache.k.shape
+        flat = (shape[0] * shape[1],) + shape[2:]
+        kv = (cache.k.reshape(flat), cache.v.reshape(flat))
+
+        def advance(kv, toks, lens, active):
+            return decode_step_pooled(cfg, p, kv, toks, lens, active, tables,
+                                      ctx)
 
     def select(logits, lens):
         if greedy:
@@ -903,46 +943,20 @@ def decode_n(cfg: ModelConfig, p, cache: Cache, tokens, seq_lens, budget,
         return jax.vmap(jax.random.categorical)(keys, lg).astype(jnp.int32)
 
     def step(carry, _):
-        cache, toks, lens, produced = carry
+        kv, toks, lens, produced = carry
         active = produced < budget
-        logits, cache, lens = decode_step_paged(
-            cfg, p, cache, toks, lens, active, ctx, moe_cf=moe_cf)
+        logits, kv, lens = advance(kv, toks, lens, active)
         nxt = jnp.where(active, select(logits, lens), toks)
-        return (cache, nxt, lens, produced + active.astype(jnp.int32)), nxt
+        return (kv, nxt, lens, produced + active.astype(jnp.int32)), nxt
 
-    lens0 = jnp.asarray(seq_lens, jnp.int32)
-    init = (cache, jnp.asarray(tokens, jnp.int32), lens0,
-            jnp.zeros_like(budget))
-    (cache, last, seq_lens, _), toks = jax.lax.scan(
+    init = (kv, jnp.asarray(tokens, jnp.int32),
+            jnp.asarray(seq_lens, jnp.int32), jnp.zeros_like(budget))
+    (kv, last, seq_lens, _), toks = jax.lax.scan(
         step, init, None, length=num_steps)
-
-    if pool_cache is not None:
-        # writeback: slot b was active for exactly min(budget, num_steps)
-        # steps, writing row lens0+i at step i (clamped to the last lane on
-        # cache overflow, last write winning — same contract as the
-        # per-step scatter).  Rows >= prompt length => block index past
-        # every published block, so only private blocks are touched.
-        nsteps = jnp.minimum(budget, num_steps)
-        i = jnp.arange(num_steps)
-        rows = lens0[:, None] + i[None, :]                    # (B, steps)
-        rowc = jnp.minimum(rows, W - 1)
-        keep = ((i[None, :] < nsteps[:, None])
-                & ((rows < W - 1) | (i[None, :] == nsteps[:, None] - 1)))
-        phys = jnp.take_along_axis(tables, rowc // bs, axis=1)
-        dest = jnp.where(keep, phys * bs + rowc % bs, NB * bs).reshape(-1)
-        ridx = rowc[None, :, :, None, None]
-        newk = jnp.take_along_axis(cache.k, ridx, axis=2)
-        newv = jnp.take_along_axis(cache.v, ridx, axis=2)
-        kf = pool_cache.k.reshape((Ls, NB * bs) + pool_cache.k.shape[3:])
-        vf = pool_cache.v.reshape((Ls, NB * bs) + pool_cache.v.shape[3:])
-        kf = kf.at[:, dest].set(
-            newk.reshape((Ls, -1) + newk.shape[3:]).astype(kf.dtype))
-        vf = vf.at[:, dest].set(
-            newv.reshape((Ls, -1) + newv.shape[3:]).astype(vf.dtype))
-        cache = Cache(k=kf.reshape(pool_cache.k.shape),
-                      v=vf.reshape(pool_cache.v.shape),
-                      pos=cache.pos)
-    return toks, cache, seq_lens, last
+    if tables is not None:
+        kv = Cache(k=kv[0].reshape(shape), v=kv[1].reshape(shape),
+                   pos=jnp.maximum(cache.pos, jnp.max(seq_lens)))
+    return toks, kv, seq_lens, last
 
 
 # ---------------------------------------------------------------------------

@@ -206,6 +206,33 @@ class TestBlockTableKernel:
         np.testing.assert_allclose(np.asarray(out1), np.asarray(out2),
                                    rtol=1e-6, atol=1e-6)
 
+    @pytest.mark.parametrize("kw", [dict(), dict(window=6, softcap=20.0)],
+                             ids=["plain", "window_softcap"])
+    def test_layer_flattened_pool_matches_per_layer(self, kw):
+        """Pooled decode hands the kernel every layer's pool at once,
+        (Ls * NB, bs, KH, d), with each table clamped and then offset by
+        l * NB: layer l's answer must be the per-layer call's, bitwise,
+        out-of-range entries (unadmitted slots, unused tails) included."""
+        from repro.kernels.decode_attention import (
+            paged_decode_attention_bt_kernel_call)
+        key = jax.random.PRNGKey(25)
+        Ls, B, H, KH, NB, bs, nb, d = 3, 3, 4, 2, 10, 8, 3, 16
+        ks = jax.random.split(key, 3)
+        q = jax.random.normal(ks[0], (B, H, d))
+        k = jax.random.normal(ks[1], (Ls, NB, bs, KH, d))
+        v = jax.random.normal(ks[2], (Ls, NB, bs, KH, d))
+        tables = jnp.asarray([[NB] * nb, [4, 1, NB], [9, 0, 7]], jnp.int32)
+        lens = jnp.asarray([0, 11, 24], jnp.int32)
+        kf = k.reshape(Ls * NB, bs, KH, d)
+        vf = v.reshape(Ls * NB, bs, KH, d)
+        for l in range(Ls):
+            got = paged_decode_attention_bt_kernel_call(
+                q, kf, vf, lens, jnp.clip(tables, 0, NB - 1) + l * NB,
+                interpret=True, **kw)
+            want = paged_decode_attention_bt_kernel_call(
+                q, k[l], v[l], lens, tables, interpret=True, **kw)
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
     def test_ops_bt_dispatcher(self):
         key = jax.random.PRNGKey(24)
         B, H, KH, NB, bs, nb, d = 2, 4, 2, 16, 8, 4, 16

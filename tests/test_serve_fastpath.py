@@ -19,6 +19,7 @@ import pytest
 from _hypothesis_compat import given, settings, st
 from repro.configs import registry
 from repro.models import api
+from repro.parallel.context import LOCAL as LOCAL_CTX
 from repro.serve.engine import Request, ServeEngine, SliceSpec
 
 
@@ -350,3 +351,157 @@ class TestPooledPrefixKV:
         assert eng.prefix_lookup(probe) >= 16    # header blocks resident
         assert eng.prefix_lookup(header[::-1].copy()) == 0
         eng.kv_close()
+
+
+def _gather_once_decode(cfg, params, pool, tokens, lens, budget, tables,
+                        num_steps, ctx=None):
+    """The pooled decode as it ran before the in-place form, kept as the
+    oracle: gather each slot's logical view out of the pool, decode on it
+    with the per-slot dense path, then scatter the chunk's rows back —
+    slot b wrote row lens+i at step i < min(budget, num_steps), clamped to
+    the last lane on overflow with the last write winning."""
+    import jax.numpy as jnp
+    from repro.models import transformer as TF
+    from repro.parallel.context import LOCAL
+
+    Ls, NB, bs, KH, hd = pool.k.shape
+    B, nb = tables.shape
+    W = nb * bs
+    gidx = ((jnp.clip(tables, 0, NB - 1) * bs)[:, :, None]
+            + jnp.arange(bs)).reshape(-1)
+    kf = pool.k.reshape(Ls, NB * bs, KH, hd)
+    vf = pool.v.reshape(Ls, NB * bs, KH, hd)
+    view = TF.Cache(k=kf[:, gidx].reshape(Ls, B, W, KH, hd),
+                    v=vf[:, gidx].reshape(Ls, B, W, KH, hd), pos=pool.pos)
+    toks, view, seq_lens, last = TF.decode_n(
+        cfg, params, view, tokens, lens, budget, ctx or LOCAL,
+        num_steps=num_steps)
+    nsteps = jnp.minimum(budget, num_steps)
+    i = jnp.arange(num_steps)
+    rows = lens[:, None] + i[None, :]
+    rowc = jnp.minimum(rows, W - 1)
+    keep = ((i[None, :] < nsteps[:, None])
+            & ((rows < W - 1) | (i[None, :] == nsteps[:, None] - 1)))
+    phys = jnp.take_along_axis(tables, rowc // bs, axis=1)
+    dest = jnp.where(keep, phys * bs + rowc % bs, NB * bs).reshape(-1)
+    ridx = rowc[None, :, :, None, None]
+    newk = jnp.take_along_axis(view.k, ridx, axis=2).reshape(Ls, -1, KH, hd)
+    newv = jnp.take_along_axis(view.v, ridx, axis=2).reshape(Ls, -1, KH, hd)
+    pool = TF.Cache(k=kf.at[:, dest].set(newk).reshape(pool.k.shape),
+                    v=vf.at[:, dest].set(newv).reshape(pool.v.shape),
+                    pos=view.pos)
+    return toks, pool, seq_lens, last
+
+
+class TestPooledDecodeInPlace:
+    """`decode_n` with block tables decodes on the KV pool in place: the
+    same tokens, lengths and pool contents as the gather-once algorithm,
+    and nothing but each live slot's fresh rows written."""
+
+    NUM_STEPS, BS, NB = 6, 4, 24
+    # slot: (table, seq_len, budget) — 0 unadmitted (its budget is the
+    # test's parameter), 1 done mid-chunk, 2 overflows into the last lane,
+    # 3 and 4 share prefix block 8; blocks 15-23 belong to nobody
+    SLOTS = [([NB] * 4, 0, None), ([0, 1, 2, 3], 5, 3),
+             ([4, 5, 6, 7], 13, 8), ([8, 9, 10, 11], 5, 6),
+             ([8, 12, 13, 14], 7, 6)]
+
+    def _inputs(self, cfg, unadmitted_budget):
+        import jax.numpy as jnp
+        from repro.models import transformer as TF
+
+        a = cfg.attention
+        shape = (cfg.num_layers, self.NB, self.BS, a.num_kv_heads,
+                 a.head_dim)
+        kk, kv, kt = jax.random.split(jax.random.PRNGKey(5), 3)
+        pool = TF.Cache(k=jax.random.normal(kk, shape, jnp.bfloat16),
+                        v=jax.random.normal(kv, shape, jnp.bfloat16),
+                        pos=jnp.asarray(3, jnp.int32))
+        tables = jnp.asarray([s[0] for s in self.SLOTS], jnp.int32)
+        lens = jnp.asarray([s[1] for s in self.SLOTS], jnp.int32)
+        budget = jnp.asarray([unadmitted_budget]
+                             + [s[2] for s in self.SLOTS[1:]], jnp.int32)
+        tokens = jax.random.randint(kt, (len(self.SLOTS),), 0,
+                                    cfg.vocab_size, jnp.int32)
+        return pool, tokens, lens, budget, tables
+
+    def _written(self, cfg, lens, budget):
+        """(layer, block, row) of every row the chunk may write."""
+        W = 4 * self.BS
+        out = set()
+        for b, (table, _, _) in enumerate(self.SLOTS):
+            if table[0] >= self.NB:
+                continue
+            for i in range(min(int(budget[b]), self.NUM_STEPS)):
+                r = min(int(lens[b]) + i, W - 1)
+                out |= {(l, table[r // self.BS], r % self.BS)
+                        for l in range(cfg.num_layers)}
+        return out
+
+    @pytest.mark.parametrize("unadmitted_budget", [0, 3],
+                             ids=["idle", "live_unadmitted"])
+    def test_matches_gather_once_bitwise(self, small_model,
+                                         unadmitted_budget):
+        cfg, params = small_model
+        pool, tokens, lens, budget, tables = self._inputs(
+            cfg, unadmitted_budget)
+        want = _gather_once_decode(cfg, params, pool, tokens, lens, budget,
+                                   tables, self.NUM_STEPS)
+        got = api.decode_n(cfg, params, pool, tokens, lens, budget,
+                           num_steps=self.NUM_STEPS, tables=tables)
+        # an unadmitted slot given a budget decodes on whatever its clamped
+        # table names; the gather-once view kept its writes, the pool drops
+        # them, so only the admitted slots' tokens must agree then
+        first = 1 if unadmitted_budget else 0
+        np.testing.assert_array_equal(np.asarray(got[0])[:, first:],
+                                      np.asarray(want[0])[:, first:])
+        np.testing.assert_array_equal(np.asarray(got[3])[first:],
+                                      np.asarray(want[3])[first:])
+        np.testing.assert_array_equal(np.asarray(got[2]),
+                                      np.asarray(want[2]))
+        for x, y in ((got[1].k, want[1].k), (got[1].v, want[1].v),
+                     (got[1].pos, want[1].pos)):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+        # nothing outside the live slots' fresh rows moved: no block of
+        # another layer, of the unadmitted slot, of the shared prefix (8)
+        # or of the unowned tail (15-23)
+        written = self._written(cfg, lens, budget)
+        for new, old in ((got[1].k, pool.k), (got[1].v, pool.v)):
+            moved = np.any(np.asarray(new) != np.asarray(old), axis=(3, 4))
+            assert set(zip(*np.nonzero(moved))) == written
+
+    @pytest.mark.parametrize("decode_attn", ["dense", "paged"])
+    def test_no_logical_view_materialised(self, small_model, decode_attn):
+        """Neither the XLA reference nor the kernel path of the pooled
+        chunk holds a value the size of every slot's logical view over
+        every layer; the gather-once oracle does."""
+        cfg, params = small_model
+        ctx = dataclasses.replace(LOCAL_CTX, decode_attn=decode_attn)
+        pool, tokens, lens, budget, tables = self._inputs(cfg, 0)
+        a = cfg.attention
+        view = (cfg.num_layers * len(self.SLOTS) * 4 * self.BS
+                * a.num_kv_heads * a.head_dim)
+
+        def sizes(fn):
+            jaxpr = jax.make_jaxpr(fn)(pool, tokens, lens, budget, tables)
+            return set(_out_sizes(jaxpr.jaxpr))
+
+        assert view in sizes(lambda *xs: _gather_once_decode(
+            cfg, params, *xs, num_steps=self.NUM_STEPS, ctx=ctx))
+        assert view not in sizes(
+            lambda pool, t, n, b, tb: api.decode_n(
+                cfg, params, pool, t, n, b, ctx, num_steps=self.NUM_STEPS,
+                tables=tb))
+
+
+def _out_sizes(jaxpr):
+    """Element counts of every equation's outputs, sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            yield int(np.prod(v.aval.shape))
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (list, tuple)) else [p]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _out_sizes(inner)
