@@ -29,7 +29,6 @@ import numpy as np
 from bench import generator as GEN
 from bench import harness as H
 from bench import program as PROG
-from bench.reference import olmo as REF
 
 
 def _percentile(xs, q: float) -> float:
@@ -53,7 +52,7 @@ def _warm_up(session, spec, vocab: int, seed: int) -> None:
 
 
 def run(job: H.Job) -> H.Outcome:
-    c, mix = job.cell.config, job.cell.mix
+    c, mix, REF = job.cell.config, job.cell.mix, job.cell.reference
     cfg = PROG.model_config(c)
     spec = PROG.SliceSpec(**mix["engine"])
     n_out = GEN.longest(mix["output_len"])
@@ -188,7 +187,7 @@ def _check(job, c, reqs, served, done, width, n_out, weights):
     The sample is drawn from the seed and always holds the longest
     request.  With the control, the gap of the float8 reference's own
     choice stands in the program's place."""
-    lim = job.cell.limits
+    lim, REF = job.cell.limits, job.cell.reference
     if not done:
         return {"completed_requests": {"value": 1.0, "limit": 0.0}}, [], {}
     rng = np.random.default_rng([job.seed % 2**63, 4])

@@ -32,7 +32,6 @@ import numpy as np
 from bench import generator as GEN
 from bench import harness as H
 from bench import program as PROG
-from bench.reference import olmo as REF
 
 
 @jax.jit
@@ -42,10 +41,11 @@ def _leaf_norms(new, old):
         for k, x in new.items()}
 
 
-def _norms(tree, old=None) -> dict:
-    """Per-leaf norms of ``tree``, or of its change from ``old``."""
-    new = REF.flatten(tree)
-    old = REF.flatten(old) if old is not None else {
+def _norms(flatten, tree, old=None) -> dict:
+    """Per-leaf norms of ``tree``, or of its change from ``old``, flattened
+    by the reference's ``flatten``."""
+    new = flatten(tree)
+    old = flatten(old) if old is not None else {
         k: jnp.zeros((), x.dtype) for k, x in new.items()}
     return {k: float(v) for k, v in _leaf_norms(new, old).items()}
 
@@ -63,13 +63,13 @@ def worst_leaf_gap(got: dict, want: dict, leaves) -> float:
 
 def _moved(r_g1: dict) -> list:
     # leaves whose reference gradient is nought to rounding move under Adam
-    # by round-off alone; none of OLMo's is (no biases, no norm scales)
+    # by round-off alone, as a key's bias does under softmax
     med = float(np.median(list(r_g1.values())))
     return [k for k in r_g1 if r_g1[k] >= 1e-3 * med]
 
 
 def run(job: H.Job) -> H.Outcome:
-    c, mix = job.cell.config, job.cell.mix
+    c, mix, REF = job.cell.config, job.cell.mix, job.cell.reference
     opt = mix["optimizer"]
     run_cfg = PROG.RunConfig(
         model=PROG.model_config(c),
@@ -92,11 +92,12 @@ def run(job: H.Job) -> H.Outcome:
         phases["weights_s"] = time.perf_counter() - job.t_start
         state = session.run(1, state=state, log_every=1)
         g1 = {k: v / (1.0 - opt["b1"])
-              for k, v in _norms(state.opt_state.mu).items()}
+              for k, v in _norms(REF.flatten, state.opt_state.mu).items()}
         phases["first_step_s"] = time.perf_counter() - job.t_start
         state = session.run(n_check, state=state, log_every=1)
         losses = [m["loss"] for m in session.metrics_log if "loss" in m]
-        change = _norms(state.params, REF.init_weights(c, job.seed))
+        change = _norms(REF.flatten, state.params,
+                        REF.init_weights(c, job.seed))
 
         t0 = time.perf_counter()
         setup_s = t0 - job.t_start

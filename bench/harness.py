@@ -2,10 +2,14 @@
 
 Everything is found by name: the cell names a configuration (its file is
 given in ``BENCHMARK.json``) and a traffic mix (``bench/traffic/<mix>.json``,
-whose ``kind`` names the driver ``bench/drivers/<kind>.py``); the cell's
-correctness limits are ``bench/limits/<cell>.json``; each per-layer metric
-is read by ``bench/metrics/<metric>.py``.  A cell, mix or metric is added
-as new files and entries, with no edit to a file that is here.
+whose ``kind`` names the driver ``bench/drivers/<kind>.py``); the
+configuration file names its program configuration in a ``program`` block
+(``bench/program.py``) and its architecture, whose plain reference is
+``bench/reference/<architecture>.py``; the cell's correctness limits are
+``bench/limits/<cell>.json``; each per-layer metric is read by
+``bench/metrics/<metric>.py``.  A configuration (of any architecture the
+program's registry has), a cell, a mix or a metric is added as new files
+and entries, with no edit to a file that is here.
 
 A run: check that JAX holds the cell's chips and knows their peaks, turn on
 the compile cache, let the driver set up, measure its window and check what
@@ -18,6 +22,7 @@ on standard error and the last key of the result.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib
 import importlib.util
 import json
@@ -25,12 +30,14 @@ import shutil
 import sys
 import tempfile
 from pathlib import Path
+from types import ModuleType
 from typing import Any, Dict, List, Optional
 
 import jax
 
 from bench import trace as TR
 from bench.peaks import peaks_for
+from bench.reference import INTERFACE
 
 ROOT = Path(__file__).resolve().parents[1]
 
@@ -62,6 +69,7 @@ class Cell:
     limits: dict
     end_to_end: List[dict]
     per_layer: List[dict]
+    reference: ModuleType           # bench/reference/<architecture>.py
 
 
 def _applies(metric: dict, cell: str, e2e_names) -> bool:
@@ -80,24 +88,51 @@ def find_cell(bench: dict, name: str, root: Path = ROOT) -> Cell:
            if "workloads" not in m or name in m["workloads"]]
     names = {m["name"] for m in e2e}
     per_layer = [m for m in bench["per_layer"] if _applies(m, name, names)]
-    return Cell(name=name, chips=w["chips"],
-                config=load_json(root / cfg["file"]),
+    config = load_json(root / cfg["file"])
+    return Cell(name=name, chips=w["chips"], config=config,
                 mix=load_json(root / "bench" / "traffic"
                               / f"{w['traffic']}.json"),
                 limits=load_json(root / "bench" / "limits" / f"{name}.json"),
-                end_to_end=e2e, per_layer=per_layer)
+                end_to_end=e2e, per_layer=per_layer,
+                reference=reference(config["architecture"], root))
 
 
 def driver(kind: str):
     return importlib.import_module(f"bench.drivers.{kind}")
 
 
-def metric_reader(name: str, root: Path = ROOT):
-    path = root / "bench" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}", path)
+def _load(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
+    return mod
+
+
+def metric_reader(name: str, root: Path = ROOT):
+    return _load(root / "bench" / "metrics" / f"{name}.py",
+                 f"bench_metric_{name.replace('.', '_')}")
+
+
+# one module per file, so that the functions a reference jits keep their
+# compiled programs from one cell to the next in a process
+@functools.lru_cache(maxsize=None)
+def _load_reference(path: Path):
+    return _load(path, f"bench_reference_{path.stem.replace('.', '_')}")
+
+
+def reference(architecture: str, root: Path = ROOT) -> ModuleType:
+    """The plain reference of ``architecture``: the module
+    ``bench/reference/<architecture>.py`` under ``root``, which has to
+    provide every function of ``bench.reference.INTERFACE``."""
+    path = (root / "bench" / "reference" / f"{architecture}.py").resolve()
+    if not path.is_file():
+        raise FileNotFoundError(
+            f"no reference for architecture {architecture!r}: "
+            f"{path} is missing")
+    mod = _load_reference(path)
+    missing = [f for f in INTERFACE if not callable(getattr(mod, f, None))]
+    if missing:
+        raise AttributeError(f"the reference {path} lacks {missing}")
     return mod
 
 
@@ -255,6 +290,9 @@ def main(argv, t_start: float) -> int:
 
     bench = load_benchmark()
     cell = find_cell(bench, args.workload)
+    # a configuration the program does not have fails here, before the chip
+    from bench import program
+    program.model_config(cell.config)
     try:
         device = require_chips(cell.chips)
     except Exception as e:                       # noqa: BLE001

@@ -10,6 +10,11 @@ import time
 from bench import harness as H
 
 TINY = {"model": "olmo-tiny", "architecture": "olmo",
+        "program": {"config": "olmo-1b", "replace": {
+            "name": "olmo-tiny", "num_layers": 2, "d_model": 64,
+            "d_ff": 128, "vocab_size": 512, "max_seq_len": 128,
+            "attention.num_heads": 4, "attention.num_kv_heads": 4,
+            "attention.head_dim": 16}},
         "num_hidden_layers": 2, "hidden_size": 64, "intermediate_size": 128,
         "num_attention_heads": 4, "num_key_value_heads": 4,
         "vocab_size": 512, "max_position_embeddings": 128,
@@ -21,6 +26,7 @@ TINY = {"model": "olmo-tiny", "architecture": "olmo",
 def serve_cell(config=None, **mix):
     cell = H.find_cell(H.load_benchmark(), "olmo1b-serve-chat")
     cell.config = copy.deepcopy(config or TINY)
+    cell.reference = H.reference(cell.config["architecture"])
     cell.mix.update(
         rate_per_s=6.0,
         prompt_len={"dist": "lognormal", "median": 16, "sigma": 0.7,
@@ -39,6 +45,7 @@ def serve_cell(config=None, **mix):
 def train_cell(config=None, **mix):
     cell = H.find_cell(H.load_benchmark(), "olmo1b-train-2k")
     cell.config = copy.deepcopy(config or TINY)
+    cell.reference = H.reference(cell.config["architecture"])
     cell.mix.update(batch=4, seq_len=32, block_steps=2, trace_from_s=0.2)
     cell.mix.update(mix)
     cell.limits.update(first_grad_gap=0.004, update_gap=0.003,

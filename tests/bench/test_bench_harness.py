@@ -14,6 +14,7 @@ import pytest
 
 from bench import generator as GEN
 from bench import harness as H
+from bench import program as PROG
 from bench.peaks import UnknownDevice, peaks_for
 
 ROOT = Path(__file__).resolve().parents[2]
@@ -133,6 +134,7 @@ def test_a_new_cell_needs_only_new_files(tmp_path, bench):
     new = json.loads(json.dumps(bench))
     cfg = json.load(open(ROOT / "bench/configs/olmo-1b.json"))
     cfg["num_hidden_layers"] = 8
+    cfg["program"]["replace"]["num_layers"] = 8
     (tmp_path / "bench/configs/olmo-1b-8l.json").write_text(json.dumps(cfg))
     mix = json.load(open(ROOT / "bench/traffic/chat.json"))
     mix["rate_per_s"] = 3.0
@@ -156,6 +158,7 @@ def test_a_new_cell_needs_only_new_files(tmp_path, bench):
                              "workloads": ["olmo1b8-serve-fast"]})
     cell = H.find_cell(new, "olmo1b8-serve-fast", root=tmp_path)
     assert cell.config["num_hidden_layers"] == 8
+    assert PROG.model_config(cell.config).num_layers == 8
     assert cell.mix["rate_per_s"] == 3.0
     assert cell.limits["sample_requests"] == 4
     assert "serve.new_metric" in {m["name"] for m in cell.per_layer}
@@ -166,6 +169,161 @@ def test_a_new_cell_needs_only_new_files(tmp_path, bench):
     for rel in ("bench/configs/olmo-1b.json", "bench/traffic/chat.json",
                 "bench/harness.py"):
         assert (tmp_path / rel).read_bytes() == (ROOT / rel).read_bytes()
+
+
+def _bench_files(root: Path) -> dict:
+    return {p.relative_to(root): p.read_bytes()
+            for p in (root / "bench").rglob("*")
+            if p.is_file() and "__pycache__" not in p.parts}
+
+
+# a reference in the interface's shape whose functions do nothing: the
+# harness finds it, and no run calls it
+STUB_REFERENCE = """\"\"\"A stand-in reference of a mixture-of-experts decoder.\"\"\"
+NAME = "qwen3_moe stub"
+
+
+def init_weights(c, seed):
+    return {}
+
+
+def flatten(tree):
+    return dict(tree)
+
+
+def served_gaps(c, weights, prompt, served, width, n_out, control=False):
+    return None, None
+
+
+def train_steps(c, opt, weights, batches, quant=None):
+    return [], {}, {}
+"""
+
+# qwen3-moe-30b-a3b from the program's registry, cut to widths a test holds
+MOE_REPLACE = {"num_layers": 2, "d_model": 64, "d_ff": 32, "vocab_size": 512,
+               "max_seq_len": 128, "attention.num_heads": 4,
+               "attention.num_kv_heads": 2, "attention.head_dim": 16,
+               "moe.num_experts": 8, "moe.top_k": 2, "moe.expert_ffw": 32}
+
+
+def _add_config(root: Path, bench: dict, name: str, config: dict) -> dict:
+    """``bench`` with a configuration ``name`` of file ``config`` and a
+    serving cell ``<name>-serve`` on a mix and limits of its own, all
+    written under ``root`` as new files."""
+    (root / f"bench/configs/{name}.json").write_text(json.dumps(config))
+    mix = json.load(open(ROOT / "bench/traffic/chat.json"))
+    (root / f"bench/traffic/{name}-chat.json").write_text(json.dumps(mix))
+    (root / f"bench/limits/{name}-serve.json").write_text(
+        json.dumps({"sample_requests": 4, "max_logit_gap": 0.5}))
+    new = json.loads(json.dumps(bench))
+    new["configs"].append({"name": name, "source": "x",
+                           "file": f"bench/configs/{name}.json",
+                           "reduced": [], "why": "x"})
+    new["workloads"].append({"name": f"{name}-serve", "config": name,
+                             "traffic": f"{name}-chat", "chips": 1,
+                             "why": "x"})
+    for m in new["end_to_end"]:
+        if "olmo1b-serve-chat" in m.get("workloads", []):
+            m["workloads"].append(f"{name}-serve")
+    return new
+
+
+def test_a_configuration_of_another_architecture_needs_only_new_files(
+        tmp_path, bench):
+    """A configuration of an architecture the program's registry has is
+    added as files of its own (its configuration with a ``program`` block,
+    its reference, a mix and limits) and entries in BENCHMARK.json: the
+    cell finds that reference, the program's configuration is that family
+    at those widths, and no file that is there changes."""
+    from repro.configs.base import AttentionConfig, MoEConfig
+    shutil.copytree(ROOT / "bench", tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    before = _bench_files(ROOT)
+    (tmp_path / "bench/reference/qwen3_moe.py").write_text(STUB_REFERENCE)
+    config = {"model": "qwen3-moe-tiny", "architecture": "qwen3_moe",
+              "program": {"config": "qwen3-moe-30b-a3b",
+                          "replace": MOE_REPLACE},
+              "vocab_size": 512, "param_dtype": "float32",
+              "compute_dtype": "bfloat16"}
+    new = _add_config(tmp_path, bench, "qwen3-moe-tiny", config)
+    cell = H.find_cell(new, "qwen3-moe-tiny-serve", root=tmp_path)
+    assert cell.reference.NAME == "qwen3_moe stub"
+    assert Path(cell.reference.__file__) == (
+        tmp_path / "bench/reference/qwen3_moe.py").resolve()
+    assert {m["name"] for m in cell.end_to_end} == {"tpot_p90_ms", "setup_s"}
+    cfg = PROG.model_config(cell.config)
+    assert (cfg.name, cfg.family) == ("qwen3-moe-30b-a3b", "moe")
+    assert (cfg.num_layers, cfg.d_model, cfg.d_ff, cfg.vocab_size) == (
+        2, 64, 32, 512)
+    assert cfg.moe == MoEConfig(num_experts=8, top_k=2, expert_ffw=32)
+    assert cfg.attention == AttentionConfig(
+        num_heads=4, num_kv_heads=2, head_dim=16, rope_theta=1_000_000.0)
+    assert cfg.norm == "rmsnorm" and not cfg.tie_embeddings
+    assert (cfg.dtype, cfg.param_dtype) == ("bfloat16", "float32")
+    after = _bench_files(tmp_path)
+    assert {k: after[k] for k in before} == before
+    # the olmo cells still find the olmo reference under the same root
+    olmo = H.find_cell(new, "olmo1b-serve-chat", root=tmp_path)
+    assert Path(olmo.reference.__file__).name == "olmo.py"
+
+
+@pytest.mark.parametrize("reference", [None, "def init_weights(c, seed):\n"
+                                       "    return {}\n"])
+def test_a_configuration_without_a_whole_reference_is_refused(
+        tmp_path, bench, reference):
+    """No reference file for the architecture, or one that lacks part of
+    the interface: ``find_cell`` refuses the cell."""
+    shutil.copytree(ROOT / "bench", tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    if reference is not None:
+        (tmp_path / "bench/reference/halfdone.py").write_text(reference)
+    config = {"model": "x", "architecture": "halfdone",
+              "program": {"config": "olmo-1b", "replace": {}},
+              "vocab_size": 50304, "param_dtype": "float32",
+              "compute_dtype": "bfloat16"}
+    new = _add_config(tmp_path, bench, "halfdone", config)
+    error = FileNotFoundError if reference is None else AttributeError
+    with pytest.raises(error, match="halfdone"):
+        H.find_cell(new, "halfdone-serve", root=tmp_path)
+
+
+def test_every_reference_has_the_interface_and_none_imports_the_program():
+    import ast
+    from bench.reference import INTERFACE
+    files = sorted((ROOT / "bench/reference").glob("*.py"))
+    files = [f for f in files if f.name != "__init__.py"]
+    assert files
+    for f in files:
+        mod = H.reference(f.stem)
+        assert all(callable(getattr(mod, name, None)) for name in INTERFACE)
+        for node in ast.walk(ast.parse(f.read_text())):
+            names = ([a.name for a in node.names]
+                     if isinstance(node, ast.Import) else
+                     [node.module or ""]
+                     if isinstance(node, ast.ImportFrom) else [])
+            for n in names:
+                assert n.split(".")[0] != "repro" and n != "bench.program", \
+                    (f, n)
+
+
+def test_an_unknown_program_config_fails_before_the_chip(tmp_path):
+    """A configuration naming what the program's registry lacks (as a new
+    one does on the parent of the change that adds it) stops the run
+    before it looks for a chip."""
+    shutil.copytree(ROOT / "bench", tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path / "BENCHMARK.json")
+    (tmp_path / "src").symlink_to(ROOT / "src")
+    path = tmp_path / "bench/configs/olmo-1b.json"
+    cfg = json.loads(path.read_text())
+    cfg["program"]["config"] = "no-such-model"
+    path.write_text(json.dumps(cfg))
+    with pytest.raises(KeyError, match="no-such-model"):
+        PROG.model_config(cfg)
+    p = _run(tmp_path)
+    assert p.returncode != 0
+    assert '"correct"' not in p.stdout
+    assert "no-such-model" in p.stderr and "bench: needs" not in p.stderr
 
 
 def test_benchmark_json_keeps_the_contracts_shapes(bench):
@@ -191,17 +349,46 @@ def test_benchmark_json_keeps_the_contracts_shapes(bench):
     assert 1 <= bench["run_seconds"] <= 51
 
 
+def _published_keys_match(c: dict) -> None:
+    """The published keys of configuration file ``c`` against the program
+    configuration it names, field by field."""
+    cfg = PROG.model_config(c)
+    a = cfg.attention
+    assert {"num_hidden_layers": cfg.num_layers, "hidden_size": cfg.d_model,
+            "intermediate_size": cfg.d_ff, "vocab_size": cfg.vocab_size,
+            "num_attention_heads": a.num_heads,
+            "num_key_value_heads": a.num_kv_heads,
+            "max_position_embeddings": cfg.max_seq_len,
+            "rope_theta": a.rope_theta, "hidden_act": cfg.act,
+            "tie_word_embeddings": cfg.tie_embeddings} == {
+        k: c[k] for k in ("num_hidden_layers", "hidden_size",
+                          "intermediate_size", "vocab_size",
+                          "num_attention_heads", "num_key_value_heads",
+                          "max_position_embeddings", "rope_theta",
+                          "hidden_act", "tie_word_embeddings")}
+    assert c["hidden_size"] // c["num_attention_heads"] == a.head_dim
+    assert (cfg.dtype, cfg.param_dtype) == (c["compute_dtype"],
+                                            c["param_dtype"])
+
+
 def test_configs_are_the_programs_olmo_1b():
-    from bench import program as PROG
     from repro.configs import registry
     olmo = registry.get_config("olmo-1b")
     serve = json.load(open(ROOT / "bench/configs/olmo-1b.json"))
     train = json.load(open(ROOT / "bench/configs/olmo-1b-4l.json"))
     assert PROG.model_config(serve) == olmo
     assert PROG.model_config(train) == olmo.replace(num_layers=4)
+    for c in (serve, train):
+        _published_keys_match(c)
     changed = {k for k in serve if serve[k] != train.get(k)} - {
-        "reduced", "published", "deployment"}
+        "reduced", "published", "deployment", "program"}
     assert changed == set(train["reduced"]) == {"num_hidden_layers"}
+
+
+def test_tiny_config_is_the_program_config_it_names():
+    import bench_tiny as tiny
+    _published_keys_match(tiny.TINY)
+    assert PROG.model_config(tiny.TINY).name == tiny.TINY["model"]
 
 
 # ---------------------------------------------------------------------------

@@ -22,7 +22,14 @@ SEED = 2**34 + 3
 def reduced_config() -> dict:
     cfg = registry.get_reduced("olmo-1b")
     a = cfg.attention
-    return dict(tiny.TINY, model=cfg.name, num_hidden_layers=cfg.num_layers,
+    program = {"config": "olmo-1b", "replace": {
+        "num_layers": cfg.num_layers, "d_model": cfg.d_model,
+        "d_ff": cfg.d_ff, "vocab_size": cfg.vocab_size,
+        "max_seq_len": cfg.max_seq_len, "attention.num_heads": a.num_heads,
+        "attention.num_kv_heads": a.num_kv_heads,
+        "attention.head_dim": a.head_dim}}
+    return dict(tiny.TINY, model=cfg.name, program=program,
+                num_hidden_layers=cfg.num_layers,
                 hidden_size=cfg.d_model, intermediate_size=cfg.d_ff,
                 num_attention_heads=a.num_heads,
                 num_key_value_heads=a.num_kv_heads,
