@@ -59,6 +59,9 @@ class AttentionConfig:
     rope_theta: float = 10000.0
     # attention logit scale override; None -> 1/sqrt(head_dim)
     attn_scale: Optional[float] = None
+    # RMSNorm over each query and key head (learned, head_dim wide), applied
+    # before RoPE (lfm2)
+    qk_norm: bool = False
 
 
 @dataclass(frozen=True)
@@ -72,6 +75,17 @@ class MoEConfig:
     # first `dense_layers` layers use a dense FFN instead of MoE (deepseek/kimi style)
     dense_layers: int = 0
     dense_ffw: int = 0
+    # router scoring: "softmax" over all experts, or "sigmoid" per expert
+    # (lfm2); with `expert_bias` a learned per-expert bias is added to the
+    # scores for the top-k choice only, never to the gates
+    score: str = "softmax"
+    expert_bias: bool = False
+    # experts this chip holds: `held_experts` of them from `first_expert`
+    # (0 = all `num_experts`).  The router still scores all `num_experts`;
+    # the layer computes, dropless, the part of the result its own experts
+    # give (models/moe.py `moe_held`)
+    held_experts: int = 0
+    first_expert: int = 0
 
 
 @dataclass(frozen=True)
@@ -117,6 +131,7 @@ class DLRMConfig:
 # ---------------------------------------------------------------------------
 
 FAMILIES = ("dense", "moe", "ssm", "hybrid", "audio", "vlm", "dlrm")
+MIXERS = ("attention", "conv")
 
 
 @dataclass(frozen=True)
@@ -152,9 +167,21 @@ class ModelConfig:
     # hybrid: run attention and SSM in parallel per layer (hymba)
     parallel_heads: bool = False
 
+    # per-layer mixer schedule, one of MIXERS per layer ("conv" is the gated
+    # short convolution of models/ssm.py); empty = the family's own mixer in
+    # every layer
+    mixers: Tuple[str, ...] = ()
+    conv_width: int = 3                # short-convolution kernel length
+    norm_eps: float = 1e-6             # rmsnorm epsilon
+
     # numerics
     dtype: str = "bfloat16"
     param_dtype: str = "float32"
+
+    def __post_init__(self):
+        # a schedule read from JSON arrives as a list; keep the config
+        # hashable
+        object.__setattr__(self, "mixers", tuple(self.mixers))
 
     # --- derived helpers ------------------------------------------------
     @property
@@ -173,6 +200,12 @@ class ModelConfig:
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
+
+    @property
+    def conv_layers(self) -> int:
+        """Layers whose mixer is the short convolution (they keep conv
+        state in serving instead of KV)."""
+        return sum(1 for m in self.mixers if m == "conv")
 
     def supports_long_context(self) -> bool:
         """True if decode at 500k context is sub-quadratic (SSM/hybrid/local-attn)."""

@@ -22,6 +22,7 @@ _ARCH_MODULES = {
     "kimi-k2-1t-a32b": "repro.configs.kimi_k2",
     "qwen3-moe-30b-a3b": "repro.configs.qwen3_moe",
     "internvl2-2b": "repro.configs.internvl2_2b",
+    "lfm2-8b-a1b": "repro.configs.lfm2_8b_a1b",
     "dlrm0": "repro.configs.dlrm0",
 }
 

@@ -93,7 +93,8 @@ def cache_insert(cache, slot_cache, slot):
 def decode_n(cfg: ModelConfig, p, cache, tokens, seq_lens, budget,
              ctx: ParallelContext = LOCAL, *, num_steps: int, **kw):
     """Multi-step on-device decode with per-slot lengths/budgets; see
-    transformer.decode_n.  Pass ``tables=(B, nb)`` to decode over a pooled
+    transformer.decode_n (``moe_load=True`` also returns each step's
+    held-expert load).  Pass ``tables=(B, nb)`` to decode over a pooled
     prefix-shared KV cache (init_kv_pool) instead of per-slot rows."""
     if cfg.family == "audio":
         raise NotImplementedError(
@@ -106,12 +107,23 @@ def decode_n(cfg: ModelConfig, p, cache, tokens, seq_lens, budget,
 # -- pooled prefix-shared KV (serve/kvpool.py block tables) ------------------
 
 
-def init_kv_pool(cfg: ModelConfig, num_blocks: int, block_size: int, **kw):
-    """Pooled KV cache (Ls, NB, bs, KH, hd); dense attention families only;
-    see transformer.init_kv_pool."""
-    if cfg.family != "dense":
+def has_pooled_layout(cfg: ModelConfig) -> bool:
+    """Whether ``cfg`` can serve on the pooled KV layout."""
+    return TF.has_pooled_layout(cfg)
+
+
+def _require_pool(cfg: ModelConfig) -> None:
+    if not has_pooled_layout(cfg):
         raise NotImplementedError(
-            "pooled prefix-shared KV is dense-transformer only")
+            f"{cfg.name}: pooled KV serves dense attention stacks and "
+            f"per-layer mixer schedules, not the {cfg.family} family")
+
+
+def init_kv_pool(cfg: ModelConfig, num_blocks: int, block_size: int, **kw):
+    """Pooled KV cache (Ls, NB, bs, KH, hd) for the attention layers, and
+    per-slot conv state for a schedule's conv layers (pass ``slots``); see
+    transformer.init_kv_pool."""
+    _require_pool(cfg)
     return TF.init_kv_pool(cfg, num_blocks, block_size, **kw)
 
 
@@ -120,9 +132,7 @@ def prefill_suffix(cfg: ModelConfig, p, cache, tokens, start, valid, tables,
     """Fixed-width suffix prefill over a pooled KV cache: rows resume at
     logical position ``start`` with ``valid`` fresh tokens, KV lands in the
     blocks named by ``tables``; see transformer.prefill_suffix."""
-    if cfg.family != "dense":
-        raise NotImplementedError(
-            "pooled prefix-shared KV is dense-transformer only")
+    _require_pool(cfg)
     return TF.prefill_suffix(cfg, p, cache, tokens, start, valid, tables,
                              ctx, **kw)
 

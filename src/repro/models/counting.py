@@ -76,7 +76,11 @@ def _decoder_layer_params(cfg: ModelConfig, layer_idx: int, active: bool) -> int
     if cfg.family in ("dense", "audio", "vlm"):
         p += _attn_params(cfg) + _ffn_params(cfg.d_model, cfg.d_ff, cfg.ffn_glu)
     elif cfg.family == "moe":
-        p += _attn_params(cfg)
+        if cfg.mixers and cfg.mixers[layer_idx] == "conv":
+            d = cfg.d_model          # gated short conv: in/out proj + taps
+            p += 4 * d * d + cfg.conv_width * d
+        else:
+            p += _attn_params(cfg)
         if layer_idx < cfg.moe.dense_layers:
             p += _ffn_params(cfg.d_model, cfg.moe.dense_ffw, cfg.ffn_glu)
         else:

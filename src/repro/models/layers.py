@@ -85,7 +85,7 @@ def apply_norm(cfg: ModelConfig, p, x):
         return nonparam_ln(x)
     if cfg.norm == "layernorm":
         return layernorm(x, p["w"], p["b"])
-    return rmsnorm(x, p["w"])
+    return rmsnorm(x, p["w"], cfg.norm_eps)
 
 
 # ---------------------------------------------------------------------------
@@ -354,12 +354,16 @@ def attention_init(cfg: ModelConfig, key, stacked: Optional[int] = None,
         p["bq"] = jnp.zeros(L + (a.num_heads, a.head_dim), jnp.float32)
         p["bk"] = jnp.zeros(L + (a.num_kv_heads, a.head_dim), jnp.float32)
         p["bv"] = jnp.zeros(L + (a.num_kv_heads, a.head_dim), jnp.float32)
+    if a.qk_norm:
+        p["q_norm"] = jnp.zeros(L + (a.head_dim,), jnp.float32)
+        p["k_norm"] = jnp.zeros(L + (a.head_dim,), jnp.float32)
     return p
 
 
 def attention_qkv(p, x, a: AttentionConfig, positions, *, rope: bool = True,
-                  dtype=jnp.bfloat16):
-    """Project to q, k, v and apply RoPE.  x: (B, T, D)."""
+                  dtype=jnp.bfloat16, norm_eps: float = 1e-6):
+    """Project to q, k, v and apply RoPE (after the per-head q/k RMSNorm
+    where the config has one).  x: (B, T, D)."""
     q = jnp.einsum("btd,dhk->bthk", x, Q.cast(p["wq"], dtype))
     k = jnp.einsum("btd,dhk->bthk", x, Q.cast(p["wk"], dtype))
     v = jnp.einsum("btd,dhk->bthk", x, Q.cast(p["wv"], dtype))
@@ -367,6 +371,9 @@ def attention_qkv(p, x, a: AttentionConfig, positions, *, rope: bool = True,
         q = q + p["bq"].astype(dtype)
         k = k + p["bk"].astype(dtype)
         v = v + p["bv"].astype(dtype)
+    if a.qk_norm:
+        q = rmsnorm(q, p["q_norm"], norm_eps)
+        k = rmsnorm(k, p["k_norm"], norm_eps)
     if rope:
         q = apply_rope(q, positions, a.rope_theta)
         k = apply_rope(k, positions, a.rope_theta)

@@ -1,6 +1,6 @@
 """Mixture-of-Experts layer with expert-parallel all-to-all dispatch.
 
-Three dispatch paths share one router:
+Four dispatch paths share one router:
 
   * ``moe_local``   — sort-based dispatch, no collectives.  The reference
     implementation and the single-device (smoke-test) path.
@@ -12,7 +12,15 @@ Three dispatch paths share one router:
     over the model axis (they are ~KiB), every shard computes its local
     experts at small capacity, partial outputs are psum-merged.
 
-All paths implement *dropping* MoE with a static capacity factor, matching
+  * ``moe_held``    — the layer one chip of an expert-parallel deployment
+    runs when it holds ``held_experts`` of the experts and no exchange:
+    the router scores all ``num_experts``, and the layer computes, for
+    every token, the part of the result its own experts give.  It is
+    *dropless*: every held expert sees every token, weighted by the token's
+    gate for it (0 where not chosen), so a token's result never depends on
+    which tokens share its batch.  The pooled serving path uses it.
+
+The first three are *dropping* MoE with a static capacity factor, matching
 GSPMD-style production MoE.
 """
 from __future__ import annotations
@@ -35,11 +43,17 @@ P = jax.sharding.PartitionSpec
 # Params
 # ---------------------------------------------------------------------------
 
+def held_experts(m: MoEConfig) -> int:
+    """Experts whose weights this layer holds."""
+    return m.held_experts or m.num_experts
+
+
 def moe_init(cfg: ModelConfig, key, stacked: Optional[int] = None):
     m = cfg.moe
     d = cfg.d_model
     ks = jax.random.split(key, 7)
     L = () if stacked is None else (stacked,)
+    E = held_experts(m)
 
     def mk(k, *dims):
         return (jax.random.truncated_normal(k, -2.0, 2.0, L + dims,
@@ -48,13 +62,15 @@ def moe_init(cfg: ModelConfig, key, stacked: Optional[int] = None):
 
     p = {
         "router": mk(ks[0], d, m.num_experts),
-        "wo": mk(ks[3], m.num_experts, m.expert_ffw, d),
+        "wo": mk(ks[3], E, m.expert_ffw, d),
     }
     if cfg.ffn_glu:
-        p["wg"] = mk(ks[1], m.num_experts, d, m.expert_ffw)
-        p["wu"] = mk(ks[2], m.num_experts, d, m.expert_ffw)
+        p["wg"] = mk(ks[1], E, d, m.expert_ffw)
+        p["wu"] = mk(ks[2], E, d, m.expert_ffw)
     else:
-        p["wi"] = mk(ks[1], m.num_experts, d, m.expert_ffw)
+        p["wi"] = mk(ks[1], E, d, m.expert_ffw)
+    if m.expert_bias:
+        p["expert_bias"] = jnp.zeros(L + (m.num_experts,), jnp.float32)
     if m.num_shared_experts:
         f = m.shared_ffw * m.num_shared_experts
         p["shared"] = {
@@ -76,6 +92,18 @@ def router_topk(cfg: ModelConfig, p, x, dtype=jnp.bfloat16):
                         ).astype(jnp.float32)
     if m.router_softcap:
         logits = m.router_softcap * jnp.tanh(logits / m.router_softcap)
+    if m.score == "sigmoid":
+        # lfm2: independent scores; the expert bias steers the choice only
+        # (auxiliary-loss-free balancing), so there is no auxiliary loss
+        scores = jax.nn.sigmoid(logits)
+        pick = scores
+        if m.expert_bias:
+            pick = scores + jax.lax.stop_gradient(
+                p["expert_bias"].astype(jnp.float32))
+        _, eidx = jax.lax.top_k(pick, m.top_k)
+        gates = jnp.take_along_axis(scores, eidx, axis=-1)
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+        return gates, eidx, jnp.zeros((), jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
     gates, eidx = jax.lax.top_k(probs, m.top_k)
     gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
@@ -163,6 +191,50 @@ def moe_local(cfg: ModelConfig, p, x, *, capacity_factor: float = 1.25,
     if m.num_shared_experts:
         out = out + _shared_expert(cfg, p["shared"], x, dtype)
     return out, aux, dropped
+
+
+# ---------------------------------------------------------------------------
+# One chip's share of the experts, dropless, with no exchange
+# ---------------------------------------------------------------------------
+
+def moe_held(cfg: ModelConfig, p, x, *, dtype=jnp.bfloat16):
+    """x: (S, D) -> (out (S, D), routed (S, held) bool).
+
+    Routes over all ``num_experts`` and returns the part of the layer's
+    result that the held experts ``[first_expert, first_expert + held)``
+    give: each token's gated sum over those of its top-k choices that are
+    held.  ``routed[s, e]`` is whether token s chose held expert e.  Every
+    held expert computes every token (gate 0 where it was not chosen), so
+    nothing is dropped and rows never interact."""
+    m = cfg.moe
+    E = held_experts(m)
+    assert 0 <= m.first_expert and m.first_expert + E <= m.num_experts, m
+    gates, eidx, _ = router_topk(cfg, p, x, dtype)
+    mine = eidx[:, :, None] == (m.first_expert
+                                + jnp.arange(E, dtype=eidx.dtype))
+    g = jnp.sum(jnp.where(mine, gates[:, :, None], 0.0), axis=1)   # (S, E)
+    with jax.named_scope("moe_held"):
+        if cfg.ffn_glu:
+            h = (jax.nn.silu(jnp.einsum("sd,edf->esf", x,
+                                        p["wg"].astype(dtype)))
+                 * jnp.einsum("sd,edf->esf", x, p["wu"].astype(dtype)))
+        else:
+            h = jax.nn.silu(jnp.einsum("sd,edf->esf", x,
+                                       p["wi"].astype(dtype)))
+        # the gates weight each expert's hidden units, and one product sums
+        # over experts and units alike
+        h = (h.astype(jnp.float32) * g.T[:, :, None]).astype(dtype)
+        out = jnp.einsum("esf,efd->sd", h, p["wo"].astype(dtype),
+                         preferred_element_type=jnp.float32)
+    return out.astype(x.dtype), jnp.any(mine, axis=1)
+
+
+def routed_load(routed, live):
+    """(token-expert pairs of ``live`` rows routed to held experts, held
+    experts with at least one such pair), int32; routed (S, E), live (S,)."""
+    hit = routed & live[:, None]
+    return jnp.stack([jnp.sum(hit), jnp.sum(jnp.any(hit, axis=0))]
+                     ).astype(jnp.int32)
 
 
 # ---------------------------------------------------------------------------

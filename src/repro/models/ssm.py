@@ -1,4 +1,5 @@
-"""Mamba-2 SSD (state-space duality) block.
+"""Mamba-2 SSD (state-space duality) block, and LFM2's gated short
+convolution (``short_conv``, at the end of this file).
 
 Three implementations of the same layer:
   * ``ssd_forward``   — chunked matmul form (training / prefill).  Intra-chunk
@@ -249,3 +250,47 @@ def ssd_reference(cfg: ModelConfig, p, u, *, init_state=None):
     y = _gated_rmsnorm(y, z, p["norm_w"])
     out = jnp.einsum("bte,ed->btd", y, p["out_proj"].astype(jnp.float32))
     return out, state
+
+
+# ---------------------------------------------------------------------------
+# Gated short convolution (lfm2 "conv" layers)
+# ---------------------------------------------------------------------------
+# in_proj D -> 3D is split into B, C and x; the block computes
+# ``out_proj(C * causal_depthwise_conv(B * x))`` with a kernel of
+# ``cfg.conv_width`` taps, no bias and no activation.  Its serving state is
+# the last ``conv_width - 1`` rows of B * x (bf16, as the products are
+# computed), so a sequence split across dispatches or decoded a token at a
+# time sees exactly the rows a single pass would.
+
+
+def short_conv_init(cfg: ModelConfig, key, stacked: Optional[int] = None):
+    d, W = cfg.d_model, cfg.conv_width
+    ks = jax.random.split(key, 3)
+    L = () if stacked is None else (stacked,)
+
+    def mk(k, *dims):
+        return (jax.random.truncated_normal(k, -2.0, 2.0, L + dims,
+                                            jnp.float32) / np.sqrt(dims[0]))
+    return {"in_proj": mk(ks[0], d, 3 * d), "conv_w": mk(ks[1], W, d),
+            "out_proj": mk(ks[2], d, d)}
+
+
+def short_conv(cfg: ModelConfig, p, x, state, valid, *, dtype=jnp.bfloat16):
+    """x (B, T, D) continuing each row from ``state`` (B, W-1, D), the B*x
+    rows before it; ``valid`` (B,) int32 counts each row's real tokens (the
+    rest of the row is padding).  Returns (out (B, T, D), the state after
+    the row's ``valid`` tokens — unchanged where ``valid`` is 0)."""
+    W = cfg.conv_width
+    T = x.shape[1]
+    proj = jnp.einsum("btd,de->bte", x, p["in_proj"].astype(dtype))
+    b, c, xx = jnp.split(proj, 3, axis=-1)
+    hist = jnp.concatenate([state.astype(dtype), b * xx], axis=1)
+    w = p["conv_w"].astype(jnp.float32)
+    conv = hist[:, 0:T].astype(jnp.float32) * w[0]
+    for i in range(1, W):
+        conv = conv + hist[:, i:i + T].astype(jnp.float32) * w[i]
+    y = (c.astype(jnp.float32) * conv).astype(dtype)
+    out = jnp.einsum("btd,de->bte", y, p["out_proj"].astype(dtype))
+    new_state = jax.vmap(lambda h, n: jax.lax.dynamic_slice_in_dim(
+        h, n, W - 1, axis=0))(hist, valid.astype(jnp.int32))
+    return out, new_state

@@ -13,6 +13,12 @@ Design notes
     int32 array scanned as xs), never per-layer Python branches.
   * MoE layers with a dense prefix (kimi-k2) unroll the prefix outside the
     scan and scan the uniform MoE remainder.
+  * A per-layer mixer schedule that does not repeat (lfm2: short-conv and
+    attention layers in an irregular order, a dense-FFN prefix, then MoE) is
+    cut into runs of like layers (`segments`); each run's weights are
+    stacked and scanned, the runs unrolled in order.  Such a model serves
+    on the pooled layout only: KV for its attention layers in the block
+    pool, and per slot the short convolutions' state beside it.
   * The KV cache is stacked over layers, scanned as xs/ys.
 """
 from __future__ import annotations
@@ -24,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import MIXERS, ModelConfig
 from repro.models import layers as L
 from repro.models import moe as MOE
 from repro.models import quant as Q
@@ -91,6 +97,82 @@ def static_window_for(cfg: ModelConfig, idx_in_group: int, group: int):
 
 
 # ---------------------------------------------------------------------------
+# per-layer mixer schedule: runs of like layers
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class Segment:
+    """A run of consecutive layers of one kind, scanned over its stacked
+    weights.  ``slot`` is the run's first index among the layers of its
+    mixer: its KV pool layer, or its row of the conv state."""
+    mixer: str              # "attention" | "conv"
+    ffn: str                # "mlp" | "moe"
+    start: int
+    n: int
+    slot: int
+
+
+def segments(cfg: ModelConfig) -> Tuple[Segment, ...]:
+    """The runs of ``cfg.mixers``: a dense-FFN prefix of ``moe.dense_layers``
+    layers, then MoE layers where the config has experts."""
+    assert len(cfg.mixers) == cfg.num_layers and set(cfg.mixers) <= set(
+        MIXERS), (cfg.name, cfg.mixers)
+    dense = cfg.moe.dense_layers if cfg.moe else 0
+    kinds = [(m, "moe" if cfg.moe is not None and i >= dense else "mlp")
+             for i, m in enumerate(cfg.mixers)]
+    out, seen = [], {"attention": 0, "conv": 0}
+    for i, kind in enumerate(kinds):
+        if out and kinds[i - 1] == kind:
+            out[-1] = dataclasses.replace(out[-1], n=out[-1].n + 1)
+        else:
+            out.append(Segment(kind[0], kind[1], i, 1, seen[kind[0]]))
+        seen[kind[0]] += 1
+    return tuple(out)
+
+
+def _refuse_schedule(cfg: ModelConfig, what: str) -> None:
+    if cfg.mixers:
+        raise NotImplementedError(
+            f"{cfg.name}: {what} has no per-layer mixer schedule; a model "
+            "with conv layers serves on the pooled KV layout "
+            "(init_kv_pool / prefill_suffix / decode_n with tables)")
+
+
+def _init_segment(cfg: ModelConfig, seg: Segment, key):
+    ks = jax.random.split(key, 4)
+    lp = {"ln1": L.norm_init(cfg, ks[0], stacked=seg.n),
+          "ln2": L.norm_init(cfg, ks[1], stacked=seg.n)}
+    if seg.mixer == "attention":
+        lp["attn"] = L.attention_init(cfg, ks[2], stacked=seg.n)
+    else:
+        lp["conv"] = SSM.short_conv_init(cfg, ks[2], stacked=seg.n)
+    if seg.ffn == "moe":
+        lp["moe"] = MOE.moe_init(cfg, ks[3], stacked=seg.n)
+    else:
+        lp["mlp"] = L.mlp_init(cfg, ks[3], stacked=seg.n, d_ff=(
+            cfg.moe.dense_ffw if cfg.moe else cfg.d_ff))
+    return lp
+
+
+def _normed(cfg: ModelConfig, w, x, dtype=jnp.bfloat16):
+    """A scheduled layer's norm: read the float32 residual stream, hand the
+    products bf16.  The stream stays float32 because the router's top-k is
+    a discrete choice: a bf16 stream rounds once per layer, and where two
+    experts' biased scores lie within that rounding the choice flips."""
+    return L.apply_norm(cfg, w, x).astype(dtype)
+
+
+def _segment_ffn(cfg: ModelConfig, seg: Segment, lp, h):
+    """The FFN of a scheduled layer on (B, T, D); returns (out, routed
+    (B*T, held) bool or None)."""
+    if seg.ffn == "mlp":
+        return L.mlp_apply(cfg, lp["mlp"], h), None
+    B, T, D = h.shape
+    out, routed = MOE.moe_held(cfg, lp["moe"], h.reshape(B * T, D))
+    return out.reshape(B, T, D), routed
+
+
+# ---------------------------------------------------------------------------
 # Init
 # ---------------------------------------------------------------------------
 
@@ -102,6 +184,11 @@ def init_params(cfg: ModelConfig, key) -> Dict[str, Any]:
     }
     if not cfg.tie_embeddings:
         p["head"] = L.dense_init(keys[2], cfg.d_model, cfg.vocab_size)
+    if cfg.mixers:
+        segs = segments(cfg)
+        p["layers"] = [_init_segment(cfg, seg, k) for seg, k in
+                       zip(segs, jax.random.split(keys[4], len(segs)))]
+        return p
     if cfg.vision_prefix:
         p["vision_proj"] = L.dense_init(keys[3], cfg.vision_dim, cfg.d_model)
 
@@ -262,6 +349,11 @@ def forward(cfg: ModelConfig, p, batch: Dict[str, Any],
             remat: bool = False, moe_cf=None, return_hidden: bool = False,
             attn_impl: str = "blocked"):
     """Returns (logits (B, T, V), aux_losses scalar[, states])."""
+    if cfg.mixers:
+        assert not collect_states, "scheduled layers keep no state here"
+        return _forward_scheduled(cfg, p, batch["tokens"], remat=remat,
+                                  kv_chunk=kv_chunk,
+                                  return_hidden=return_hidden)
     tokens = batch["tokens"]
     B, T_text = tokens.shape
     x = embed_tokens(cfg, p, tokens)
@@ -340,6 +432,43 @@ def forward(cfg: ModelConfig, p, batch: Dict[str, Any],
     return logits, aux_total
 
 
+def _forward_scheduled(cfg: ModelConfig, p, tokens, *, remat: bool,
+                       kv_chunk: int, return_hidden: bool):
+    """`forward` of a per-layer mixer schedule: each run of like layers
+    scanned, attention over the whole sequence, conv from a zero state."""
+    a = cfg.attention
+    B, T = tokens.shape
+    x = embed_tokens(cfg, p, tokens).astype(jnp.float32)
+    positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+    full = jnp.full((B,), T, jnp.int32)
+    for seg, lp_seg in zip(segments(cfg), p["layers"]):
+        def body(x, lp, seg=seg):
+            h = _normed(cfg, lp["ln1"], x)
+            if seg.mixer == "attention":
+                q, k, v = L.attention_qkv(lp["attn"], h, a, positions,
+                                          norm_eps=cfg.norm_eps)
+                o = L.blocked_attention(q, k, v, positions, positions,
+                                        kv_chunk=kv_chunk)
+                h = L.attention_out(lp["attn"], o)
+            else:
+                zero = jnp.zeros((B, cfg.conv_width - 1, cfg.d_model),
+                                 h.dtype)
+                with jax.named_scope("short_conv"):
+                    h, _ = SSM.short_conv(cfg, lp["conv"], h, zero, full)
+            x = x + h
+            h, _ = _segment_ffn(cfg, seg, lp, _normed(cfg, lp["ln2"], x))
+            return x + h, None
+
+        if remat:
+            body = jax.checkpoint(body)
+        x, _ = jax.lax.scan(body, x, lp_seg)
+    x = _normed(cfg, p["final_norm"], x)
+    aux = jnp.zeros((), jnp.float32)
+    if return_hidden:
+        return x, aux
+    return unembed(cfg, p, x), aux
+
+
 # ---------------------------------------------------------------------------
 # KV / state cache
 # ---------------------------------------------------------------------------
@@ -349,7 +478,9 @@ class Cache:
     k: Optional[jax.Array] = None        # (Ls, B, S, KH, hd)
     v: Optional[jax.Array] = None
     ssm: Optional[jax.Array] = None      # (Ls, B, H, P, N)
-    conv: Optional[jax.Array] = None     # (Ls, B, W-1, conv_dim)
+    conv: Optional[jax.Array] = None     # (Ls, B, W-1, conv_dim); pooled
+                                         # schedule: (conv layers, slots,
+                                         # W-1, D)
     prefix_k: Optional[list] = None      # kimi dense prefix (unrolled layers)
     prefix_v: Optional[list] = None
     pos: Optional[jax.Array] = None      # scalar int32: tokens already cached
@@ -363,6 +494,7 @@ jax.tree_util.register_dataclass(
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int,
                dtype=jnp.bfloat16) -> Cache:
+    _refuse_schedule(cfg, "the dense per-slot cache")
     a = cfg.attention
     n_scan = num_moe_layers(cfg) if cfg.family == "moe" else cfg.num_layers
     c = Cache(pos=jnp.zeros((), jnp.int32))
@@ -391,6 +523,7 @@ def prefill(cfg: ModelConfig, p, batch: Dict[str, Any],
             kv_chunk: int = 1024, moe_cf=None,
             attn_impl: str = "blocked") -> Tuple[jax.Array, Cache]:
     """Forward over the prompt; returns (last-position logits, filled cache)."""
+    _refuse_schedule(cfg, "prefill")
     tokens = batch["tokens"]
     B, T_text = tokens.shape
     x = embed_tokens(cfg, p, tokens)
@@ -564,6 +697,7 @@ def decode_step(cfg: ModelConfig, p, cache: Cache, tokens,
     The new token is written at index ``cache.pos``; attention sees positions
     [0, pos] (windowed per layer).
     """
+    _refuse_schedule(cfg, "decode_step")
     a = cfg.attention
     B = tokens.shape[0]
     pos = cache.pos
@@ -689,6 +823,7 @@ def decode_step_paged(cfg: ModelConfig, p, cache: Cache, tokens, seq_lens,
     """
     from repro.kernels import ops as OPS
 
+    _refuse_schedule(cfg, "the dense per-slot layout")
     a = cfg.attention
     seq_lens = seq_lens.astype(jnp.int32)
     act_i = active.astype(jnp.int32)
@@ -798,9 +933,10 @@ def decode_step_pooled(cfg: ModelConfig, p, kv, tokens, seq_lens, active,
                        tables, ctx: ParallelContext = LOCAL):
     """One decode step over a pooled KV cache, read and written in place.
 
-    kv — (k, v), each ``init_kv_pool``'s (Ls, NB, bs, KH, hd) pool with
-    the layer and block axes merged: (Ls * NB, bs, KH, hd), so layer l's
-    pool block t is block ``l * NB + t``;
+    kv — (k, v, conv): k and v each ``init_kv_pool``'s (Ls, NB, bs, KH, hd)
+    pool with the layer and block axes merged: (Ls * NB, bs, KH, hd), so
+    attention layer l's pool block t is block ``l * NB + t``; conv the
+    short convolutions' per-slot state (or None where the model has none);
     tables (B, nb) int32 — slot block tables (out-of-range = unadmitted);
     tokens, seq_lens, active — as in `decode_step_paged`.
 
@@ -808,14 +944,16 @@ def decode_step_pooled(cfg: ModelConfig, p, kv, tokens, seq_lens, active,
     each layer writes one row per slot and the block-table kernel reads
     the slot's blocks where they lie: nothing but the fresh rows moves.
     Rows land strictly past the prompt, in the slot's private blocks;
-    unadmitted and done slots write nothing.  Returns (logits (B, V), kv,
-    seq_lens + active).
+    unadmitted and done slots write nothing, and their conv state stays.
+    Returns (logits (B, V), kv, seq_lens + active, load): ``load`` is, per
+    MoE layer of a scheduled model, `moe.routed_load` of the active slots
+    (None for other models).
     """
     from repro.kernels import ops as OPS
 
     a = cfg.attention
-    kp, vp = kv
-    Ls = cfg.num_layers
+    kp, vp, conv = kv
+    Ls = num_attention_layers(cfg)
     NB, bs = kp.shape[0] // Ls, kp.shape[1]
     W = tables.shape[1] * bs
     seq_lens = seq_lens.astype(jnp.int32)
@@ -834,21 +972,42 @@ def decode_step_pooled(cfg: ModelConfig, p, kv, tokens, seq_lens, active,
     lens_now = jnp.minimum(seq_lens + 1, W)
     tclip = jnp.clip(tables, 0, NB - 1)
 
+    def attn(lp, h, kp, vp, win, l):
+        q, k, v = L.attention_qkv(lp["attn"], h, a, q_pos,
+                                  norm_eps=cfg.norm_eps)
+        blk = jnp.where(writes, l * NB + phys, Ls * NB)
+        kp = kp.at[blk, row].set(k[:, 0].astype(kp.dtype), mode="drop")
+        vp = vp.at[blk, row].set(v[:, 0].astype(vp.dtype), mode="drop")
+        o = OPS.paged_decode_attention_bt(
+            q[:, 0], kp, vp, lens_now, tclip + l * NB, window=win,
+            softcap=a.logit_softcap, scale=a.attn_scale, impl=impl)
+        return L.attention_out(lp["attn"], o[:, None]), kp, vp
+
+    if cfg.mixers:
+        x, kp, vp, conv, load = _decode_scheduled(cfg, p, x, kp, vp, conv,
+                                                  active, attn)
+        x = _normed(cfg, p["final_norm"], x)
+    else:
+        x, kp, vp = _decode_uniform(cfg, p, x, kp, vp, active, attn, ctx)
+        x = L.apply_norm(cfg, p["final_norm"], x)
+        load = None
+    logits = unembed(cfg, p, x)
+    return (logits[:, 0], (kp, vp, conv),
+            seq_lens + active.astype(jnp.int32), load)
+
+
+def _decode_uniform(cfg: ModelConfig, p, x, kp, vp, active, attn, ctx):
+    """The pooled decode layers of a uniform (dense) stack: one scan."""
+    Ls = cfg.num_layers
+
     def layer(carry, lp, l, win):
         x, kp, vp = carry
 
-        def attn(lp, h, kp, vp, win):
-            q, k, v = L.attention_qkv(lp["attn"], h, a, q_pos)
-            blk = jnp.where(writes, l * NB + phys, Ls * NB)
-            kp = kp.at[blk, row].set(k[:, 0].astype(kp.dtype), mode="drop")
-            vp = vp.at[blk, row].set(v[:, 0].astype(vp.dtype), mode="drop")
-            o = OPS.paged_decode_attention_bt(
-                q[:, 0], kp, vp, lens_now, tclip + l * NB, window=win,
-                softcap=a.logit_softcap, scale=a.attn_scale, impl=impl)
-            return L.attention_out(lp["attn"], o[:, None]), kp, vp
+        def attn_l(lp, h, kp, vp, win):
+            return attn(lp, h, kp, vp, win, l)
 
         x, (kp, vp, _, _) = _decode_layer(
-            cfg, lp, win, x, kp, vp, None, None, ctx, attn_fn=attn,
+            cfg, lp, win, x, kp, vp, None, None, ctx, attn_fn=attn_l,
             bspec=None, active=active)
         return x, kp, vp
 
@@ -873,15 +1032,49 @@ def decode_step_pooled(cfg: ModelConfig, p, kv, tokens, seq_lens, active,
         gbody, (x, kp, vp),
         (jax.tree.map(group, p["layers"]), jnp.arange(Ls // g),
          group(jnp.asarray(window_schedule(cfg)))))
-    x = L.apply_norm(cfg, p["final_norm"], x)
-    logits = unembed(cfg, p, x)
-    return logits[:, 0], (kp, vp), seq_lens + active.astype(jnp.int32)
+    return x, kp, vp
+
+
+def _decode_scheduled(cfg: ModelConfig, p, x, kp, vp, conv, active, attn):
+    """The pooled decode layers of a per-layer mixer schedule, run by run:
+    attention layers write and read the pool, conv layers advance the
+    active slots' conv state.  Returns (x, the float32 residual stream;
+    kp, vp, conv, load (n_moe, 2))."""
+    step = active.astype(jnp.int32)
+    x = x.astype(jnp.float32)
+    loads = []
+    for seg, lp_seg in zip(segments(cfg), p["layers"]):
+        def body(carry, xs, seg=seg):
+            x, kp, vp, conv = carry
+            lp, i = xs
+            h = _normed(cfg, lp["ln1"], x)
+            if seg.mixer == "attention":
+                h, kp, vp = attn(lp, h, kp, vp, None, seg.slot + i)
+            else:
+                c = seg.slot + i
+                with jax.named_scope("short_conv"):
+                    h, st = SSM.short_conv(cfg, lp["conv"], h, conv[c], step)
+                conv = conv.at[c].set(st)
+            x = x + h
+            h, routed = _segment_ffn(cfg, seg, lp,
+                                     _normed(cfg, lp["ln2"], x))
+            load = None if routed is None else MOE.routed_load(routed,
+                                                               active)
+            return (x + h, kp, vp, conv), load
+
+        (x, kp, vp, conv), load = jax.lax.scan(
+            body, (x, kp, vp, conv), (lp_seg, jnp.arange(seg.n)))
+        if load is not None:
+            loads.append(load)
+    load = (jnp.concatenate(loads) if loads
+            else jnp.zeros((0, 2), jnp.int32))
+    return x, kp, vp, conv, load
 
 
 def decode_n(cfg: ModelConfig, p, cache: Cache, tokens, seq_lens, budget,
              ctx: ParallelContext = LOCAL, *, num_steps: int,
              greedy: bool = True, key=None, temperature: float = 1.0,
-             salt=None, moe_cf=None, tables=None):
+             salt=None, moe_cf=None, tables=None, moe_load: bool = False):
     """Advance all slots up to ``num_steps`` tokens in ONE dispatch.
 
     A ``lax.scan`` over ``decode_step_paged`` with on-device token selection
@@ -901,7 +1094,13 @@ def decode_n(cfg: ModelConfig, p, cache: Cache, tokens, seq_lens, budget,
     — so sampled outputs are chunk-invariant AND decorrelated across slots
     and across requests reusing a slot.
 
-    Returns (toks (num_steps, B) int32, cache, seq_lens, last_tokens).
+    Returns (toks (num_steps, B) int32, cache, seq_lens, last_tokens), and
+    with ``moe_load`` a fifth, ``load``: for a scheduled model with MoE
+    layers served pooled, (num_steps, moe_layers, 2) int32 holding per step
+    and MoE layer the token-expert pairs of the step's live slots routed to
+    held experts and the held experts they touched (`moe.routed_load`);
+    None otherwise.  It comes back with the tokens, so reading it costs no
+    extra sync.
 
     With ``tables`` (pooled cache from `init_kv_pool`), the scan runs
     `decode_step_pooled` and carries the pool itself: each step writes
@@ -923,12 +1122,12 @@ def decode_n(cfg: ModelConfig, p, cache: Cache, tokens, seq_lens, budget,
 
         def advance(kv, toks, lens, active):
             return decode_step_paged(cfg, p, kv, toks, lens, active, ctx,
-                                     moe_cf=moe_cf)
+                                     moe_cf=moe_cf) + (None,)
     else:
         tables = jnp.asarray(tables, jnp.int32)
         shape = cache.k.shape
         flat = (shape[0] * shape[1],) + shape[2:]
-        kv = (cache.k.reshape(flat), cache.v.reshape(flat))
+        kv = (cache.k.reshape(flat), cache.v.reshape(flat), cache.conv)
 
         def advance(kv, toks, lens, active):
             return decode_step_pooled(cfg, p, kv, toks, lens, active, tables,
@@ -945,17 +1144,20 @@ def decode_n(cfg: ModelConfig, p, cache: Cache, tokens, seq_lens, budget,
     def step(carry, _):
         kv, toks, lens, produced = carry
         active = produced < budget
-        logits, kv, lens = advance(kv, toks, lens, active)
+        logits, kv, lens, load = advance(kv, toks, lens, active)
         nxt = jnp.where(active, select(logits, lens), toks)
-        return (kv, nxt, lens, produced + active.astype(jnp.int32)), nxt
+        return (kv, nxt, lens, produced + active.astype(jnp.int32)), (nxt,
+                                                                      load)
 
     init = (kv, jnp.asarray(tokens, jnp.int32),
             jnp.asarray(seq_lens, jnp.int32), jnp.zeros_like(budget))
-    (kv, last, seq_lens, _), toks = jax.lax.scan(
+    (kv, last, seq_lens, _), (toks, load) = jax.lax.scan(
         step, init, None, length=num_steps)
     if tables is not None:
         kv = Cache(k=kv[0].reshape(shape), v=kv[1].reshape(shape),
-                   pos=jnp.maximum(cache.pos, jnp.max(seq_lens)))
+                   conv=kv[2], pos=jnp.maximum(cache.pos, jnp.max(seq_lens)))
+    if moe_load:
+        return toks, kv, seq_lens, last, load
     return toks, kv, seq_lens, last
 
 
@@ -975,17 +1177,39 @@ def decode_n(cfg: ModelConfig, p, cache: Cache, tokens, seq_lens, budget,
 # never depends on which physical block a lane lives in.
 
 
-def init_kv_pool(cfg: ModelConfig, num_blocks: int, block_size: int,
-                 dtype=jnp.bfloat16) -> Cache:
-    """Pooled KV cache: k/v (Ls, NB, bs, KH, hd), indexed by block tables.
+def has_pooled_layout(cfg: ModelConfig) -> bool:
+    """Dense attention families, and models with a per-layer mixer
+    schedule (attention and short-conv layers, dense or held-expert FFNs).
+    SSM, hybrid, vision-prefix and dense-prefix stacks have no pooled
+    layout yet."""
+    return (cfg.family == "dense" and cfg.attention is not None
+            and not cfg.vision_prefix) or bool(cfg.mixers)
 
-    Attention-only dense families (no ssm/conv state, no dense prefix, no
-    vision prefix — their caches have no pooled layout yet)."""
+
+def num_attention_layers(cfg: ModelConfig) -> int:
+    """Layers that keep KV (every layer without a mixer schedule)."""
+    if cfg.mixers:
+        return cfg.num_layers - cfg.conv_layers
+    return cfg.num_layers
+
+
+def init_kv_pool(cfg: ModelConfig, num_blocks: int, block_size: int,
+                 dtype=jnp.bfloat16, *, slots: int = 0) -> Cache:
+    """Pooled KV cache: k/v (Ls, NB, bs, KH, hd) for the Ls attention
+    layers, indexed by block tables; with conv layers also ``conv``
+    (n_conv, slots, conv_width - 1, D), each slot's short-convolution state
+    (the last B*x rows), which lives beside the pool, one row per slot."""
     a = cfg.attention
-    assert cfg.family == "dense" and a is not None and not cfg.vision_prefix, \
-        f"pooled KV supports dense attention families, not {cfg.family}"
-    kv = (cfg.num_layers, num_blocks, block_size, a.num_kv_heads, a.head_dim)
-    return Cache(k=jnp.zeros(kv, dtype), v=jnp.zeros(kv, dtype),
+    assert has_pooled_layout(cfg), \
+        f"{cfg.name}: no pooled KV layout for the {cfg.family} family"
+    kv = (num_attention_layers(cfg), num_blocks, block_size, a.num_kv_heads,
+          a.head_dim)
+    conv = None
+    if cfg.conv_layers:
+        assert slots > 0, "conv state needs the slot count"
+        conv = jnp.zeros((cfg.conv_layers, slots, cfg.conv_width - 1,
+                          cfg.d_model), dtype)
+    return Cache(k=jnp.zeros(kv, dtype), v=jnp.zeros(kv, dtype), conv=conv,
                  pos=jnp.zeros((), jnp.int32))
 
 
@@ -1009,9 +1233,16 @@ def prefill_suffix(cfg: ModelConfig, p, cache: Cache, tokens, start, valid,
     sentinel and contribute exact zeros.  A long suffix prefills in
     ``ceil(len/T)`` chained dispatches of this ONE program.
 
+    A scheduled model's conv layers continue each row from its slot's conv
+    state (zero where the row starts a prompt) and leave the state after
+    the row's last valid token; rows with nothing valid keep theirs.
+
     Returns (logits (B, V) at each row's last valid suffix position,
     updated pooled cache).
     """
+    if cfg.mixers:
+        return _prefill_suffix_scheduled(cfg, p, cache, tokens, start, valid,
+                                         tables)
     a = cfg.attention
     assert cfg.family == "dense" and not p.get("dense_prefix"), \
         "prefill_suffix supports dense attention families"
@@ -1072,6 +1303,81 @@ def prefill_suffix(cfg: ModelConfig, p, cache: Cache, tokens, start, valid,
                                          cache.k, cache.v))
     new_cache = Cache(k=ks, v=vs, pos=cache.pos)
     x = L.apply_norm(cfg, p["final_norm"], x)
+    li = jnp.clip(valid - 1, 0, T - 1)
+    xlast = jnp.take_along_axis(x, li[:, None, None], axis=1)   # (B, 1, D)
+    logits = unembed(cfg, p, xlast)
+    return logits[:, 0], new_cache
+
+
+def _prefill_suffix_scheduled(cfg: ModelConfig, p, cache: Cache, tokens,
+                              start, valid, tables) -> Tuple[jax.Array, Cache]:
+    """`prefill_suffix` of a per-layer mixer schedule.  The pool (layer and
+    block axes merged) and the conv state ride the layer loop's carry, so
+    each attention layer scatters its fresh rows and gathers its logical
+    view in place."""
+    a = cfg.attention
+    B, T = tokens.shape
+    Ls, NB, bs, KH, hd = cache.k.shape
+    nb = tables.shape[1]
+    W = nb * bs
+    rows = NB * bs                            # pool rows of one layer
+    tables = tables.astype(jnp.int32)
+    start = start.astype(jnp.int32)
+    valid = valid.astype(jnp.int32)
+
+    x = embed_tokens(cfg, p, tokens).astype(jnp.float32)
+    positions = start[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
+    lane = jnp.arange(W, dtype=jnp.int32)[None, :]
+    kv_pos = jnp.where(lane < (start + valid)[:, None], lane, -1)
+    gidx = ((jnp.clip(tables, 0, NB - 1) * bs)[:, :, None]
+            + jnp.arange(bs, dtype=jnp.int32)[None, None]).reshape(-1)
+    blk = positions // bs
+    phys = jnp.take_along_axis(tables, jnp.clip(blk, 0, nb - 1), axis=1)
+    keep = (jnp.arange(T, dtype=jnp.int32)[None] < valid[:, None]) & (
+        blk < nb)
+    dest = (phys * bs + positions % bs).reshape(-1)
+    keep = keep.reshape(-1)
+    # a row that starts its prompt here starts from a zero conv state
+    fresh = ((start == 0) & (valid > 0))[:, None, None]
+
+    def body(carry, xs, seg):
+        x, kf, vf, conv = carry
+        lp, i = xs
+        h = _normed(cfg, lp["ln1"], x)
+        if seg.mixer == "attention":
+            off = (seg.slot + i) * rows
+            q, k, v = L.attention_qkv(lp["attn"], h, a, positions,
+                                      norm_eps=cfg.norm_eps)
+            d = jnp.where(keep, off + dest, Ls * rows)
+            kf = kf.at[d].set(k.reshape(-1, KH, hd).astype(kf.dtype),
+                              mode="drop")
+            vf = vf.at[d].set(v.reshape(-1, KH, hd).astype(vf.dtype),
+                              mode="drop")
+            kfull = jnp.take(kf, off + gidx, axis=0).reshape(B, W, KH, hd)
+            vfull = jnp.take(vf, off + gidx, axis=0).reshape(B, W, KH, hd)
+            o = L.blocked_attention(q, kfull, vfull, positions, kv_pos,
+                                    kv_chunk=max(W, 1024))
+            h = L.attention_out(lp["attn"], o)
+        else:
+            c = seg.slot + i
+            st = jnp.where(fresh, jnp.zeros((), conv.dtype), conv[c])
+            with jax.named_scope("short_conv"):
+                h, st = SSM.short_conv(cfg, lp["conv"], h, st, valid)
+            conv = conv.at[c].set(st)
+        x = x + h
+        h, _ = _segment_ffn(cfg, seg, lp, _normed(cfg, lp["ln2"], x))
+        return (x + h, kf, vf, conv), None
+
+    carry = (x, cache.k.reshape(Ls * rows, KH, hd),
+             cache.v.reshape(Ls * rows, KH, hd), cache.conv)
+    for seg, lp_seg in zip(segments(cfg), p["layers"]):
+        carry, _ = jax.lax.scan(
+            lambda c_, xs, seg=seg: body(c_, xs, seg), carry,
+            (lp_seg, jnp.arange(seg.n)))
+    x, kf, vf, conv = carry
+    new_cache = Cache(k=kf.reshape(cache.k.shape), v=vf.reshape(cache.v.shape),
+                      conv=conv, pos=cache.pos)
+    x = _normed(cfg, p["final_norm"], x)
     li = jnp.clip(valid - 1, 0, T - 1)
     xlast = jnp.take_along_axis(x, li[:, None, None], axis=1)   # (B, 1, D)
     logits = unembed(cfg, p, xlast)

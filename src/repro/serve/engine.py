@@ -21,16 +21,28 @@ The fast path (every transformer-cache family):
     Chunking is numerics-neutral: greedy outputs are bitwise identical for
     any chunk size (the property benchmarks/cluster_session.py pins) for
     every family whose per-token compute is batch-lane independent.  The
-    one caveat is MoE capacity coupling: admission lands on chunk
-    boundaries, so chunk size can shift WHEN a freed slot's lane flips from
-    a frozen repeat-token to a fresh request, and a saturated expert's
-    token-drop choice sees those lane contents (identical admission
-    schedules — e.g. uniform budgets — are still bitwise stable).
+    one caveat is the capacity coupling of a *dropping* MoE layer:
+    admission lands on chunk boundaries, so chunk size can shift WHEN a
+    freed slot's lane flips from a frozen repeat-token to a fresh request,
+    and a saturated expert's token-drop choice sees those lane contents
+    (identical admission schedules — e.g. uniform budgets — are still
+    bitwise stable).  The held-expert layer of the pooled path
+    (`moe.moe_held`) drops nothing, so it has no such coupling.
 
 Batching discipline: one batch-1 prefill program + one chunked decode
 program, both jit'd once — the static-shape serving pattern TPU serving
 stacks use.  The whisper enc-dec family keeps the legacy full-batch
 prefill + per-token loop (its cache layout has no per-slot insert yet).
+
+A model with short-convolution layers (a per-layer mixer schedule, lfm2)
+keeps two kinds of state in one manager: the block pool holds KV for its
+attention layers, and each slot carries its conv layers' state beside the
+pool — reset when a prompt is admitted, written by each prefill dispatch,
+advanced by every decode step.  It serves on the pooled layout with
+``kv_share=False`` only: a shared prefix's blocks carry no conv state, and
+the dense per-slot layout has none at all.  Its held-expert load per
+decode step comes back with the chunk's tokens and is counted in
+``serve.moe_pairs`` / ``serve.moe_experts_touched``.
 """
 from __future__ import annotations
 
@@ -154,7 +166,8 @@ def _fast_programs(cfg: ModelConfig, spec: SliceSpec, ctx: ParallelContext):
         with activate(ctx):
             return api.decode_n(
                 cfg, params, cache, tokens, seq_lens, budget, ctx,
-                num_steps=num_steps, greedy=spec.greedy, key=key, salt=salt)
+                num_steps=num_steps, greedy=spec.greedy, key=key,
+                salt=salt) + (None,)
 
     return (jax.jit(_admit, donate_argnums=(1,)),
             jax.jit(_decode, donate_argnums=(1,), static_argnums=(7,)))
@@ -192,13 +205,17 @@ def _pooled_programs(cfg: ModelConfig, spec: SliceSpec, ctx: ParallelContext):
         salt = jnp.where(commit, rids, salt)
         return nxt, cache, seq_lens, last, salt
 
+    # a schedule with MoE layers reports its held-expert load per step
+    load = bool(cfg.mixers) and cfg.moe is not None
+
     def _decode(params, cache, tokens, seq_lens, budget, key, salt, tables,
                 num_steps):
         with activate(ctx):
-            return api.decode_n(
+            out = api.decode_n(
                 cfg, params, cache, tokens, seq_lens, budget, ctx,
                 num_steps=num_steps, greedy=spec.greedy, key=key, salt=salt,
-                tables=tables)
+                tables=tables, moe_load=load)
+        return out if load else out + (None,)
 
     return (jax.jit(_admit, donate_argnums=(1,)),
             jax.jit(_decode, donate_argnums=(1,), static_argnums=(8,)))
@@ -273,7 +290,8 @@ class ServeEngine:
         # whisper's enc-dec cache has no per-slot insert; it keeps the
         # legacy full-batch prefill + per-token decode loop
         self._fast = cfg.family != "audio"
-        # pooled prefix-shared KV (kvpool.py); dense-transformer only
+        # pooled prefix-shared KV (kvpool.py): dense attention stacks and
+        # per-layer mixer schedules
         self._pooled = self._fast and spec.kv_block > 0
         # prefill-cost proxy (dispatch width x batch rows, summed over
         # prefill dispatches) + prefix-sharing counters — the kv-prefix
@@ -292,9 +310,22 @@ class ServeEngine:
         self._c_mig_suffix = reg.counter(
             "serve.kv_migrated_suffix_blocks", **labels)
 
+        if cfg.conv_layers and not self._pooled:
+            raise ValueError(
+                f"{cfg.name} keeps short-convolution state, which only the "
+                "pooled KV layout carries: serve it with kv_block > 0")
+        if cfg.conv_layers and spec.kv_share:
+            raise ValueError(
+                f"{cfg.name} keeps short-convolution state, and a shared "
+                "prefix's blocks carry none: serve it with kv_share=False")
+        self._c_moe_pairs = reg.counter("serve.moe_pairs", **labels)
+        self._c_moe_touched = reg.counter("serve.moe_experts_touched",
+                                          **labels)
         if self._pooled:
-            assert cfg.family == "dense", \
-                "pooled prefix-shared KV is dense-transformer only"
+            if not api.has_pooled_layout(cfg):
+                raise NotImplementedError(
+                    f"{cfg.name}: no pooled KV layout for the "
+                    f"{cfg.family} family")
             nb = spec.max_len // spec.kv_block
             self._nb = nb
             self._suffix_len = spec.suffix_len or spec.prompt_len
@@ -425,7 +456,8 @@ class ServeEngine:
         ``(slot, request, start, seq)`` per admitted request."""
         if self.cache is None:
             self.cache = api.init_kv_pool(
-                self.cfg, self.kvpool.num_blocks, self.spec.kv_block)
+                self.cfg, self.kvpool.num_blocks, self.spec.kv_block,
+                slots=self.slots)
         admitted = self.pending[:len(free)]
         del self.pending[:len(free)]
         bs = self.spec.kv_block
@@ -510,15 +542,22 @@ class ServeEngine:
             t0 = time.perf_counter()
             with self.obs.span("serve.decode.dispatch"):
                 extra = (self.tables,) if self._pooled else ()
-                toks, self.cache, self.seq_lens, self.last_tokens = \
+                toks, self.cache, self.seq_lens, self.last_tokens, load = \
                     self._decode_fn(
                         self.params, self.cache, self.last_tokens,
                         self.seq_lens, jnp.asarray(budgets),
                         self._sample_key, self.sample_salt, *extra,
                         num_steps)
             with self.obs.span("serve.decode.sync"):
-                toks = np.asarray(toks)              # (num_steps, B)
+                # (num_steps, B) tokens, and the held-expert load beside
+                toks, load = jax.device_get((toks, load))
             self._record_latency(time.perf_counter() - t0)
+            if load is not None:
+                pairs, touched = (int(n) for n in load.sum(axis=(0, 1)))
+                with self.obs.span("serve.decode.moe", pairs=pairs,
+                                   touched=touched, steps=num_steps):
+                    self._c_moe_pairs.inc(pairs)
+                    self._c_moe_touched.inc(touched)
             self._steps += num_steps
             with self.obs.span("serve.decode.bookkeeping"):
                 now = time.perf_counter()

@@ -66,7 +66,11 @@ def test_smoke_train_step_runs_and_loss_finite(arch):
     assert float(metrics["grad_norm"]) > 0
 
 
-@pytest.mark.parametrize("arch", [a for a in ARCHS if a != "dlrm0"])
+# a per-layer mixer schedule serves on the pooled layout only; its pooled
+# prefill and decode are checked against the float32 reference in
+# tests/bench/test_bench_lfm2.py
+@pytest.mark.parametrize("arch", [a for a in ARCHS if a != "dlrm0"
+                                  and not registry.get_config(a).mixers])
 def test_prefill_decode_matches_forward(arch):
     cfg = registry.get_reduced(arch)
     key = jax.random.PRNGKey(2)

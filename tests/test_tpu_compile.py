@@ -105,6 +105,21 @@ def test_paged_decode_block_table_compiles(one_chip, dtype):
     assert "tpu_custom_call" in hlo
 
 
+def test_paged_decode_block_table_compiles_at_lfm2_widths(one_chip):
+    """lfm2-8b-a1b's serving shapes: GQA 32/8 heads of 64 over the pool of
+    its 6 attention layers (192 blocks of 128 rows each), 12 slots of 2048
+    rows."""
+    b, nb = 12, 16
+    q = jax.ShapeDtypeStruct((b, 32, 64), jnp.bfloat16, sharding=one_chip)
+    lens = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one_chip)
+    pool = jax.ShapeDtypeStruct((6 * 192, 128, 8, 64), jnp.bfloat16,
+                                sharding=one_chip)
+    tables = jax.ShapeDtypeStruct((b, nb), jnp.int32, sharding=one_chip)
+    hlo = _compile(lambda q, k, v, n, t: paged_decode_attention_bt_kernel_call(
+        q, k, v, n, t, interpret=False), q, pool, pool, lens, tables)
+    assert "tpu_custom_call" in hlo
+
+
 def test_flash_attention_compiles(one_chip):
     q = jax.ShapeDtypeStruct((1, H, S, D), jnp.bfloat16, sharding=one_chip)
     kv = jax.ShapeDtypeStruct((1, KH, S, D), jnp.bfloat16, sharding=one_chip)
