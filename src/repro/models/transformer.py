@@ -1214,7 +1214,7 @@ def init_kv_pool(cfg: ModelConfig, num_blocks: int, block_size: int,
 
 
 def prefill_suffix(cfg: ModelConfig, p, cache: Cache, tokens, start, valid,
-                   tables, ctx: ParallelContext = LOCAL
+                   tables, ctx: ParallelContext = LOCAL, *, slots=None
                    ) -> Tuple[jax.Array, Cache]:
     """Fixed-width suffix prefill over a pooled KV cache.
 
@@ -1224,7 +1224,11 @@ def prefill_suffix(cfg: ModelConfig, p, cache: Cache, tokens, start, valid,
     start (B,) int32 — logical position of ``tokens[:, 0]`` (the shared /
     already-prefilled prefix length for this chunk);
     valid (B,) int32 — valid suffix tokens this dispatch (0 = idle row);
-    tables (B, nb) int32 — slot block tables (out-of-range = unadmitted).
+    tables (B, nb) int32 — each row's slot block table (out-of-range =
+    unadmitted);
+    slots (B,) int32, optional — the slot each row prefills (None: row b is
+    slot b).  Only per-slot state beside the pool (a schedule's conv
+    state) is indexed by it; an out-of-range slot's state writes drop.
 
     Each layer scatters the fresh suffix KV into its pool rows FIRST, then
     gathers the slot's full logical view (prefix blocks written by earlier
@@ -1242,7 +1246,7 @@ def prefill_suffix(cfg: ModelConfig, p, cache: Cache, tokens, start, valid,
     """
     if cfg.mixers:
         return _prefill_suffix_scheduled(cfg, p, cache, tokens, start, valid,
-                                         tables)
+                                         tables, slots)
     a = cfg.attention
     assert cfg.family == "dense" and not p.get("dense_prefix"), \
         "prefill_suffix supports dense attention families"
@@ -1310,11 +1314,12 @@ def prefill_suffix(cfg: ModelConfig, p, cache: Cache, tokens, start, valid,
 
 
 def _prefill_suffix_scheduled(cfg: ModelConfig, p, cache: Cache, tokens,
-                              start, valid, tables) -> Tuple[jax.Array, Cache]:
+                              start, valid, tables, slots=None
+                              ) -> Tuple[jax.Array, Cache]:
     """`prefill_suffix` of a per-layer mixer schedule.  The pool (layer and
     block axes merged) and the conv state ride the layer loop's carry, so
     each attention layer scatters its fresh rows and gathers its logical
-    view in place."""
+    view in place; each conv layer reads and writes its rows' slots."""
     a = cfg.attention
     B, T = tokens.shape
     Ls, NB, bs, KH, hd = cache.k.shape
@@ -1324,6 +1329,8 @@ def _prefill_suffix_scheduled(cfg: ModelConfig, p, cache: Cache, tokens,
     tables = tables.astype(jnp.int32)
     start = start.astype(jnp.int32)
     valid = valid.astype(jnp.int32)
+    if slots is not None:
+        slots = jnp.asarray(slots, jnp.int32)
 
     x = embed_tokens(cfg, p, tokens).astype(jnp.float32)
     positions = start[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
@@ -1360,10 +1367,13 @@ def _prefill_suffix_scheduled(cfg: ModelConfig, p, cache: Cache, tokens,
             h = L.attention_out(lp["attn"], o)
         else:
             c = seg.slot + i
-            st = jnp.where(fresh, jnp.zeros((), conv.dtype), conv[c])
+            held = (conv[c] if slots is None
+                    else jnp.take(conv[c], slots, axis=0, mode="clip"))
+            st = jnp.where(fresh, jnp.zeros((), conv.dtype), held)
             with jax.named_scope("short_conv"):
                 h, st = SSM.short_conv(cfg, lp["conv"], h, st, valid)
-            conv = conv.at[c].set(st)
+            conv = (conv.at[c].set(st) if slots is None
+                    else conv.at[c, slots].set(st, mode="drop"))
         x = x + h
         h, _ = _segment_ffn(cfg, seg, lp, _normed(cfg, lp["ln2"], x))
         return (x + h, kf, vf, conv), None

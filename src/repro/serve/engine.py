@@ -48,6 +48,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 import time
 from typing import Any, Dict, List, Optional
 
@@ -56,6 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.core.costmodel import TPU_V4
 from repro.models import api
 from repro.models import quant as QUANT
 from repro.obs import Telemetry
@@ -77,8 +79,9 @@ class SliceSpec:
     ``kv_block > 0`` switches the engine to the POOLED prefix-shared KV
     cache (`serve/kvpool.py`): per-slot cache rows become indirection tables
     over a shared block pool, admissions sharing a prompt prefix reuse
-    already-prefilled blocks, and prefill runs as fixed-width
-    ``suffix_len``-token dispatches over only the unshared suffix.
+    already-prefilled blocks, and prefill runs as ``suffix_len``-token
+    dispatches over only the unshared suffix, one admitted request per row
+    (the row count follows the weights' bytes: `admission_rows`).
     ``kv_share=False`` keeps the pooled layout but never matches/publishes —
     the bitwise-identity baseline arm.  ``kv_blocks`` sizes the pool
     (0 = 2x the table capacity, so published prefixes survive slot churn).
@@ -91,8 +94,8 @@ class SliceSpec:
     kv_block: int = 0               # pooled KV block size (0 = dense cache)
     kv_share: bool = True           # match/publish prompt prefixes
     kv_blocks: int = 0              # pool size (0 = 2 * slots * table width)
-    suffix_len: int = 0             # suffix-prefill dispatch width
-                                    # (0 = prompt_len)
+    suffix_len: int = 0             # suffix-prefill tokens per row and
+                                    # dispatch (0 = prompt_len)
     quant: str = "none"             # weight storage: "none" | "int8"
                                     # (models/quant.py tile-wise int8; the
                                     # engine quantises its params at init)
@@ -122,6 +125,25 @@ class Request:
     t_submit: float = 0.0
     t_first: Optional[float] = None
     t_done: Optional[float] = None
+
+
+# tokens of one dispatch per byte of a stored weight at which it stops
+# being bound by its weight stream: peak FLOP/s over HBM bytes/s, over the
+# 2 FLOPs a weight costs per token (one fixed preset, TPU v4's)
+_RIDGE_PER_WEIGHT_BYTE = TPU_V4.peak_flops_bf16 / TPU_V4.hbm_bw / 2
+
+
+def admission_rows(slots: int, suffix_len: int, weight_bytes: float) -> int:
+    """Rows of one pooled admission dispatch: as many ``suffix_len``-token
+    rows as it takes to reach the ridge (`_RIDGE_PER_WEIGHT_BYTE` times the
+    bytes of a stored weight), at most ``slots``.  A
+    ``suffix_len`` of 512 gives 1 for weights of up to 4 bytes, so a wave
+    of fewer requests than slots does not pay for the empty rows.  The
+    R > 1 side (short rows, where a further row is assumed to cost
+    nothing as the weights are read anyway) has not been measured on a
+    chip: no benchmark cell runs it, only small test engines."""
+    ridge = _RIDGE_PER_WEIGHT_BYTE * weight_bytes
+    return max(1, min(slots, math.ceil(ridge / suffix_len)))
 
 
 def _pct(xs: List[float], q: float) -> float:
@@ -177,19 +199,22 @@ def _fast_programs(cfg: ModelConfig, spec: SliceSpec, ctx: ParallelContext):
 def _pooled_programs(cfg: ModelConfig, spec: SliceSpec, ctx: ParallelContext):
     """Jit'd suffix-prefill admission + pooled chunked decode.
 
-    The admission program is SLOT-ALIGNED (row i == slot i) and fixed-width
-    (``suffix_len`` tokens): a long suffix prefills in several chained
-    dispatches of this one program, and only rows whose ``commit`` flag is
-    set (the chunk holding their last prompt token) fold their logits into
-    the decode state — everything else is a masked no-op, so idle rows and
-    mid-suffix chunks never perturb live slots."""
+    Each admission row prefills ``suffix_len`` tokens of the request in the
+    slot ``rows`` names for it (padding rows name slot ``spec.slots``, out
+    of range, so their writes drop); the engine picks the row count once
+    (`admission_rows`), so this is one compiled program.  A long suffix
+    prefills in several chained dispatches, and only rows whose ``commit``
+    flag is set (the chunk holding their last prompt token) fold their
+    logits into the decode state — everything else is a masked no-op, so
+    idle rows and mid-suffix chunks never perturb live slots."""
     sample_key = jax.random.PRNGKey(spec.slots)
 
-    def _admit(params, cache, tokens, start, valid, tables, rids, plens,
-               commit, seq_lens, last, salt):
+    def _admit(params, cache, tokens, start, valid, tables, rows, rids,
+               plens, commit, seq_lens, last, salt):
         with activate(ctx):
             logits, cache = api.prefill_suffix(
-                cfg, params, cache, tokens, start, valid, tables, ctx)
+                cfg, params, cache, tokens, start, valid, tables, ctx,
+                slots=rows)
         if spec.greedy:
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         else:
@@ -200,9 +225,10 @@ def _pooled_programs(cfg: ModelConfig, spec: SliceSpec, ctx: ParallelContext):
                 jax.random.fold_in(sample_key, b), n))(rids, plens)
             nxt = jax.vmap(jax.random.categorical)(
                 keys, logits).astype(jnp.int32)
-        seq_lens = jnp.where(commit, plens, seq_lens)
-        last = jnp.where(commit, nxt, last)
-        salt = jnp.where(commit, rids, salt)
+        at = jnp.where(commit, rows, spec.slots)
+        seq_lens = seq_lens.at[at].set(plens, mode="drop")
+        last = last.at[at].set(nxt, mode="drop")
+        salt = salt.at[at].set(rids, mode="drop")
         return nxt, cache, seq_lens, last, salt
 
     # a schedule with MoE layers reports its held-expert load per step
@@ -241,9 +267,8 @@ class ServeEngine:
     """Continuous-batching serving engine (the PR-3 fast path).
 
     One engine owns `spec.slots` decode slots over a paged KV cache:
-    admission prefills ONLY the admitted requests (one fixed-width
-    dispatch), decode advances all slots `spec.chunk` tokens per dispatch
-    with on-device sampling and done-masking, and per-slot valid lengths
+    admission prefills ONLY the admitted requests, decode advances all
+    slots `spec.chunk` tokens per dispatch with on-device sampling and done-masking, and per-slot valid lengths
     drive the paged decode-attention kernel.  Greedy outputs are bitwise
     chunk-invariant.
 
@@ -329,6 +354,10 @@ class ServeEngine:
             nb = spec.max_len // spec.kv_block
             self._nb = nb
             self._suffix_len = spec.suffix_len or spec.prompt_len
+            leaves = jax.tree.leaves(params)
+            self._rows = admission_rows(
+                spec.slots, self._suffix_len,
+                QUANT.storage_bytes(params) / sum(x.size for x in leaves))
             self.kvpool = KVPool(
                 num_blocks=spec.kv_blocks or 2 * spec.slots * nb,
                 block_size=spec.kv_block, slots=spec.slots,
@@ -432,11 +461,12 @@ class ServeEngine:
     def _admit_pooled(self) -> bool:
         """Pooled admission: map each admitted prompt's shared prefix onto
         already-prefilled pool blocks (kvpool.admit) and prefill ONLY the
-        unshared suffix in fixed-width ``suffix_len`` chunks — a request
-        whose whole prompt header is cached pays one small dispatch instead
-        of a full-width prefill.  Publication into the prefix trie happens
-        AFTER the dispatches land, so two same-wave admissions can never
-        alias blocks still being written."""
+        unshared suffix, ``suffix_len`` tokens per dispatch row, one row per
+        admitted request — a request whose whole prompt header is cached
+        pays one short row, and a one-request wave computes one row, not
+        one per slot.  Publication into the prefix trie happens AFTER the
+        dispatches land, so two same-wave admissions can never alias blocks
+        still being written."""
         if not self.pending:
             return False
         free = [i for i, a in enumerate(self.active)
@@ -475,46 +505,60 @@ class ServeEngine:
         return rows
 
     def _prefill_pooled(self, rows: list) -> None:
-        """Prefill the admitted suffixes in ``suffix_len``-token dispatches
-        and hand each request its first token."""
-        Tc = self._suffix_len
-        nchunk = max(1, -(-max(len(seq) - start
-                               for (_, _, start, seq) in rows) // Tc))
-        nxt_keep = np.zeros((self.slots,), np.int32)
-        for c in range(nchunk):
-            tok = np.zeros((self.slots, Tc), np.int32)
-            st = np.zeros((self.slots,), np.int32)
-            vd = np.zeros((self.slots,), np.int32)
-            rids = np.zeros((self.slots,), np.int32)
-            plens = np.zeros((self.slots,), np.int32)
-            commit = np.zeros((self.slots,), bool)
-            for slot, r, start, seq in rows:
-                s0 = start + c * Tc
-                v = max(0, min(Tc, len(seq) - s0))
-                st[slot] = min(s0, len(seq))
-                vd[slot] = v
-                rids[slot] = r.rid
-                plens[slot] = len(seq)
-                if v:
-                    tok[slot, :v] = seq[s0:s0 + v]
-                    commit[slot] = s0 + v == len(seq)
-            self._c_prefill.inc(Tc * self.slots)
-            with self.obs.span("serve.admit.prefill", tokens=int(vd.sum()),
-                               width=self.slots * Tc):
-                nxt, self.cache, self.seq_lens, self.last_tokens, \
-                    self.sample_salt = self._admit_fn(
-                        self.params, self.cache, jnp.asarray(tok),
-                        jnp.asarray(st), jnp.asarray(vd), self.tables,
-                        jnp.asarray(rids), jnp.asarray(plens),
-                        jnp.asarray(commit), self.seq_lens,
-                        self.last_tokens, self.sample_salt)
-            if commit.any():
-                with self.obs.span("serve.admit.sync"):
-                    nxt_np = np.asarray(nxt)
-                nxt_keep[commit] = nxt_np[commit]
+        """Prefill the admitted suffixes and hand each request its first
+        token.  The wave runs in groups of ``self._rows`` requests, one per
+        dispatch row (padding rows name no slot); each group chains
+        ``suffix_len``-token dispatches until its longest suffix is in.
+        The first tokens are read once, after the wave's last dispatch."""
+        R, Tc = self._rows, self._suffix_len
+        firsts = []             # (device tokens, group start, commit rows)
+        for g in range(0, len(rows), R):
+            group = rows[g:g + R]
+            slot_of = np.full((R,), self.slots, np.int32)
+            tables = np.full((R, self._nb), self.kvpool.num_blocks, np.int32)
+            rids = np.zeros((R,), np.int32)
+            plens = np.zeros((R,), np.int32)
+            for row, (slot, r, _, seq) in enumerate(group):
+                slot_of[row], rids[row], plens[row] = slot, r.rid, len(seq)
+                tables[row] = self._tables_np[slot]
+            slot_of, tables, rids, plens = (
+                jnp.asarray(x) for x in (slot_of, tables, rids, plens))
+            nchunk = max(1, -(-max(len(seq) - start
+                                   for (_, _, start, seq) in group) // Tc))
+            for c in range(nchunk):
+                tok = np.zeros((R, Tc), np.int32)
+                st = np.zeros((R,), np.int32)
+                vd = np.zeros((R,), np.int32)
+                commit = np.zeros((R,), bool)
+                for row, (_, _, start, seq) in enumerate(group):
+                    s0 = start + c * Tc
+                    v = max(0, min(Tc, len(seq) - s0))
+                    st[row] = min(s0, len(seq))
+                    vd[row] = v
+                    if v:
+                        tok[row, :v] = seq[s0:s0 + v]
+                        commit[row] = s0 + v == len(seq)
+                self._c_prefill.inc(Tc * R)
+                with self.obs.span("serve.admit.prefill",
+                                   tokens=int(vd.sum()), width=R * Tc,
+                                   rows=R):
+                    nxt, self.cache, self.seq_lens, self.last_tokens, \
+                        self.sample_salt = self._admit_fn(
+                            self.params, self.cache, jnp.asarray(tok),
+                            jnp.asarray(st), jnp.asarray(vd), tables,
+                            slot_of, rids, plens, jnp.asarray(commit),
+                            self.seq_lens, self.last_tokens,
+                            self.sample_salt)
+                if commit.any():
+                    firsts.append((nxt, g, commit))
+        with self.obs.span("serve.admit.sync"):
+            got = jax.device_get([nxt for nxt, _, _ in firsts])
+        first = np.zeros((len(rows),), np.int32)
+        for toks, (_, g, commit) in zip(got, firsts):
+            first[g + np.flatnonzero(commit)] = toks[commit]
         now = time.perf_counter()
-        for slot, r, start, seq in rows:
-            r.out_tokens.append(int(nxt_keep[slot]))
+        for (slot, r, _, _), tok in zip(rows, first):
+            r.out_tokens.append(int(tok))
             r.t_first = now
             if len(r.out_tokens) >= r.max_new_tokens:
                 r.done = True

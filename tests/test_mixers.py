@@ -259,3 +259,43 @@ def test_dense_pooled_decode_reports_no_load():
     assert len(api.decode_n(cfg, params, cache, out[3], out[2],
                             jnp.ones((2,), jnp.int32), num_steps=1,
                             tables=tables)) == 4
+
+
+def test_one_row_admission_keeps_the_other_slots_conv_state(model,
+                                                            admitted):
+    """A prompt admitted into slot 2 of 3 by one-row dispatches (row ->
+    slot through ``slots``) leaves slots 0 and 1's conv state bit for bit,
+    and gives the tokens of the slot-aligned admission."""
+    cfg, params = model
+    cache, tables, lens, toks = admitted
+    prompt = np.random.default_rng(2).integers(
+        0, cfg.vocab_size, 19).astype(np.int32)
+    prefill = jax.jit(lambda c, t, s, v, tb, rows: api.prefill_suffix(
+        cfg, params, c, t, s, v, tb, slots=rows))
+
+    def admit(c, aligned):
+        rows = 3 if aligned else 1
+        row = 2 if aligned else 0
+        for c0 in range(0, len(prompt), 8):
+            v = min(8, len(prompt) - c0)
+            tok = np.zeros((rows, 8), np.int32)
+            st, vd = np.zeros(rows, np.int32), np.zeros(rows, np.int32)
+            tok[row, :v], st[row], vd[row] = prompt[c0:c0 + v], c0, v
+            logits, c = prefill(
+                c, jnp.asarray(tok), jnp.asarray(st), jnp.asarray(vd),
+                tables if aligned else tables[2:3],
+                None if aligned else jnp.asarray([2], jnp.int32))
+        return int(jnp.argmax(logits[row])), c
+
+    first, one = admit(cache, aligned=False)
+    np.testing.assert_array_equal(one.conv[:, :2], cache.conv[:, :2])
+    assert np.any(np.asarray(one.conv[:, 2]) != np.asarray(cache.conv[:, 2]))
+    want, aligned = admit(cache, aligned=True)
+    assert first == want
+    # and the decode that follows, on slot 2
+    n = lens.at[2].set(len(prompt))
+    t = toks.at[2].set(first)
+    budget = jnp.asarray([0, 0, 6], jnp.int32)
+    got = [api.decode_n(cfg, params, c, t, n, budget, num_steps=6,
+                        tables=tables)[0][:, 2] for c in (one, aligned)]
+    np.testing.assert_array_equal(got[0], got[1])

@@ -505,3 +505,146 @@ def _out_sizes(jaxpr):
                 inner = getattr(sub, "jaxpr", sub)
                 if hasattr(inner, "eqns"):
                     yield from _out_sizes(inner)
+
+
+class TestAdmissionRows:
+    """A pooled admission dispatch carries one admitted request per row,
+    and as many rows as reach the ridge of the weights' bytes
+    (`admission_rows`), not one row per slot: a wave of fewer requests
+    computes fewer rows, with the same tokens, in one compiled program."""
+
+    SPEC = SliceSpec(slots=3, max_len=64, prompt_len=40, chunk=4,
+                     kv_block=8, kv_share=False, suffix_len=8)
+
+    @pytest.mark.parametrize("mix", ["chat", "reason"])
+    @pytest.mark.parametrize("weight_bytes", [4, 2, 1],
+                             ids=["f32", "bf16", "int8"])
+    def test_rule_gives_one_row_to_the_serving_cells(self, mix,
+                                                     weight_bytes):
+        import json
+        import pathlib
+
+        from repro.serve.engine import admission_rows
+
+        path = (pathlib.Path(__file__).resolve().parents[1] / "bench"
+                / "traffic" / f"{mix}.json")
+        spec = SliceSpec(**json.loads(path.read_text())["engine"])
+        assert admission_rows(spec.slots, spec.suffix_len,
+                              weight_bytes) == 1
+
+    def test_rule_keeps_every_slot_for_short_rows(self, small_model):
+        from repro.serve.engine import admission_rows
+
+        assert admission_rows(2, 8, 4) == 2
+        assert admission_rows(2, 8, 2) == 2
+        # the engine reads its weights' bytes: float32 here
+        cfg, params = small_model
+        wide = dataclasses.replace(self.SPEC, suffix_len=512, prompt_len=64)
+        assert ServeEngine(cfg, params, wide)._rows == 1
+        assert ServeEngine(cfg, params, self.SPEC)._rows == 3
+
+    def _engine(self, cfg, params, spec, rows, monkeypatch, obs=None):
+        from repro.serve import engine as ENGINE
+
+        if rows is not None:
+            monkeypatch.setattr(ENGINE, "admission_rows", lambda *a: rows)
+        eng = ServeEngine(cfg, params, spec, obs=obs)
+        if rows is not None:
+            assert eng._rows == rows
+        return eng
+
+    def _prompts(self, cfg):
+        rng = np.random.RandomState(5)
+        # 20 tokens span three 8-token chunks
+        return [rng.randint(0, cfg.vocab_size, (n,)).astype(np.int32)
+                for n in (11, 4, 17, 20, 9, 14)]
+
+    def _serve(self, eng, prompts, check_admission=None):
+        """Waves of 3, then 1 (a three-chunk prompt), then 2 while that
+        one is mid-decode."""
+        reqs = [eng.submit(p, max_new_tokens=5) for p in prompts[:3]]
+        while any(not r.done for r in reqs):
+            eng.step_chunk()
+        reqs.append(eng.submit(prompts[3], max_new_tokens=12))
+        eng.step_chunk()
+        eng.step_chunk()
+        reqs += [eng.submit(p, max_new_tokens=6) for p in prompts[4:]]
+        if check_admission:
+            check_admission(eng)
+        while any(not r.done for r in reqs):
+            eng.step_chunk()
+        return [list(r.out_tokens) for r in reqs]
+
+    def test_tokens_match_across_row_counts_and_dense(self, small_model,
+                                                      monkeypatch):
+        cfg, params = small_model
+        prompts = self._prompts(cfg)
+        dense = self._serve(ServeEngine(cfg, params, dataclasses.replace(
+            self.SPEC, kv_block=0)), prompts)
+        every = self._serve(self._engine(cfg, params, self.SPEC, None,
+                                         monkeypatch), prompts)
+
+        def untouched(eng):
+            """Admitting two requests leaves the decoding slot's pool
+            rows, length and last token, and every block the admitted
+            slots do not own, as they were."""
+            live = [i for i, r in enumerate(eng.active)
+                    if r is not None and not r.done]
+            assert len(live) == 1
+            before = [np.array(x) for x in (eng.cache.k, eng.cache.v,
+                                            eng.seq_lens, eng.last_tokens)]
+            eng._admit()
+            admitted = [i for i in range(eng.slots) if i not in live]
+            owned = {int(b) for i in admitted for b in eng._tables_np[i]}
+            keep = [b for b in range(eng.kvpool.num_blocks)
+                    if b not in owned]
+            assert set(eng._tables_np[live[0]]) <= set(keep)
+            after = [np.array(x) for x in (eng.cache.k, eng.cache.v,
+                                           eng.seq_lens, eng.last_tokens)]
+            for old, new in zip(before[:2], after[:2]):
+                np.testing.assert_array_equal(new[:, keep], old[:, keep])
+            for old, new in zip(before[2:], after[2:]):
+                np.testing.assert_array_equal(new[live], old[live])
+
+        one = self._serve(self._engine(cfg, params, self.SPEC, 1,
+                                       monkeypatch), prompts, untouched)
+        assert one == every == dense
+
+    @pytest.mark.parametrize("rows", [1, 2, 3])
+    def test_spans_count_the_dispatched_rows(self, small_model, monkeypatch,
+                                             rows):
+        from repro.obs import Telemetry
+
+        cfg, params = small_model
+        obs = Telemetry(tracing=True)
+        eng = self._engine(cfg, params, self.SPEC, rows, monkeypatch, obs)
+        self._serve(eng, self._prompts(cfg))
+        spans = [s for s in obs.tracer.spans
+                 if s.name == "serve.admit.prefill"]
+        assert spans and all(s.args["rows"] == rows
+                             and s.args["width"] == rows * 8
+                             for s in spans)
+        assert eng.prefill_flops_proxy == sum(s.args["width"]
+                                              for s in spans)
+        # every prompt token is prefilled once
+        assert sum(s.args["tokens"] for s in spans) == sum(
+            len(p) for p in self._prompts(cfg))
+
+    def test_admit_compiles_once(self, small_model, monkeypatch):
+        cfg, params = small_model
+        spec = dataclasses.replace(self.SPEC, max_len=72)
+        eng = self._engine(cfg, params, spec, 1, monkeypatch)
+        sizes = []
+
+        def admit(n, start):
+            for i in range(n):
+                eng.submit(np.arange(start + i, start + i + 10) %
+                           cfg.vocab_size, max_new_tokens=2)
+            eng._admit()
+            sizes.append(eng._admit_fn._cache_size())
+            eng.run()
+
+        admit(1, 0)
+        admit(2, 3)
+        admit(3, 7)
+        assert sizes == [1, 1, 1]
