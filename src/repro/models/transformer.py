@@ -19,7 +19,10 @@ Design notes
     stacked and scanned, the runs unrolled in order.  Such a model serves
     on the pooled layout only: KV for its attention layers in the block
     pool, and per slot the short convolutions' state beside it.
-  * The KV cache is stacked over layers, scanned as xs/ys.
+  * Every model with the pooled layout prefills and decodes through one
+    layer loop over its runs (`_pooled_layers`), the pool in the scan's
+    carry, read and written in place.  The dense per-slot cache is stacked
+    over layers and scanned as xs/ys.
 """
 from __future__ import annotations
 
@@ -154,11 +157,18 @@ def _init_segment(cfg: ModelConfig, seg: Segment, key):
     return lp
 
 
+def _stream_dtype(cfg: ModelConfig):
+    """The residual stream's dtype: float32 for a schedule (`_normed`)."""
+    return jnp.float32 if cfg.mixers else jnp.bfloat16
+
+
 def _normed(cfg: ModelConfig, w, x, dtype=jnp.bfloat16):
-    """A scheduled layer's norm: read the float32 residual stream, hand the
-    products bf16.  The stream stays float32 because the router's top-k is
-    a discrete choice: a bf16 stream rounds once per layer, and where two
-    experts' biased scores lie within that rounding the choice flips."""
+    """A scheduled or pooled layer's norm: read the residual stream, hand
+    the products bf16.  A schedule's stream stays float32 because the
+    router's top-k is a discrete choice: a bf16 stream rounds once per
+    layer, and where two experts' biased scores lie within that rounding
+    the choice flips.  On a bf16 stream this is `layers.apply_norm`, whose
+    norms all return their input's dtype."""
     return L.apply_norm(cfg, w, x).astype(dtype)
 
 
@@ -438,7 +448,7 @@ def _forward_scheduled(cfg: ModelConfig, p, tokens, *, remat: bool,
     scanned, attention over the whole sequence, conv from a zero state."""
     a = cfg.attention
     B, T = tokens.shape
-    x = embed_tokens(cfg, p, tokens).astype(jnp.float32)
+    x = embed_tokens(cfg, p, tokens).astype(_stream_dtype(cfg))
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
     full = jnp.full((B,), T, jnp.int32)
     for seg, lp_seg in zip(segments(cfg), p["layers"]):
@@ -929,146 +939,17 @@ def decode_step_paged(cfg: ModelConfig, p, cache: Cache, tokens, seq_lens,
     return logits[:, 0], new_cache, seq_lens + act_i
 
 
-def decode_step_pooled(cfg: ModelConfig, p, kv, tokens, seq_lens, active,
-                       tables, ctx: ParallelContext = LOCAL):
-    """One decode step over a pooled KV cache, read and written in place.
-
-    kv — (k, v, conv): k and v each ``init_kv_pool``'s (Ls, NB, bs, KH, hd)
-    pool with the layer and block axes merged: (Ls * NB, bs, KH, hd), so
-    attention layer l's pool block t is block ``l * NB + t``; conv the
-    short convolutions' per-slot state (or None where the model has none);
-    tables (B, nb) int32 — slot block tables (out-of-range = unadmitted);
-    tokens, seq_lens, active — as in `decode_step_paged`.
-
-    The pool stays in the layer loop's carry (never scan ``xs``/``ys``), so
-    each layer writes one row per slot and the block-table kernel reads
-    the slot's blocks where they lie: nothing but the fresh rows moves.
-    Rows land strictly past the prompt, in the slot's private blocks;
-    unadmitted and done slots write nothing, and their conv state stays.
-    Returns (logits (B, V), kv, seq_lens + active, load): ``load`` is, per
-    MoE layer of a scheduled model, `moe.routed_load` of the active slots
-    (None for other models).
-    """
-    from repro.kernels import ops as OPS
-
-    a = cfg.attention
-    kp, vp, conv = kv
-    Ls = num_attention_layers(cfg)
-    NB, bs = kp.shape[0] // Ls, kp.shape[1]
-    W = tables.shape[1] * bs
-    seq_lens = seq_lens.astype(jnp.int32)
-    x = embed_tokens(cfg, p, tokens[:, None])            # (B, 1, D)
-    q_pos = hint(seq_lens[:, None], "batch", None)
-    impl = _decode_attn_impl(ctx)
-
-    # the fresh row: overflow clamps into the slot's (private) last block.
-    # Unadmitted slots (table entry >= NB) and done slots aim past every
-    # layer's blocks, where the scatter drops them — an entry of NB plus a
-    # layer offset would land in the next layer's blocks
-    pos = jnp.minimum(seq_lens, W - 1)
-    phys = jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1)[:, 0]
-    writes = active & (phys < NB)
-    row = pos % bs
-    lens_now = jnp.minimum(seq_lens + 1, W)
-    tclip = jnp.clip(tables, 0, NB - 1)
-
-    def attn(lp, h, kp, vp, win, l):
-        q, k, v = L.attention_qkv(lp["attn"], h, a, q_pos,
-                                  norm_eps=cfg.norm_eps)
-        blk = jnp.where(writes, l * NB + phys, Ls * NB)
-        kp = kp.at[blk, row].set(k[:, 0].astype(kp.dtype), mode="drop")
-        vp = vp.at[blk, row].set(v[:, 0].astype(vp.dtype), mode="drop")
-        o = OPS.paged_decode_attention_bt(
-            q[:, 0], kp, vp, lens_now, tclip + l * NB, window=win,
-            softcap=a.logit_softcap, scale=a.attn_scale, impl=impl)
-        return L.attention_out(lp["attn"], o[:, None]), kp, vp
-
-    if cfg.mixers:
-        x, kp, vp, conv, load = _decode_scheduled(cfg, p, x, kp, vp, conv,
-                                                  active, attn)
-        x = _normed(cfg, p["final_norm"], x)
-    else:
-        x, kp, vp = _decode_uniform(cfg, p, x, kp, vp, active, attn, ctx)
-        x = L.apply_norm(cfg, p["final_norm"], x)
-        load = None
-    logits = unembed(cfg, p, x)
-    return (logits[:, 0], (kp, vp, conv),
-            seq_lens + active.astype(jnp.int32), load)
-
-
-def _decode_uniform(cfg: ModelConfig, p, x, kp, vp, active, attn, ctx):
-    """The pooled decode layers of a uniform (dense) stack: one scan."""
-    Ls = cfg.num_layers
-
-    def layer(carry, lp, l, win):
-        x, kp, vp = carry
-
-        def attn_l(lp, h, kp, vp, win):
-            return attn(lp, h, kp, vp, win, l)
-
-        x, (kp, vp, _, _) = _decode_layer(
-            cfg, lp, win, x, kp, vp, None, None, ctx, attn_fn=attn_l,
-            bspec=None, active=active)
-        return x, kp, vp
-
-    # layer groups with a STATIC window each where the stack tiles (the
-    # kernel needs one; a traced window falls back to XLA), as in
-    # `decode_step_paged`
-    static = can_qchunk(cfg)
-    g = attn_group_size(cfg)
-
-    def group(t):
-        return t.reshape((t.shape[0] // g, g) + t.shape[1:])
-
-    def gbody(carry, xs):
-        lp_g, i, win_g = xs
-        for j in range(g):
-            win = static_window_for(cfg, j, g) if static else win_g[j]
-            carry = layer(carry, jax.tree.map(lambda t: t[j], lp_g),
-                          i * g + j, win)
-        return carry, None
-
-    (x, kp, vp), _ = jax.lax.scan(
-        gbody, (x, kp, vp),
-        (jax.tree.map(group, p["layers"]), jnp.arange(Ls // g),
-         group(jnp.asarray(window_schedule(cfg)))))
-    return x, kp, vp
-
-
-def _decode_scheduled(cfg: ModelConfig, p, x, kp, vp, conv, active, attn):
-    """The pooled decode layers of a per-layer mixer schedule, run by run:
-    attention layers write and read the pool, conv layers advance the
-    active slots' conv state.  Returns (x, the float32 residual stream;
-    kp, vp, conv, load (n_moe, 2))."""
-    step = active.astype(jnp.int32)
-    x = x.astype(jnp.float32)
-    loads = []
-    for seg, lp_seg in zip(segments(cfg), p["layers"]):
-        def body(carry, xs, seg=seg):
-            x, kp, vp, conv = carry
-            lp, i = xs
-            h = _normed(cfg, lp["ln1"], x)
-            if seg.mixer == "attention":
-                h, kp, vp = attn(lp, h, kp, vp, None, seg.slot + i)
-            else:
-                c = seg.slot + i
-                with jax.named_scope("short_conv"):
-                    h, st = SSM.short_conv(cfg, lp["conv"], h, conv[c], step)
-                conv = conv.at[c].set(st)
-            x = x + h
-            h, routed = _segment_ffn(cfg, seg, lp,
-                                     _normed(cfg, lp["ln2"], x))
-            load = None if routed is None else MOE.routed_load(routed,
-                                                               active)
-            return (x + h, kp, vp, conv), load
-
-        (x, kp, vp, conv), load = jax.lax.scan(
-            body, (x, kp, vp, conv), (lp_seg, jnp.arange(seg.n)))
-        if load is not None:
-            loads.append(load)
-    load = (jnp.concatenate(loads) if loads
-            else jnp.zeros((0, 2), jnp.int32))
-    return x, kp, vp, conv, load
+def sample_tokens(logits, key, salt, pos, *, greedy: bool,
+                  temperature: float = 1.0):
+    """The serving token choice for logits (B, V): argmax, or a draw at
+    ``temperature`` keyed by folding each row's request ``salt`` (B,), then
+    its position ``pos`` (B,), into ``key``: chunking and slot drop out."""
+    if greedy:
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    lg = logits / jnp.asarray(max(temperature, 1e-6), logits.dtype)
+    keys = jax.vmap(lambda b, s: jax.random.fold_in(
+        jax.random.fold_in(key, b), s))(salt, pos)
+    return jax.vmap(jax.random.categorical)(keys, lg).astype(jnp.int32)
 
 
 def decode_n(cfg: ModelConfig, p, cache: Cache, tokens, seq_lens, budget,
@@ -1087,27 +968,22 @@ def decode_n(cfg: ModelConfig, p, cache: Cache, tokens, seq_lens, budget,
     per-token path runs, so greedy outputs are bitwise identical for any
     ``num_steps`` split of the same (tokens, seq_lens, budget) trajectory.
     (Across a serving session, MoE capacity coupling can still observe
-    admission timing — see serve/engine.py.)  Sampling keys are folded per
-    (salt, position) —
-    ``salt`` (B,) int32 is a per-request value (the engine passes the
-    request id; default: the slot index), constant for a request's lifetime
-    — so sampled outputs are chunk-invariant AND decorrelated across slots
-    and across requests reusing a slot.
+    admission timing — see serve/engine.py.)  Sampling (`sample_tokens`)
+    keys each draw by ``salt`` (B,) int32, a per-request value (the engine
+    passes the request id; default: the slot index), and the position, so
+    sampled outputs are chunk-invariant AND decorrelated across slots and
+    across requests reusing a slot.
 
     Returns (toks (num_steps, B) int32, cache, seq_lens, last_tokens), and
-    with ``moe_load`` a fifth, ``load``: for a scheduled model with MoE
-    layers served pooled, (num_steps, moe_layers, 2) int32 holding per step
-    and MoE layer the token-expert pairs of the step's live slots routed to
-    held experts and the held experts they touched (`moe.routed_load`);
-    None otherwise.  It comes back with the tokens, so reading it costs no
-    extra sync.
+    with ``moe_load`` a fifth, ``load``: for a pooled model with routed
+    experts, (num_steps, MoE layers, 2) int32, per step the live slots'
+    `moe.routed_load`; else None.  It comes back with the tokens, so
+    reading it costs no extra sync.
 
     With ``tables`` (pooled cache from `init_kv_pool`), the scan runs
-    `decode_step_pooled` and carries the pool itself: each step writes
-    only the fresh row per layer and slot, and the block-table kernel
-    reads the slots' blocks in the pool, so no per-slot view is ever
-    materialised and the pool (donated by the serve engine) is updated
-    in place.  The kernel's body is the per-slot path's, so where its
+    `decode_step_pooled` and carries the pool itself, so no per-slot view
+    is ever materialised and the pool (donated by the serve engine) is
+    updated in place.  The kernel's body is the per-slot path's, so where its
     block size (``ctx.decode_kv_block``) is the pool's, pooled and
     per-slot decode give the same tokens.
     """
@@ -1133,19 +1009,13 @@ def decode_n(cfg: ModelConfig, p, cache: Cache, tokens, seq_lens, budget,
             return decode_step_pooled(cfg, p, kv, toks, lens, active, tables,
                                       ctx)
 
-    def select(logits, lens):
-        if greedy:
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        lg = logits / jnp.asarray(max(temperature, 1e-6), logits.dtype)
-        keys = jax.vmap(lambda b, s: jax.random.fold_in(
-            jax.random.fold_in(key, b), s))(salt, lens)
-        return jax.vmap(jax.random.categorical)(keys, lg).astype(jnp.int32)
-
     def step(carry, _):
         kv, toks, lens, produced = carry
         active = produced < budget
         logits, kv, lens, load = advance(kv, toks, lens, active)
-        nxt = jnp.where(active, select(logits, lens), toks)
+        nxt = jnp.where(active, sample_tokens(
+            logits, key, salt, lens, greedy=greedy,
+            temperature=temperature), toks)
         return (kv, nxt, lens, produced + active.astype(jnp.int32)), (nxt,
                                                                       load)
 
@@ -1213,6 +1083,72 @@ def init_kv_pool(cfg: ModelConfig, num_blocks: int, block_size: int,
                  pos=jnp.zeros((), jnp.int32))
 
 
+def _pool_runs(cfg: ModelConfig, p):
+    """``(Segment, stacked weights)`` runs of a model with the pooled
+    layout: a schedule's `segments`, or a uniform stack as one run."""
+    assert has_pooled_layout(cfg), \
+        f"{cfg.name}: no pooled KV layout for the {cfg.family} family"
+    if cfg.mixers:
+        return tuple(zip(segments(cfg), p["layers"]))
+    return ((Segment("attention", "mlp", 0, cfg.num_layers, 0),
+             p["layers"]),)
+
+
+def _pooled_layers(cfg: ModelConfig, p, x, state, mixer, *, active=None,
+                   group: bool = False):
+    """Every layer of a model with the pooled layout, one scan per run
+    (`_pool_runs`).  ``state`` (the pool, layer and block axes merged, and
+    the conv state) rides the scan's carry, never its xs/ys, so each layer
+    updates its own rows in place.  ``mixer(seg, lp, h, state, l, window)
+    -> (h, state)`` is the caller's token mixer; ``l`` indexes the layer
+    among those of its mixer (its pool layer, or its conv state row).
+    ``group`` scans a run whose windows tile by `attn_group_size` in groups
+    of that many layers, each place with a STATIC window (None = global),
+    as the decode kernel needs; else layer by layer (a prefill keeps the
+    per-slot `prefill`'s program and bits), static where the run has one
+    window.  Returns (x, state, load: per MoE layer the `moe.routed_load`
+    of the ``active`` (B,) slots, (n, 2) int32; None without either)."""
+    x = x.astype(_stream_dtype(cfg))
+    loads = []
+    for seg, lp_run in _pool_runs(cfg, p):
+        w = window_schedule(cfg)[seg.start:seg.start + seg.n]
+        g = attn_group_size(cfg) if group else 1
+        if seg.n % g or (w.reshape(-1, g) != w[:g]).any():
+            g = 1
+        static = ([None if v == GLOBAL_WINDOW else int(v) for v in w[:g]]
+                  if (w.reshape(-1, g) == w[:g]).all() else None)
+
+        xs = (lp_run, jnp.arange(seg.n), jnp.asarray(w))
+        if g > 1:       # a stack of weights reshaped can cost a copy of it
+            xs = jax.tree.map(
+                lambda t: t.reshape((seg.n // g, g) + t.shape[1:]), xs)
+
+        def body(carry, xs, seg=seg, g=g, static=static):
+            x, state = carry
+            load = []
+            for j in range(g):
+                lp, l, win = jax.tree.map(lambda t: t[j], xs) if g > 1 else xs
+                win = win if static is None else static[j]
+                h, state = mixer(seg, lp, _normed(cfg, lp["ln1"], x), state,
+                                 seg.slot + l, win)
+                if cfg.post_norm:
+                    h = L.apply_norm(cfg, lp["post_ln1"], h)
+                x = x + h
+                h, routed = _segment_ffn(cfg, seg, lp,
+                                         _normed(cfg, lp["ln2"], x))
+                if cfg.post_norm:
+                    h = L.apply_norm(cfg, lp["post_ln2"], h)
+                x = x + h
+                if routed is not None and active is not None:
+                    load.append(MOE.routed_load(routed, active))
+            return (x, state), (jnp.stack(load) if load else None)
+
+        (x, state), load = jax.lax.scan(body, (x, state), xs)
+        if load is not None:
+            loads.append(load.reshape((-1,) + load.shape[2:]))
+    return x, state, (jnp.concatenate(loads) if loads else None)
+
+
 def prefill_suffix(cfg: ModelConfig, p, cache: Cache, tokens, start, valid,
                    tables, ctx: ParallelContext = LOCAL, *, slots=None
                    ) -> Tuple[jax.Array, Cache]:
@@ -1230,96 +1166,17 @@ def prefill_suffix(cfg: ModelConfig, p, cache: Cache, tokens, start, valid,
     slot b).  Only per-slot state beside the pool (a schedule's conv
     state) is indexed by it; an out-of-range slot's state writes drop.
 
-    Each layer scatters the fresh suffix KV into its pool rows FIRST, then
-    gathers the slot's full logical view (prefix blocks written by earlier
-    dispatches + this chunk) and runs blocked attention with logical
-    positions — masked lanes (unwritten tail, idle rows) use the kv_pos=-1
-    sentinel and contribute exact zeros.  A long suffix prefills in
-    ``ceil(len/T)`` chained dispatches of this ONE program.
-
-    A scheduled model's conv layers continue each row from its slot's conv
-    state (zero where the row starts a prompt) and leave the state after
-    the row's last valid token; rows with nothing valid keep theirs.
-
-    Returns (logits (B, V) at each row's last valid suffix position,
-    updated pooled cache).
+    Each attention layer scatters the fresh suffix KV into its pool rows
+    FIRST, then gathers the slot's full logical view (prefix blocks written
+    by earlier dispatches + this chunk) and runs blocked attention with
+    logical positions — masked lanes (unwritten tail, idle rows) use the
+    kv_pos=-1 sentinel and contribute exact zeros.  A long suffix prefills
+    in ``ceil(len/T)`` chained dispatches of this ONE program.  A conv
+    layer continues each row from its slot's conv state (zero where the
+    row starts a prompt) and leaves the state after the row's last valid
+    token; rows with nothing valid keep theirs.  Returns (logits (B, V)
+    at each row's last valid suffix position, updated pooled cache).
     """
-    if cfg.mixers:
-        return _prefill_suffix_scheduled(cfg, p, cache, tokens, start, valid,
-                                         tables, slots)
-    a = cfg.attention
-    assert cfg.family == "dense" and not p.get("dense_prefix"), \
-        "prefill_suffix supports dense attention families"
-    B, T = tokens.shape
-    _, NB, bs, KH, hd = cache.k.shape
-    nb = tables.shape[1]
-    W = nb * bs
-    tables = tables.astype(jnp.int32)
-    start = start.astype(jnp.int32)
-    valid = valid.astype(jnp.int32)
-
-    x = embed_tokens(cfg, p, tokens)
-    positions = hint(start[:, None] + jnp.arange(T, dtype=jnp.int32)[None],
-                     "batch", None)
-    # logical lane positions of the slot's KV view; lanes at/after the
-    # suffix end are unwritten — the -1 sentinel masks them exactly
-    lane = jnp.arange(W, dtype=jnp.int32)[None, :]
-    kv_pos = jnp.where(lane < (start + valid)[:, None], lane, -1)
-    # gather map: logical lane -> flat pool row (OOB tables clamp; their
-    # lanes are always masked)
-    gidx = ((jnp.clip(tables, 0, NB - 1) * bs)[:, :, None]
-            + jnp.arange(bs, dtype=jnp.int32)[None, None]).reshape(B, W)
-    # scatter map: suffix token t -> flat pool row; padding lanes and idle
-    # rows go out of bounds, which the scatter drops
-    blk = positions // bs
-    phys = jnp.take_along_axis(tables, jnp.clip(blk, 0, nb - 1), axis=1)
-    dest = jnp.where(
-        (jnp.arange(T, dtype=jnp.int32)[None] < valid[:, None]) & (blk < nb),
-        phys * bs + positions % bs, NB * bs).reshape(-1)
-
-    windows = jnp.asarray(window_schedule(cfg))
-
-    def body(x, xs):
-        lp, win, kp, vp = xs
-        h = L.apply_norm(cfg, lp["ln1"], x)
-        q, k, v = L.attention_qkv(lp["attn"], h, a, positions)
-        kf = kp.reshape(NB * bs, KH, hd).at[dest].set(
-            k.reshape(-1, KH, hd).astype(kp.dtype))
-        vf = vp.reshape(NB * bs, KH, hd).at[dest].set(
-            v.reshape(-1, KH, hd).astype(vp.dtype))
-        kfull = jnp.take(kf, gidx.reshape(-1), axis=0).reshape(B, W, KH, hd)
-        vfull = jnp.take(vf, gidx.reshape(-1), axis=0).reshape(B, W, KH, hd)
-        o = L.blocked_attention(q, kfull, vfull, positions, kv_pos,
-                                window=win, softcap=a.logit_softcap,
-                                scale=a.attn_scale, kv_chunk=max(W, 1024))
-        h = L.attention_out(lp["attn"], o)
-        if cfg.post_norm:
-            h = L.apply_norm(cfg, lp["post_ln1"], h)
-        x = x + h
-        h = L.apply_norm(cfg, lp["ln2"], x)
-        h = L.mlp_apply(cfg, lp["mlp"], h)
-        if cfg.post_norm:
-            h = L.apply_norm(cfg, lp["post_ln2"], h)
-        x = x + h
-        return x, (kf.reshape(NB, bs, KH, hd), vf.reshape(NB, bs, KH, hd))
-
-    x, (ks, vs) = jax.lax.scan(body, x, (p["layers"], windows,
-                                         cache.k, cache.v))
-    new_cache = Cache(k=ks, v=vs, pos=cache.pos)
-    x = L.apply_norm(cfg, p["final_norm"], x)
-    li = jnp.clip(valid - 1, 0, T - 1)
-    xlast = jnp.take_along_axis(x, li[:, None, None], axis=1)   # (B, 1, D)
-    logits = unembed(cfg, p, xlast)
-    return logits[:, 0], new_cache
-
-
-def _prefill_suffix_scheduled(cfg: ModelConfig, p, cache: Cache, tokens,
-                              start, valid, tables, slots=None
-                              ) -> Tuple[jax.Array, Cache]:
-    """`prefill_suffix` of a per-layer mixer schedule.  The pool (layer and
-    block axes merged) and the conv state ride the layer loop's carry, so
-    each attention layer scatters its fresh rows and gathers its logical
-    view in place; each conv layer reads and writes its rows' slots."""
     a = cfg.attention
     B, T = tokens.shape
     Ls, NB, bs, KH, hd = cache.k.shape
@@ -1329,30 +1186,34 @@ def _prefill_suffix_scheduled(cfg: ModelConfig, p, cache: Cache, tokens,
     tables = tables.astype(jnp.int32)
     start = start.astype(jnp.int32)
     valid = valid.astype(jnp.int32)
-    if slots is not None:
-        slots = jnp.asarray(slots, jnp.int32)
+    slots = (jnp.arange(B, dtype=jnp.int32) if slots is None
+             else jnp.asarray(slots, jnp.int32))
 
-    x = embed_tokens(cfg, p, tokens).astype(jnp.float32)
-    positions = start[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
+    x = embed_tokens(cfg, p, tokens)
+    positions = hint(start[:, None] + jnp.arange(T, dtype=jnp.int32)[None],
+                     "batch", None)
+    # logical lane positions of the slot's KV view; lanes at/after the
+    # suffix end are unwritten — the -1 sentinel masks them exactly
     lane = jnp.arange(W, dtype=jnp.int32)[None, :]
     kv_pos = jnp.where(lane < (start + valid)[:, None], lane, -1)
+    # gather map: logical lane -> pool row of layer 0 (OOB tables clamp;
+    # their lanes are always masked)
     gidx = ((jnp.clip(tables, 0, NB - 1) * bs)[:, :, None]
             + jnp.arange(bs, dtype=jnp.int32)[None, None]).reshape(-1)
+    # scatter map: suffix token -> pool row of layer 0; what is not kept
+    # (padding, idle rows, unadmitted blocks) aims past every layer: dropped
     blk = positions // bs
     phys = jnp.take_along_axis(tables, jnp.clip(blk, 0, nb - 1), axis=1)
-    keep = (jnp.arange(T, dtype=jnp.int32)[None] < valid[:, None]) & (
-        blk < nb)
+    keep = ((jnp.arange(T, dtype=jnp.int32)[None] < valid[:, None])
+            & (blk < nb) & (phys < NB)).reshape(-1)
     dest = (phys * bs + positions % bs).reshape(-1)
-    keep = keep.reshape(-1)
     # a row that starts its prompt here starts from a zero conv state
     fresh = ((start == 0) & (valid > 0))[:, None, None]
 
-    def body(carry, xs, seg):
-        x, kf, vf, conv = carry
-        lp, i = xs
-        h = _normed(cfg, lp["ln1"], x)
+    def mix(seg, lp, h, state, l, win):
+        kf, vf, conv = state
         if seg.mixer == "attention":
-            off = (seg.slot + i) * rows
+            off = l * rows
             q, k, v = L.attention_qkv(lp["attn"], h, a, positions,
                                       norm_eps=cfg.norm_eps)
             d = jnp.where(keep, off + dest, Ls * rows)
@@ -1363,32 +1224,91 @@ def _prefill_suffix_scheduled(cfg: ModelConfig, p, cache: Cache, tokens,
             kfull = jnp.take(kf, off + gidx, axis=0).reshape(B, W, KH, hd)
             vfull = jnp.take(vf, off + gidx, axis=0).reshape(B, W, KH, hd)
             o = L.blocked_attention(q, kfull, vfull, positions, kv_pos,
-                                    kv_chunk=max(W, 1024))
-            h = L.attention_out(lp["attn"], o)
-        else:
-            c = seg.slot + i
-            held = (conv[c] if slots is None
-                    else jnp.take(conv[c], slots, axis=0, mode="clip"))
-            st = jnp.where(fresh, jnp.zeros((), conv.dtype), held)
-            with jax.named_scope("short_conv"):
-                h, st = SSM.short_conv(cfg, lp["conv"], h, st, valid)
-            conv = (conv.at[c].set(st) if slots is None
-                    else conv.at[c, slots].set(st, mode="drop"))
-        x = x + h
-        h, _ = _segment_ffn(cfg, seg, lp, _normed(cfg, lp["ln2"], x))
-        return (x + h, kf, vf, conv), None
+                                    window=win, softcap=a.logit_softcap,
+                                    scale=a.attn_scale, kv_chunk=max(W, 1024))
+            return L.attention_out(lp["attn"], o), (kf, vf, conv)
+        held = jnp.take(conv[l], slots, axis=0, mode="clip")
+        st = jnp.where(fresh, jnp.zeros((), conv.dtype), held)
+        with jax.named_scope("short_conv"):
+            h, st = SSM.short_conv(cfg, lp["conv"], h, st, valid)
+        conv = conv.at[l, slots].set(st, mode="drop")
+        return h, (kf, vf, conv)
 
-    carry = (x, cache.k.reshape(Ls * rows, KH, hd),
-             cache.v.reshape(Ls * rows, KH, hd), cache.conv)
-    for seg, lp_seg in zip(segments(cfg), p["layers"]):
-        carry, _ = jax.lax.scan(
-            lambda c_, xs, seg=seg: body(c_, xs, seg), carry,
-            (lp_seg, jnp.arange(seg.n)))
-    x, kf, vf, conv = carry
-    new_cache = Cache(k=kf.reshape(cache.k.shape), v=vf.reshape(cache.v.shape),
-                      conv=conv, pos=cache.pos)
+    x, (kf, vf, conv), _ = _pooled_layers(
+        cfg, p, x, (cache.k.reshape(Ls * rows, KH, hd),
+                    cache.v.reshape(Ls * rows, KH, hd), cache.conv), mix)
     x = _normed(cfg, p["final_norm"], x)
     li = jnp.clip(valid - 1, 0, T - 1)
     xlast = jnp.take_along_axis(x, li[:, None, None], axis=1)   # (B, 1, D)
     logits = unembed(cfg, p, xlast)
-    return logits[:, 0], new_cache
+    return logits[:, 0], Cache(k=kf.reshape(cache.k.shape),
+                               v=vf.reshape(cache.v.shape), conv=conv,
+                               pos=cache.pos)
+
+
+def decode_step_pooled(cfg: ModelConfig, p, kv, tokens, seq_lens, active,
+                       tables, ctx: ParallelContext = LOCAL):
+    """One decode step over a pooled KV cache, read and written in place.
+
+    kv — (k, v, conv): k and v each ``init_kv_pool``'s (Ls, NB, bs, KH, hd)
+    pool with the layer and block axes merged: (Ls * NB, bs, KH, hd), so
+    attention layer l's pool block t is block ``l * NB + t``; conv the
+    short convolutions' per-slot state (or None where the model has none);
+    tables (B, nb) int32 — slot block tables (out-of-range = unadmitted);
+    tokens, seq_lens, active — as in `decode_step_paged`.
+
+    Each layer writes one row per slot in place (`_pooled_layers`) and the
+    block-table kernel reads the slot's blocks where they lie.  Rows land
+    strictly past the prompt, in the slot's private blocks; unadmitted and
+    done slots write nothing, and their conv state stays.  Returns (logits
+    (B, V), kv, seq_lens + active, load: per MoE layer `moe.routed_load`
+    of the active slots, or None without routed experts).
+    """
+    from repro.kernels import ops as OPS
+
+    a = cfg.attention
+    kp, vp, conv = kv
+    Ls = num_attention_layers(cfg)
+    NB, bs = kp.shape[0] // Ls, kp.shape[1]
+    W = tables.shape[1] * bs
+    seq_lens = seq_lens.astype(jnp.int32)
+    step = active.astype(jnp.int32)
+    x = embed_tokens(cfg, p, tokens[:, None])            # (B, 1, D)
+    q_pos = hint(seq_lens[:, None], "batch", None)
+    impl = _decode_attn_impl(ctx)
+
+    # the fresh row: overflow clamps into the slot's (private) last block.
+    # Unadmitted slots (table entry >= NB) and done slots aim past every
+    # layer's blocks, where the scatter drops them — an entry of NB plus a
+    # layer offset would land in the next layer's blocks
+    pos = jnp.minimum(seq_lens, W - 1)
+    phys = jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1)[:, 0]
+    writes = active & (phys < NB)
+    row = pos % bs
+    lens_now = jnp.minimum(seq_lens + 1, W)
+    tclip = jnp.clip(tables, 0, NB - 1)
+
+    def attn(lp, h, kp, vp, win, l):
+        q, k, v = L.attention_qkv(lp["attn"], h, a, q_pos,
+                                  norm_eps=cfg.norm_eps)
+        blk = jnp.where(writes, l * NB + phys, Ls * NB)
+        kp = kp.at[blk, row].set(k[:, 0].astype(kp.dtype), mode="drop")
+        vp = vp.at[blk, row].set(v[:, 0].astype(vp.dtype), mode="drop")
+        o = OPS.paged_decode_attention_bt(
+            q[:, 0], kp, vp, lens_now, tclip + l * NB, window=win,
+            softcap=a.logit_softcap, scale=a.attn_scale, impl=impl)
+        return L.attention_out(lp["attn"], o[:, None]), kp, vp
+
+    def mix(seg, lp, h, state, l, win):
+        kp, vp, conv = state
+        if seg.mixer == "attention":
+            h, kp, vp = attn(lp, h, kp, vp, win, l)
+            return h, (kp, vp, conv)
+        with jax.named_scope("short_conv"):
+            h, st = SSM.short_conv(cfg, lp["conv"], h, conv[l], step)
+        return h, (kp, vp, conv.at[l].set(st))
+
+    x, kv, load = _pooled_layers(cfg, p, x, (kp, vp, conv), mix,
+                                 active=active, group=True)
+    logits = unembed(cfg, p, _normed(cfg, p["final_norm"], x))
+    return logits[:, 0], kv, seq_lens + step, load

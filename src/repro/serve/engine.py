@@ -60,6 +60,7 @@ from repro.configs.base import ModelConfig
 from repro.core.costmodel import TPU_V4
 from repro.models import api
 from repro.models import quant as QUANT
+from repro.models import transformer as TF
 from repro.obs import Telemetry
 from repro.parallel.context import LOCAL, ParallelContext, activate
 from repro.serve.kvpool import KVPool
@@ -168,16 +169,12 @@ def _fast_programs(cfg: ModelConfig, spec: SliceSpec, ctx: ParallelContext):
         # cached rows include the vision prefix for VLMs — the
         # text-token count alone would mask out valid prompt KV
         prefilled = batch["tokens"].shape[1] + (cfg.vision_prefix or 0)
-        if spec.greedy:
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        else:
-            # first token follows the same (salt, position) key scheme as
-            # decode_n; decode positions start at prefilled+1, so the
-            # streams never collide
-            keys = jax.vmap(lambda b: jax.random.fold_in(
-                jax.random.fold_in(sample_key, b), prefilled))(rids)
-            nxt = jax.vmap(jax.random.categorical)(
-                keys, logits).astype(jnp.int32)
+        # the first token follows decode_n's (salt, position) key scheme;
+        # decode positions start at prefilled+1, so the streams never
+        # collide
+        nxt = TF.sample_tokens(logits, sample_key, rids,
+                               jnp.full_like(rids, prefilled),
+                               greedy=spec.greedy)
         seq_lens = seq_lens.at[slots_].set(prefilled)
         last = last.at[slots_].set(nxt)
         salt = salt.at[slots_].set(rids)
@@ -215,16 +212,11 @@ def _pooled_programs(cfg: ModelConfig, spec: SliceSpec, ctx: ParallelContext):
             logits, cache = api.prefill_suffix(
                 cfg, params, cache, tokens, start, valid, tables, ctx,
                 slots=rows)
-        if spec.greedy:
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        else:
-            # same (salt, position) scheme as the dense fast path, but the
-            # fold position is the TRUE prompt length (pooled rows are
-            # left-aligned, not padded to prompt_len)
-            keys = jax.vmap(lambda b, n: jax.random.fold_in(
-                jax.random.fold_in(sample_key, b), n))(rids, plens)
-            nxt = jax.vmap(jax.random.categorical)(
-                keys, logits).astype(jnp.int32)
+        # the dense fast path's key scheme, but the fold position is the
+        # TRUE prompt length (pooled rows are left-aligned, not padded to
+        # prompt_len)
+        nxt = TF.sample_tokens(logits, sample_key, rids, plens,
+                               greedy=spec.greedy)
         at = jnp.where(commit, rows, spec.slots)
         seq_lens = seq_lens.at[at].set(plens, mode="drop")
         last = last.at[at].set(nxt, mode="drop")

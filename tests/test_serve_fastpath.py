@@ -341,6 +341,38 @@ class TestPooledPrefixKV:
         share_eng.kv_close()             # zero blocks leaked
         noshare_eng.kv_close()
 
+    # gemma2-9b reduced (sliding window, global_every, softcaps, post_norm)
+    # with post-norm gains of 8, so that the layers, not the scaled tied
+    # embedding, choose the tokens.  Its pooled and per-slot prefills sum
+    # attention in different orders, and the tokens then part; the pooled
+    # tokens are pinned instead
+    GAIN8_POOLED = [[145, 145, 145, 194, 440], [440, 440, 440, 440, 440],
+                    [212, 58, 58, 58, 58], [145, 145, 145, 145, 145],
+                    [393, 393, 393, 393, 393], [359, 359, 359, 359, 359]]
+
+    @pytest.mark.parametrize("gain", [1.0, 8.0], ids=["drawn", "gain8"])
+    def test_pooled_tokens_on_a_local_global_stack(self, gain):
+        """The decode loop scans gemma2's local/global layers in pairs, a
+        static window each; sharing stays bitwise-invisible, and as drawn
+        the pooled tokens are the per-slot path's."""
+        cfg = registry.get_reduced("gemma2-9b")
+        params = api.init_params(cfg, jax.random.PRNGKey(0))
+        for k in ("post_ln1", "post_ln2"):
+            params["layers"][k]["w"] = params["layers"][k]["w"] + gain - 1
+        _, prompts = self._prompts(cfg)
+        base = dict(slots=3, max_len=64, prompt_len=40, chunk=4)
+        share_eng, share = self._run(
+            cfg, params, SliceSpec(**base, kv_block=8, suffix_len=8),
+            prompts)
+        noshare_eng, noshare = self._run(
+            cfg, params, SliceSpec(**base, kv_block=8, suffix_len=8,
+                                   kv_share=False), prompts)
+        want = (self._run(cfg, params, SliceSpec(**base), prompts)[1]
+                if gain == 1 else self.GAIN8_POOLED)
+        assert share == noshare == want
+        share_eng.kv_close()
+        noshare_eng.kv_close()
+
     def test_prefix_lookup_scores_published_header(self, small_model):
         cfg, params = small_model
         header, prompts = self._prompts(cfg)
@@ -493,6 +525,64 @@ class TestPooledDecodeInPlace:
             lambda pool, t, n, b, tb: api.decode_n(
                 cfg, params, pool, t, n, b, ctx, num_steps=self.NUM_STEPS,
                 tables=tb))
+
+
+class TestPoolRidesTheCarry:
+    """The pooled prefill and decode keep the pool in their layer scans'
+    carry: no scan takes the pool, or one layer's share of it, as ``xs`` or
+    hands it back as ``ys``, so no scan slices it in or stacks a copy of it
+    out.  (A run that leaves the pool alone, such as lfm2's conv layers,
+    may hold it as a loop-invariant const, which moves nothing.)"""
+
+    B, T, BS, NB, NBT = 2, 8, 4, 13, 4      # NB = 13: no weight matches
+
+    def _model(self, name):
+        cfg = registry.get_reduced(name)
+        if cfg.moe is not None:
+            cfg = cfg.replace(moe=dataclasses.replace(cfg.moe,
+                                                      held_experts=4))
+        return cfg, api.init_params(cfg, jax.random.PRNGKey(0))
+
+    @pytest.mark.parametrize("name", ["olmo-1b", "lfm2-8b-a1b"])
+    @pytest.mark.parametrize("path", ["prefill", "decode"])
+    def test_no_scan_moves_the_pool(self, name, path):
+        import jax.numpy as jnp
+
+        cfg, params = self._model(name)
+        B = self.B
+        pool = api.init_kv_pool(cfg, self.NB, self.BS, slots=B)
+        tables = jnp.arange(B * self.NBT, dtype=jnp.int32).reshape(B, -1)
+        if path == "prefill":
+            def fn(c, tb):
+                return api.prefill_suffix(
+                    cfg, params, c, jnp.ones((B, self.T), jnp.int32),
+                    jnp.zeros((B,), jnp.int32), jnp.full((B,), 5), tb)
+        else:
+            def fn(c, tb):
+                return api.decode_n(
+                    cfg, params, c, jnp.ones((B,), jnp.int32),
+                    jnp.full((B,), 5), jnp.full((B,), 3), num_steps=3,
+                    tables=tb)
+        layer = int(np.prod(pool.k.shape[1:]))
+        sizes = {layer, pool.k.shape[0] * layer}
+        jaxpr = jax.make_jaxpr(fn)(pool, tables)
+        moved = set(_scan_io_sizes(jaxpr.jaxpr))
+        assert moved and not sizes & moved
+
+
+def _scan_io_sizes(jaxpr):
+    """Element counts of every scan's inputs past its consts and carry
+    (xs) and of its outputs past the carry (ys), nested scans included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            nc, nk = eqn.params["num_consts"], eqn.params["num_carry"]
+            for v in eqn.invars[nc + nk:] + eqn.outvars[nk:]:
+                yield int(np.prod(v.aval.shape))
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (list, tuple)) else [p]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _scan_io_sizes(inner)
 
 
 def _out_sizes(jaxpr):
