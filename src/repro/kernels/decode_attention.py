@@ -233,6 +233,36 @@ def _decode_kernel_bt_q(sl_ref, bt_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
                      m_ref, l_ref, acc_ref, **kw)
 
 
+def _bt_block(b, j, sl, bt, bs: int):
+    """Pool block that grid step (b, j) names: slot b's logical block j,
+    clamped to its last valid one."""
+    return bt[b, jnp.minimum(j, _last_block(sl, b, bs))]
+
+
+def held_block_tables(seq_lens: jax.Array, tables: jax.Array,
+                      bs: int) -> jax.Array:
+    """``tables`` (B, nb) with every empty slot's row (``seq_lens`` 0)
+    pointed at the block the grid step before it names: the last block of
+    the nearest non-empty slot before it, or, for the empty slots that
+    lead the grid, the first block of the first non-empty one.  An empty
+    slot computes nothing, and with its steps re-naming the block in VMEM
+    the pipeline fetches nothing for it either.
+
+    Layers that share ``seq_lens`` and ``tables`` share the result, so a
+    caller makes it once for all of them (`transformer.decode_step_pooled`,
+    once a step): made inside the wrapper it would cost a dozen small ops
+    per layer.  A constant offset added to every entry keeps it held."""
+    B, nb = tables.shape
+    live = seq_lens > 0
+    last = jnp.take_along_axis(
+        tables, jnp.minimum(jnp.maximum(seq_lens - 1, 0) // bs,
+                            nb - 1)[:, None], axis=1)[:, 0]
+    prev = jax.lax.cummax(jnp.where(live, jnp.arange(B), -1))
+    held = jnp.where(prev >= 0, last[jnp.maximum(prev, 0)],
+                     tables[jnp.argmax(live), 0])
+    return jnp.where(live[:, None], tables, held[:, None])
+
+
 def paged_decode_attention_bt_kernel_call(
         q: jax.Array, k: jax.Array, v: jax.Array, seq_lens: jax.Array,
         tables: jax.Array, *,
@@ -250,7 +280,8 @@ def paged_decode_attention_bt_kernel_call(
     just-written token; lanes past it are masked, so garbage in partially
     written or stale pool blocks never contributes.  The kernel block size
     equals the pool block size ``bs`` (one grid step streams one physical
-    block).
+    block).  A slot of length 0 computes nothing, and fetches nothing
+    where its row of ``tables`` is held (`held_block_tables`).
 
     int8 KV: int8 ``k``/``v`` pools + per-row fp32 ``k_scale``/``v_scale``
     (NB, bs, KH); the indirection tables address scale blocks and value
@@ -268,7 +299,7 @@ def paged_decode_attention_bt_kernel_call(
     tables = jnp.clip(tables.astype(jnp.int32), 0, NB - 1)
 
     def kv_map(b, j, sl, bt):
-        return (bt[b, jnp.minimum(j, _last_block(sl, b, bs))], 0, 0, 0)
+        return (_bt_block(b, j, sl, bt, bs), 0, 0, 0)
 
     def sc_map(b, j, sl, bt):
         return kv_map(b, j, sl, bt)[:3]

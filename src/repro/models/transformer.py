@@ -816,6 +816,15 @@ def _decode_attn_impl(ctx: ParallelContext) -> str:
         getattr(ctx, "decode_attn", "auto")]
 
 
+def attended_lens(seq_lens, active, width: int):
+    """Cache rows a decode step's attention reads per slot: the slot's
+    valid rows and the one just written, capped at the cache's ``width``;
+    none for a slot that is not ``active`` (done, unadmitted or out of
+    budget), whose answer nothing reads, so the kernel reads none of its
+    rows.  Its cache, state and length are left as they are."""
+    return jnp.where(active, jnp.minimum(seq_lens + 1, width), 0)
+
+
 def decode_step_paged(cfg: ModelConfig, p, cache: Cache, tokens, seq_lens,
                       active, ctx: ParallelContext = LOCAL, *, moe_cf=None):
     """One decode step with PER-SLOT cache lengths (continuous batching).
@@ -824,7 +833,8 @@ def decode_step_paged(cfg: ModelConfig, p, cache: Cache, tokens, seq_lens,
     seq_lens (B,) int32 — valid cached tokens per slot (the new token is
     written at this row, then attended);
     active (B,) bool — slots past their budget keep their cache, state, and
-    seq_len frozen (their lane still computes, output is discarded upstream).
+    seq_len frozen, and their attention reads no rows (`attended_lens`);
+    the rest of their lane still computes, output discarded upstream.
 
     Returns (logits (B, V), cache, seq_lens + active).  Attention runs
     through ``ops.paged_decode_attention`` — the Pallas paged kernel on TPU,
@@ -857,9 +867,8 @@ def decode_step_paged(cfg: ModelConfig, p, cache: Cache, tokens, seq_lens,
 
         kc = jax.vmap(wr)(kc, k, idx)
         vc = jax.vmap(wr)(vc, v, idx)
-        lens_now = jnp.minimum(seq_lens + 1, S)
         o = OPS.paged_decode_attention(
-            q[:, 0], kc, vc, lens_now, window=win,
+            q[:, 0], kc, vc, attended_lens(seq_lens, active, S), window=win,
             softcap=a.logit_softcap, scale=a.attn_scale, bk=kv_block,
             impl=impl)
         return L.attention_out(lp["attn"], o[:, None]), kc, vc
@@ -1260,11 +1269,13 @@ def decode_step_pooled(cfg: ModelConfig, p, kv, tokens, seq_lens, active,
     Each layer writes one row per slot in place (`_pooled_layers`) and the
     block-table kernel reads the slot's blocks where they lie.  Rows land
     strictly past the prompt, in the slot's private blocks; unadmitted and
-    done slots write nothing, and their conv state stays.  Returns (logits
+    done slots write nothing, their conv state stays, and attention reads
+    none of their rows (`attended_lens`).  Returns (logits
     (B, V), kv, seq_lens + active, load: per MoE layer `moe.routed_load`
     of the active slots, or None without routed experts).
     """
     from repro.kernels import ops as OPS
+    from repro.kernels.decode_attention import held_block_tables
 
     a = cfg.attention
     kp, vp, conv = kv
@@ -1285,8 +1296,10 @@ def decode_step_pooled(cfg: ModelConfig, p, kv, tokens, seq_lens, active,
     phys = jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1)[:, 0]
     writes = active & (phys < NB)
     row = pos % bs
-    lens_now = jnp.minimum(seq_lens + 1, W)
-    tclip = jnp.clip(tables, 0, NB - 1)
+    lens_now = attended_lens(seq_lens, active, W)
+    # once a step for every layer: slots attention skips re-name a block
+    # the kernel already holds, so it fetches nothing for them
+    tclip = held_block_tables(lens_now, jnp.clip(tables, 0, NB - 1), bs)
 
     def attn(lp, h, kp, vp, win, l):
         q, k, v = L.attention_qkv(lp["attn"], h, a, q_pos,

@@ -338,6 +338,7 @@ class ServeEngine:
         self._c_moe_pairs = reg.counter("serve.moe_pairs", **labels)
         self._c_moe_touched = reg.counter("serve.moe_experts_touched",
                                           **labels)
+        self._c_skipped = reg.counter("serve.decode_slots_skipped", **labels)
         if self._pooled:
             if not api.has_pooled_layout(cfg):
                 raise NotImplementedError(
@@ -574,7 +575,12 @@ class ServeEngine:
         tokens; host-side bookkeeping runs once on the returned chunk."""
         budgets = self._budgets()
         live = int(np.count_nonzero(budgets))
-        with self.obs.span("serve.decode", live=live, steps=num_steps):
+        # slots still holding a finished request's rows, which attention
+        # skips (`transformer.attended_lens`)
+        skipped = sum(1 for r in self.active if r is not None and r.done)
+        self._c_skipped.inc(skipped * num_steps)
+        with self.obs.span("serve.decode", live=live, steps=num_steps,
+                           skipped=skipped):
             t0 = time.perf_counter()
             with self.obs.span("serve.decode.dispatch"):
                 extra = (self.tables,) if self._pooled else ()

@@ -2,6 +2,8 @@
 reference, across seq_lens / GQA / softcap / window — plus the dispatcher
 policy (interpret auto-detect, impl selection) the serve fast path relies
 on."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -232,6 +234,79 @@ class TestBlockTableKernel:
             want = paged_decode_attention_bt_kernel_call(
                 q, k[l], v[l], lens, tables, interpret=True, **kw)
             np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    # slots that are not decoding, as the decode step hands them to the
+    # kernel with length 0: first, last, a run of two, every other one
+    DEAD = {"first": [0], "last": [5], "consecutive": [2, 3],
+            "interleaved": [0, 2, 4]}
+
+    def _dead_inputs(self, dead):
+        key = jax.random.PRNGKey(26)
+        B, H, KH, NB, bs, nb, d = 6, 4, 2, 32, 8, 4, 16
+        q, k, v, tables = self._pooled(key, B, H, KH, NB, bs, nb, d)
+        full = jnp.asarray([5, 32, 17, 9, 24, 1], jnp.int32)
+        zeroed = full.at[jnp.asarray(dead)].set(0)
+        return q, k, v, tables, full, zeroed
+
+    @pytest.mark.parametrize("kw", [dict(), dict(window=6, softcap=20.0)],
+                             ids=["plain", "window_softcap"])
+    @pytest.mark.parametrize("dead", list(DEAD), ids=list(DEAD))
+    def test_dead_slots_leave_live_slots_bitwise(self, dead, kw):
+        """A length-0 slot returns zeros and leaves every other slot's
+        output bitwise as it is when the slot carries its full length,
+        in the kernel and in the XLA reference."""
+        from repro.kernels.decode_attention import (
+            paged_decode_attention_bt_kernel_call)
+        idx = self.DEAD[dead]
+        q, k, v, tables, full, zeroed = self._dead_inputs(idx)
+        live = np.setdiff1d(np.arange(q.shape[0]), idx)
+        for fn in (functools.partial(paged_decode_attention_bt_kernel_call,
+                                     interpret=True),
+                   ref.paged_decode_attention_bt_ref):
+            want = np.asarray(fn(q, k, v, full, tables, **kw))
+            got = np.asarray(fn(q, k, v, zeroed, tables, **kw))
+            np.testing.assert_array_equal(got[live], want[live])
+            np.testing.assert_array_equal(got[idx], 0.0)
+
+    @pytest.mark.parametrize("dead", list(DEAD) + ["all"],
+                             ids=list(DEAD) + ["all"])
+    def test_dead_slots_rename_the_previous_block(self, dead):
+        """`held_block_tables`: the kernel's index map names, at every grid
+        step of a length-0 slot, the block the step before it named (the
+        first live slot's first block where no step comes before), so the
+        pipeline fetches nothing for it; live slots' steps are unchanged."""
+        from repro.kernels.decode_attention import (
+            _bt_block, held_block_tables)
+        B, nb, bs = 6, 4, 8
+        idx = list(range(B)) if dead == "all" else self.DEAD[dead]
+        _, _, _, tables, _, lens = self._dead_inputs(idx)
+
+        def named(bt):
+            return [[int(_bt_block(b, j, lens, bt, bs)) for j in range(nb)]
+                    for b in range(B)]
+
+        got = named(held_block_tables(lens, tables, bs))
+        want = named(tables)
+        steps = [(b, j) for b in range(B) for j in range(nb)]
+        first_live = next((want[b][0] for b in range(B) if b not in idx),
+                          None)
+        for n, (b, j) in enumerate(steps):
+            if b not in idx:
+                assert got[b][j] == want[b][j]
+            elif n:
+                pb, pj = steps[n - 1]
+                assert got[b][j] == got[pb][pj]
+            elif first_live is not None:
+                assert got[b][j] == first_live
+        # a step fetches where it names another block than the step before:
+        # the whole grid fetches what its live slots alone would (one
+        # block at least, for the first step)
+
+        def fetches(seq):
+            return len(seq[:1]) + sum(a != b for a, b in zip(seq, seq[1:]))
+
+        assert fetches([got[b][j] for b, j in steps]) == max(1, fetches(
+            [want[b][j] for b, j in steps if b not in idx]))
 
     def test_ops_bt_dispatcher(self):
         key = jax.random.PRNGKey(24)

@@ -527,6 +527,143 @@ class TestPooledDecodeInPlace:
                 tables=tb))
 
 
+def _full_lens(seq_lens, active, width):
+    """The rule before `transformer.attended_lens`, kept as the oracle:
+    attention reads every slot's rows, decoding or not."""
+    import jax.numpy as jnp
+
+    return jnp.minimum(seq_lens + 1, width)
+
+
+class TestDoneSlotsSkipAttention:
+    """Attention reads no rows of a slot that is not decoding
+    (`transformer.attended_lens`): the chunk's tokens, lengths, pool and
+    conv state are bitwise those of the rule that read every slot's rows,
+    on a dense stack, a conv/GQA stack with held experts and a windowed
+    local/global stack, through the XLA reference and the kernel."""
+
+    NUM_STEPS, BS, NB, NBT = 5, 4, 40, 8
+    # slot: (table, seq_len, budget) — 0 live, 1 finished holding a long
+    # cache, 2 unadmitted, 3 finishes mid-chunk, 4 live with a long cache
+    # (past gemma2's window of 16); blocks 32-39 belong to nobody
+    SLOTS = [(list(range(0, 8)), 6, 5), (list(range(8, 16)), 29, 0),
+             ([NB] * NBT, 0, 0), (list(range(16, 24)), 11, 2),
+             (list(range(24, 32)), 20, 5)]
+
+    def _model(self, name):
+        cfg = registry.get_reduced(name)
+        if cfg.moe is not None:
+            cfg = cfg.replace(moe=dataclasses.replace(cfg.moe,
+                                                      held_experts=4))
+        return cfg, api.init_params(cfg, jax.random.PRNGKey(0))
+
+    def _inputs(self, cfg):
+        import jax.numpy as jnp
+
+        B = len(self.SLOTS)
+        pool = api.init_kv_pool(cfg, self.NB, self.BS, slots=B)
+        kk, kv, kc, kt = jax.random.split(jax.random.PRNGKey(6), 4)
+        pool = dataclasses.replace(
+            pool, k=jax.random.normal(kk, pool.k.shape, pool.k.dtype),
+            v=jax.random.normal(kv, pool.v.shape, pool.v.dtype),
+            conv=(None if pool.conv is None else jax.random.normal(
+                kc, pool.conv.shape, pool.conv.dtype)))
+        tables = jnp.asarray([s[0] for s in self.SLOTS], jnp.int32)
+        lens = jnp.asarray([s[1] for s in self.SLOTS], jnp.int32)
+        budget = jnp.asarray([s[2] for s in self.SLOTS], jnp.int32)
+        tokens = jax.random.randint(kt, (B,), 0, cfg.vocab_size, jnp.int32)
+        return pool, tokens, lens, budget, tables
+
+    def test_rule_reads_no_rows_of_idle_slots(self):
+        import jax.numpy as jnp
+
+        from repro.models import transformer as TF
+
+        lens = TF.attended_lens(jnp.asarray([3, 40, 7, 31]),
+                                jnp.asarray([True, False, True, True]), 32)
+        np.testing.assert_array_equal(np.asarray(lens), [4, 0, 8, 32])
+
+    @pytest.mark.parametrize("decode_attn", ["dense", "paged"])
+    @pytest.mark.parametrize("name", ["olmo-1b", "lfm2-8b-a1b", "gemma2-9b"])
+    def test_pooled_matches_full_lengths_bitwise(self, name, decode_attn,
+                                                 monkeypatch):
+        from repro.models import transformer as TF
+
+        cfg, params = self._model(name)
+        ctx = dataclasses.replace(LOCAL_CTX, decode_attn=decode_attn)
+        pool, tokens, lens, budget, tables = self._inputs(cfg)
+
+        def run():
+            return api.decode_n(cfg, params, pool, tokens, lens, budget, ctx,
+                                num_steps=self.NUM_STEPS, tables=tables)
+
+        got = run()
+        monkeypatch.setattr(TF, "attended_lens", _full_lens)
+        want = run()
+        for x, y in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+        # the live slots decoded, the finished and unadmitted ones did not
+        np.testing.assert_array_equal(
+            np.asarray(got[2]), np.asarray(lens) + np.minimum(
+                np.asarray(budget), self.NUM_STEPS))
+
+    def test_per_slot_matches_full_lengths(self, small_model, monkeypatch):
+        """`decode_step_paged` keeps the same contract: tokens, lengths and
+        every slot's valid rows as under full lengths (a frozen slot's
+        garbage row past its length is written either way, from its own
+        discarded lane)."""
+        import jax.numpy as jnp
+
+        from repro.models import transformer as TF
+
+        cfg, params = small_model
+        pool, tokens, lens, budget, _ = self._inputs(cfg)
+        a, B, W = cfg.attention, len(self.SLOTS), self.NBT * self.BS
+        shape = (cfg.num_layers, B, W, a.num_kv_heads, a.head_dim)
+        kk, kv = jax.random.split(jax.random.PRNGKey(7))
+        cache = TF.Cache(k=jax.random.normal(kk, shape, jnp.bfloat16),
+                         v=jax.random.normal(kv, shape, jnp.bfloat16),
+                         pos=jnp.asarray(0, jnp.int32))
+
+        def run():
+            return api.decode_n(cfg, params, cache, tokens, lens, budget,
+                                num_steps=self.NUM_STEPS)
+
+        got = run()
+        monkeypatch.setattr(TF, "attended_lens", _full_lens)
+        want = run()
+        for i in (0, 2, 3):
+            np.testing.assert_array_equal(np.asarray(got[i]),
+                                          np.asarray(want[i]))
+        valid = np.arange(W)[None, :] < np.asarray(got[2])[:, None]
+        for x, y in ((got[1].k, want[1].k), (got[1].v, want[1].v)):
+            np.testing.assert_array_equal(np.asarray(x)[:, valid],
+                                          np.asarray(y)[:, valid])
+
+    @pytest.mark.parametrize("k", [1, 2])
+    def test_skipped_slots_counted(self, small_model, k):
+        """A chunk that starts with k finished slots records ``skipped=k``
+        on its ``serve.decode`` span and adds k x steps to
+        ``serve.decode_slots_skipped``."""
+        from repro.obs import Telemetry
+
+        cfg, params = small_model
+        obs = Telemetry(tracing=True)
+        eng = ServeEngine(cfg, params, SliceSpec(
+            slots=3, max_len=64, prompt_len=16, chunk=4, kv_block=8,
+            kv_share=False, suffix_len=8), obs=obs)
+        # k requests end inside the first chunk, the rest outlast two
+        for i in range(3):
+            eng.submit(np.arange(5) + i, max_new_tokens=3 if i < k else 12)
+        eng.step_chunk()
+        eng.step_chunk()
+        spans = [s for s in obs.tracer.spans if s.name == "serve.decode"]
+        assert [s.args["skipped"] for s in spans] == [0, k]
+        assert all(s.args["steps"] == 4 for s in spans)
+        assert obs.metrics.counter(
+            "serve.decode_slots_skipped").value == k * 4
+
+
 class TestPoolRidesTheCarry:
     """The pooled prefill and decode keep the pool in their layer scans'
     carry: no scan takes the pool, or one layer's share of it, as ``xs`` or
